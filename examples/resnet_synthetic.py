@@ -73,30 +73,40 @@ def make_eager_step(cfg, optimizer):
     return step
 
 
-def main():
-    args = parse_args()
-    hvd.init()
-    rank, size = hvd.rank(), hvd.size()
-
+def build(depth=50, num_classes=1000, fp32=False, step_mode="eager", seed=0):
+    """One run's ``(step, params, stats, opt_state)`` after ``hvd.init()``:
+    ResNet weights from ``seed`` synchronized from rank 0, the distributed
+    optimizer and the step function of ``step_mode``.  ``main`` and
+    ``chip_smoke.py`` both train through this."""
     cfg = resnet.ResNetConfig(
-        depth=args.depth, num_classes=args.num_classes,
-        compute_dtype=jnp.float32 if args.fp32 else jnp.bfloat16,
+        depth=depth, num_classes=num_classes,
+        compute_dtype=jnp.float32 if fp32 else jnp.bfloat16,
         sync_bn_axis=None)
-    params, stats = resnet.init_params(cfg, jax.random.PRNGKey(0))
+    params, stats = resnet.init_params(cfg, jax.random.PRNGKey(seed))
     params = hvd.broadcast_parameters(params, root_rank=0)
 
-    optimizer = hvd.DistributedOptimizer(optax.sgd(0.01 * size, momentum=0.9))
+    optimizer = hvd.DistributedOptimizer(
+        optax.sgd(0.01 * hvd.size(), momentum=0.9))
     opt_state = optimizer.init(params)
-    images, labels = resnet.synthetic_batch(
-        args.batch_size, image_size=args.image_size,
-        num_classes=args.num_classes, seed=rank)
-
-    if args.step_mode == "spmd":
+    if step_mode == "spmd":
         # One jitted shard_map step over the local device mesh: allreduce is
         # an in-graph psum XLA schedules over ICI.
         step = resnet.make_sharded_train_step(cfg, optimizer, hvd.mesh())
     else:
         step = make_eager_step(cfg, optimizer)
+    return step, params, stats, opt_state
+
+
+def main():
+    args = parse_args()
+    hvd.init()
+    rank, size = hvd.rank(), hvd.size()
+
+    step, params, stats, opt_state = build(
+        args.depth, args.num_classes, args.fp32, args.step_mode)
+    images, labels = resnet.synthetic_batch(
+        args.batch_size, image_size=args.image_size,
+        num_classes=args.num_classes, seed=rank)
 
     for _ in range(args.num_warmup):
         params, stats, opt_state, l = step(params, stats, opt_state,

@@ -137,6 +137,28 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None,
                 jax.distributed.initialize()
 
         st.topology = build_topology(axis_name=axis_name, devices=devices)
+        if multi_process and not cfg.one_proc_per_host:
+            mine = st.topology.ranks_of_process(st.topology.my_process)
+            if len(mine) == 1 and mine[0] != cfg.rank_env:
+                # On a TPU host the runtime numbers processes by where
+                # their chips sit, and the world mesh is in ICI order, so
+                # the launcher's HOROVOD_RANK (which only numbered the
+                # rendezvous) need not be this process's place in the
+                # mesh.  Eager collectives put a contribution at the
+                # device's mesh index, so that index IS the rank: adopt it
+                # before the controller and the engine are built.  Never
+                # fires on the CPU, where process i owns device i.
+                import dataclasses
+                local = (mine[0] if cfg.cross_size_env <= 1
+                         else cfg.local_rank_env)
+                cfg = st.config = dataclasses.replace(
+                    cfg, rank_env=mine[0], local_rank_env=local)
+        if st.topology.devices[0].platform != "cpu":
+            # Accelerator compiles are long (a ResNet-50 step ~45 s): keep
+            # them across processes and runs.  CPU runs (tests) stay as
+            # they were unless the environment places a cache itself.
+            from . import compile_cache
+            compile_cache.enable()
         gs = st.process_set_table.initialize(
             st.topology.devices, axis_name, extra_sets=process_sets)
         # Rebind the module-level global_process_set singleton.

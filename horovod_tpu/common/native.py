@@ -59,6 +59,15 @@ def _build() -> str:
             try:
                 subprocess.run(cmd, check=True, capture_output=True, text=True)
                 os.replace(tmp, out)
+            except FileNotFoundError as exc:
+                raise RuntimeError(
+                    "horovod_tpu builds csrc/coordinator.cc on first use "
+                    "and found no C++ compiler: `g++` is not on PATH"
+                ) from exc
+            except subprocess.CalledProcessError as exc:
+                raise RuntimeError(
+                    f"building {_SRC} failed (g++ exit {exc.returncode}):\n"
+                    f"{exc.stderr}") from exc
             finally:
                 if os.path.exists(tmp):
                     os.unlink(tmp)

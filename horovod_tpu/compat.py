@@ -1,11 +1,9 @@
-"""JAX version-compatibility shims.
+"""One import site for the JAX names the package leans on.
 
-The codebase targets the modern ``jax.shard_map`` API (keyword-only
-``mesh``/``in_specs``/``out_specs`` plus the ``check_vma`` flag).  Older
-installed JAX releases (≤ 0.4.x) only ship the experimental spelling
-``jax.experimental.shard_map.shard_map`` whose replication check is named
-``check_rep``.  Every module in this repo imports ``shard_map`` from here so
-the whole package loads — and behaves identically — on either API.
+The package runs on one installation (jax 0.9.0).  Every module imports
+``shard_map`` and ``axis_size`` from here, and the analyzer keys on these
+names (``horovod_tpu.compat.shard_map``), so they stay; there is no branch
+for any other JAX release.
 
 Usage::
 
@@ -14,113 +12,45 @@ Usage::
 
 from __future__ import annotations
 
-import functools
-
-try:
-    from jax import shard_map as _shard_map          # JAX ≥ 0.6 public API
-    _HAS_CHECK_VMA = True
-except ImportError:                                  # JAX ≤ 0.4/0.5 fallback
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _HAS_CHECK_VMA = False
-
-try:
-    from jax.lax import axis_size                    # JAX ≥ 0.5
-except ImportError:
-    import jax.core as _jax_core
-
-    def axis_size(axis_name):
-        """Size of a bound mesh axis (old-JAX fallback).
-
-        ``jax.core.axis_frame`` returns the bound size and raises
-        ``NameError`` for an unbound name — the same contract as the modern
-        ``jax.lax.axis_size``.  Tuples of names multiply, matching psum-over-
-        multiple-axes semantics.
-        """
-        if isinstance(axis_name, (tuple, list)):
-            n = 1
-            for a in axis_name:
-                n *= _jax_core.axis_frame(a)
-            return n
-        return _jax_core.axis_frame(axis_name)
+from jax import shard_map  # noqa: F401 - re-exported
+from jax.lax import axis_size  # noqa: F401 - re-exported
 
 
 def set_host_device_count(n: int):
-    """Declare ``n`` virtual CPU devices — portably, BEFORE backend init.
+    """Declare ``n`` virtual CPU devices, BEFORE backend init.
 
-    New JAX spells this ``jax.config.update("jax_num_cpu_devices", n)``;
-    0.4.x does not know that option and only honors the
-    ``--xla_force_host_platform_device_count`` XLA flag.  Either way it
-    must run before the CPU backend initializes (first ``jax.devices()``
+    It must run before the CPU backend initializes (first ``jax.devices()``
     etc.); an already-initialized backend keeps its device count and this
     call has no effect on it.
     """
     import os
 
     import jax
-    # Always strip any stale count flag first, even when the config path
-    # below succeeds: an inherited --xla_force_host_platform_device_count
-    # (e.g. a parent harness that stacked its own flags into XLA_FLAGS
-    # before spawning us) would otherwise override the config option at
-    # backend init and silently pin the OLD count.  Stripping makes
-    # stacked callers compose — last caller before backend init wins.
-    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
-             if "xla_force_host_platform_device_count" not in f]
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-        os.environ["XLA_FLAGS"] = " ".join(flags)
-        return
-    except Exception:  # noqa: BLE001 - option unknown on jax <= 0.4.x
-        pass
-    flags.append(f"--xla_force_host_platform_device_count={int(n)}")
-    os.environ["XLA_FLAGS"] = " ".join(flags)
+    # Strip any stale count flag: an inherited
+    # --xla_force_host_platform_device_count (e.g. a parent harness that
+    # stacked its own flags into XLA_FLAGS before spawning us) would
+    # override the config option at backend init and silently pin the OLD
+    # count.  Stripping makes stacked callers compose — last caller before
+    # backend init wins.
+    os.environ["XLA_FLAGS"] = " ".join(
+        f for f in os.environ.get("XLA_FLAGS", "").split()
+        if "xla_force_host_platform_device_count" not in f)
+    jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def tpu_compiler_params(**kwargs):
-    """Pallas-TPU compiler params across the rename.
-
-    New JAX spells it ``pltpu.CompilerParams``; 0.4.x spells it
-    ``pltpu.TPUCompilerParams``.  Same fields either way.
-    """
+    """Pallas-TPU compiler params (``pltpu.CompilerParams``)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across its signature change.
-
-    New JAX takes ``(axis_sizes, axis_names)``; 0.4.x takes one
-    ``((name, size), ...)`` shape tuple.
-    """
+    """``jax.sharding.AbstractMesh`` from parallel size/name sequences."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def jax_export():
-    """The ``jax.export`` module, importable on both old and new JAX.
-
-    Old JAX does not auto-import the submodule, so bare ``jax.export.export``
-    raises ``AttributeError`` unless something imported it first.
-    """
+    """The ``jax.export`` module."""
     import jax.export as _export
     return _export
-
-
-@functools.wraps(_shard_map)
-def shard_map(f, *args, **kwargs):
-    """``jax.shard_map`` with the replication-check flag translated.
-
-    Accepts either ``check_vma`` (new spelling) or ``check_rep`` (old) and
-    forwards whichever the underlying JAX understands.  Positional
-    ``mesh``/``in_specs``/``out_specs`` are passed through untouched.
-    """
-    if _HAS_CHECK_VMA:
-        if "check_rep" in kwargs:
-            kwargs["check_vma"] = kwargs.pop("check_rep")
-    else:
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, *args, **kwargs)

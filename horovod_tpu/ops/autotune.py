@@ -44,9 +44,9 @@ _CAP_LO = 4.0
 _CHUNK_BOUNDS = (16.0, 30.0)
 _INFLIGHT_BOUNDS = (0.0, 3.0)
 # Latency fast-lane threshold (multi-process only, same gate): 256B..16MB.
-# The left end of the busbw curve is where the fusion buffer costs more
-# than it buys (BENCH_SELF_r03/r05) — the search finds the crossover
-# instead of a hand-set constant.  Note cycle_time is ALREADY the second
+# The left end of the busbw curve is where the fusion buffer is expected
+# to cost more than it buys (unmeasured on the current machine) — the
+# search finds the crossover instead of a hand-set constant.  Note cycle_time is ALREADY the second
 # base coordinate, so the latency pair (fast_lane_threshold, cycle_time)
 # is fully searched, never hand-set.
 _FAST_LANE_BOUNDS = (8.0, 24.0)

@@ -58,20 +58,18 @@ def _env_int(name: str, dflt: int, valid=lambda v: True) -> int:
 
 
 def flash_min_seq(causal: bool = False) -> int:
-    """Auto-mode crossover, measured on real v5e (BENCH_SELF_r05, full
-    in-model A/B with the raw-bf16 kernels and 512x512 tiles):
+    """Auto-mode crossover.  The defaults are unmeasured on the current
+    machine (they come from an earlier installation's in-model A/Bs;
+    ROADMAP queue 1 item 4 re-measures them):
 
-    - **causal** (llama family): flash already wins at T=512
-      (623k vs 552k tok/s) — whole-block causal skipping halves the
-      work, so the crossover default is 512.
-    - **non-causal** (bert): XLA's fused attention wins at T=256
-      (789k vs 649k tok/s — no blocks to skip, flash's rescaling
-      machinery is pure overhead) and flash wins at T=1024 (544k vs
-      424k), bracketing the crossover — the default stays 1024, now
-      measured in-model on both sides.
+    - **causal** (llama family): 512 — whole-block causal skipping halves
+      the work, so flash is expected to win early.
+    - **non-causal** (bert): 1024 — no blocks to skip, so below it flash's
+      rescaling machinery is expected to be pure overhead against XLA's
+      fused attention.
 
     ``HVD_TPU_FLASH_MIN_SEQ`` overrides BOTH; tools/flash_sweep.py
-    re-measures the crossover per chip."""
+    measures the crossover per chip."""
     return _env_int("HVD_TPU_FLASH_MIN_SEQ", 512 if causal else 1024,
                     lambda v: v >= 0)
 
@@ -123,8 +121,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # Dots take the RAW input dtype (bf16 in training) with an f32
         # accumulator: bf16×bf16 products are exact in f32 accumulation,
         # so this matches the old cast-to-f32-first numerics while running
-        # the MXU at full bf16 rate instead of the ~4x-slower f32 path
-        # (the measured BENCH_SELF_r05 flash regression).
+        # the MXU at full bf16 rate instead of the slower f32 path.
         q = q_ref[0]                                # [bq, D]
         k = k_ref[0]                                # [bk, D]
         s = jax.lax.dot_general(
@@ -331,12 +328,12 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
 def _block_defaults() -> tuple:
     """Kernel tile defaults, env-overridable for per-chip tuning
     (``HVD_TPU_FLASH_BLOCK_Q`` / ``HVD_TPU_FLASH_BLOCK_K`` — read at
-    trace time; tools/flash_sweep.py measures the candidates).  512x512
-    won or tied every shape in the on-chip sweep (FLASH_SWEEP_r05.json:
-    1.3-2.1x faster than the old 128x128 at T>=1024, 5x at T=8192 —
-    bigger tiles amortize the grid/rescale overhead and keep the MXU
-    fed).  The sublane rule (multiples of 8) is enforced here so a bad
-    value keeps the default instead of dying in Mosaic lowering."""
+    trace time; tools/flash_sweep.py measures the candidates).  The
+    512x512 default is unmeasured on the current machine (an earlier
+    installation's sweep chose it: bigger tiles amortize the grid/rescale
+    overhead and keep the MXU fed).  The sublane rule (multiples of 8) is
+    enforced here so a bad value keeps the default instead of dying in
+    Mosaic lowering."""
     ok = lambda v: v >= 8 and v % 8 == 0  # noqa: E731
     return (_env_int("HVD_TPU_FLASH_BLOCK_Q", 512, ok),
             _env_int("HVD_TPU_FLASH_BLOCK_K", 512, ok))

@@ -17,9 +17,7 @@ import sys
 
 # 4 virtual CPU devices per process — the "slice" — via the shared
 # harness (tests/slice_harness.py): strips the inherited 8-device flag,
-# declares the local count through the compat shim (``jax_num_cpu_devices``
-# does not exist on jax 0.4.x, where only the XLA flag works), pins CPU +
-# gloo.
+# declares the local count (``jax_num_cpu_devices``), pins CPU + gloo.
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 from slice_harness import configure_slice_world

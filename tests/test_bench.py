@@ -19,18 +19,11 @@ from test_examples import _example_env
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "bench.py")
 
-_WATCHDOG_S = 600
-
-
 def _run_bench(extra_env):
     env = _example_env(
-        XLA_FLAGS="--xla_force_host_platform_device_count=8",
-        HVD_BENCH_TIMEOUT_S=str(_WATCHDOG_S), **extra_env)
-    # Outer timeout strictly above the internal watchdog so a wedge emits
-    # the watchdog's diagnostic JSON instead of an opaque TimeoutExpired.
+        XLA_FLAGS="--xla_force_host_platform_device_count=8", **extra_env)
     r = subprocess.run([sys.executable, BENCH], env=env,
-                       capture_output=True, text=True,
-                       timeout=_WATCHDOG_S + 120)
+                       capture_output=True, text=True, timeout=720)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-2000:])
     lines = [l for l in r.stdout.splitlines() if l.startswith("{")]
     assert len(lines) == 1, r.stdout[-2000:]

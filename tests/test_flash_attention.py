@@ -5,7 +5,7 @@ the identical kernel compiles on TPU.
 """
 
 import jax
-import jax.export  # noqa: F401  (not auto-imported on jax<=0.4)
+import jax.export  # noqa: F401
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -277,10 +277,9 @@ def test_llama_uses_flash_when_forced(monkeypatch):
 
 
 def test_flash_auto_seq_threshold(monkeypatch):
-    """Auto routing is sequence-aware (BENCH_SELF_r05: flash LOSES to
-    XLA's fused attention below the crossover on real v5e — 330k vs 552k
-    tok/s at T=512): on TPU, auto mode picks flash only at/above
-    HVD_TPU_FLASH_MIN_SEQ; explicit forces ignore the threshold."""
+    """Auto routing is sequence-aware: on TPU, auto mode picks flash only
+    at/above HVD_TPU_FLASH_MIN_SEQ; explicit forces ignore the
+    threshold."""
     from horovod_tpu.ops import flash_attention as fa
 
     monkeypatch.delenv("HVD_TPU_FLASH", raising=False)
@@ -294,8 +293,7 @@ def test_flash_auto_seq_threshold(monkeypatch):
     assert fa.resolve_flash(True, seq=512) is True    # config force wins
     assert fa.resolve_flash(False, seq=8192) is False
 
-    # Causality-aware defaults (BENCH_SELF_r05 in-model A/B with the
-    # raw-bf16 kernels): causal crossover 512, non-causal stays 1024.
+    # Causality-aware defaults: causal crossover 512, non-causal 1024.
     monkeypatch.delenv("HVD_TPU_FLASH_MIN_SEQ", raising=False)
     assert fa.flash_min_seq(causal=True) == 512
     assert fa.flash_min_seq(causal=False) == 1024
@@ -321,8 +319,8 @@ def test_flash_auto_seq_threshold(monkeypatch):
 
 def test_flash_block_env_defaults(monkeypatch):
     """HVD_TPU_FLASH_BLOCK_Q/K tune the kernel tiles without a code
-    change (tools/flash_sweep.py feeds these); unset keeps the measured
-    512x512 default (FLASH_SWEEP_r05: best or tied at every shape)."""
+    change (tools/flash_sweep.py feeds these); unset keeps the 512x512
+    default."""
     from horovod_tpu.ops import flash_attention as fa
     monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_Q", raising=False)
     monkeypatch.delenv("HVD_TPU_FLASH_BLOCK_K", raising=False)

@@ -3,7 +3,7 @@ allreduce, Adasum — each against a locally computed reference.
 """
 
 import jax
-import jax.export  # noqa: F401  (not auto-imported on jax<=0.4)
+import jax.export  # noqa: F401
 import jax.numpy as jnp
 import numpy as np
 import optax

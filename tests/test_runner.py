@@ -134,6 +134,124 @@ def test_platform_worker_env_cpu_hygiene():
     assert platform_worker_env({}) == {}
 
 
+# --- one process per chip on a TPU host (ISSUE 22) ------------------------
+
+def _fake_pci(root, devices):
+    for i, (vendor, device) in enumerate(devices):
+        d = root / f"0000:00:{i:02x}.0"
+        d.mkdir()
+        (d / "vendor").write_text(vendor + "\n")
+        (d / "device").write_text(device + "\n")
+    return str(root)
+
+
+def test_local_tpu_chips_counts_tpu_pci_functions_only(tmp_path):
+    """The launcher tells a TPU host from sysfs alone: Google-vendor TPU
+    device ids count; the same vendor's NIC and other vendors do not."""
+    from horovod_tpu.runner.run import local_tpu_chips
+    sysfs = _fake_pci(tmp_path, [("0x1ae0", "0x0063")] * 4
+                      + [("0x1ae0", "0x0042"), ("0x8086", "0x0063")])
+    assert local_tpu_chips(sysfs) == 4
+    assert local_tpu_chips(str(tmp_path / "missing")) == 0
+
+
+def test_tpu_worker_envs_bind_one_chip_per_worker():
+    """Four workers on a four-chip host: each sees ONE chip, all share the
+    2x2 process grid and the address list, each has its own port/task id
+    (the variables libtpu 0.0.34 reads; verified on a v5e 2x2 host)."""
+    from horovod_tpu.runner.run import tpu_worker_envs
+    envs = tpu_worker_envs(4, 4, base={})
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    (addresses,) = {e["TPU_PROCESS_ADDRESSES"] for e in envs}
+    ports = [a.rsplit(":", 1)[1] for a in addresses.split(",")]
+    assert len(set(ports)) == 4
+    assert [e["TPU_PROCESS_PORT"] for e in envs] == ports
+    # the image's own host bounds win over the chip-count table
+    envs = tpu_worker_envs(8, 8, base={"TPU_CHIPS_PER_HOST_BOUNDS": "2,4,1"})
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,4,1"}
+
+
+@pytest.mark.parametrize("local_size,chips,base", [
+    pytest.param(4, 0, {}, id="no-chips"),
+    pytest.param(4, 4, {"JAX_PLATFORMS": "cpu"}, id="cpu-launch"),
+    pytest.param(1, 4, {}, id="one-worker-drives-all-chips"),
+    pytest.param(4, 4, {"TPU_VISIBLE_CHIPS": "0,1"}, id="caller-bound"),
+    pytest.param(4, 4, {"TPU_PROCESS_BOUNDS": "1,1,1"}, id="caller-grid"),
+])
+def test_tpu_worker_envs_leave_other_launches_untouched(local_size, chips,
+                                                        base):
+    from horovod_tpu.runner.run import tpu_worker_envs
+    assert tpu_worker_envs(local_size, chips, base=base) == []
+
+
+@pytest.mark.parametrize("local_size,chips", [(2, 4), (3, 4), (8, 4), (2, 2)])
+def test_tpu_worker_envs_refuse_splits_they_cannot_bind(local_size, chips):
+    """N workers that do not tile the host fail AT ONCE with the supported
+    modes named, instead of queueing on the chips' lock."""
+    from horovod_tpu.runner.run import tpu_worker_envs
+    with pytest.raises(ValueError, match="HOROVOD_ONE_PROC_PER_HOST"):
+        tpu_worker_envs(local_size, chips, base={})
+
+
+def test_worker_envs_tpu_host_binding(monkeypatch):
+    """worker_envs on a (simulated) four-chip host: -np 4 binds rank r to
+    chip r next to the HOROVOD_* block; the CPU launch is exactly as it
+    was; a multi-host per-chip launch is refused."""
+    from horovod_tpu.runner.run import TPU_BINDING_VARS
+    for v in TPU_BINDING_VARS + ("TPU_CHIPS_PER_HOST_BOUNDS",):
+        monkeypatch.delenv(v, raising=False)
+    args = parse_args(["-np", "4", "python", "t.py"])
+    hosts = placement(args)
+    coord = ("127.0.0.1", 1234, 1235)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    cpu = worker_envs(args, hosts, coord, tpu_chips=4)
+    assert cpu == worker_envs(args, hosts, coord, tpu_chips=0)
+    assert not any(v in e for e in cpu for v in TPU_BINDING_VARS)
+
+    monkeypatch.delenv("JAX_PLATFORMS")
+    tpu = worker_envs(args, hosts, coord, tpu_chips=4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in tpu] == ["0", "1", "2", "3"]
+    assert [e["HOROVOD_LOCAL_RANK"] for e in tpu] == ["0", "1", "2", "3"]
+    assert "JAX_CPU_COLLECTIVES_IMPLEMENTATION" not in tpu[0]
+
+    two_hosts = [HostSpec("localhost", 4), HostSpec("otherhost", 4)]
+    args8 = parse_args(["-np", "8", "python", "t.py"])
+    with pytest.raises(ValueError, match="single TPU host"):
+        worker_envs(args8, two_hosts, coord, tpu_chips=4)
+
+
+def test_launcher_modules_open_no_jax_backend():
+    """The launcher (and the elastic driver) may import jax but must never
+    initialize a backend: on a TPU host the chips belong to the workers,
+    one process each, and a launcher that held them would hang every
+    worker on the chips' lock.  Checked in a fresh interpreter, through
+    the env computation a launch performs."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "import horovod_tpu.runner.launch, horovod_tpu.runner.run\n"
+        "import horovod_tpu.runner.task_probe, horovod_tpu.runner.bootstrap\n"
+        "import horovod_tpu.runner.tpu_vm, horovod_tpu.elastic.driver\n"
+        "from horovod_tpu.runner.run import parse_args, placement, "
+        "worker_envs\n"
+        "a = parse_args(['-np', '4', 'python', 't.py'])\n"
+        "assert len(worker_envs(a, placement(a), ('127.0.0.1', 1, 2))) == 4\n"
+        "if 'jax' in sys.modules:\n"
+        "    from jax._src import xla_bridge\n"
+        "    assert not xla_bridge._backends, xla_bridge._backends\n"
+        "print('NO_BACKEND')\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo)
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0 and "NO_BACKEND" in r.stdout, r.stderr[-2000:]
+
+
 def test_ssh_command_generation():
     env = {"HOROVOD_RANK": "3", "HOROVOD_SIZE": "4"}
     cmd = ssh_command("node2", env, ["python", "train.py"], ssh_port=2222,

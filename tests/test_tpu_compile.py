@@ -1,0 +1,131 @@
+"""Compile the main path's device programs for a DESCRIBED TPU v5e.
+
+No chip is attached here: the TPU compiler is installed and compiles for
+a topology that is described, which catches what interpret mode cannot
+(tiling, VMEM budgets, a kernel that cannot be partitioned).  Nothing
+runs, so nothing here is a result or a time.
+
+The topology is described inside a module-scoped fixture — never at
+import, in a ``skipif`` or in ``parametrize`` — because only one process
+may hold the TPU library and every xdist worker imports every test file.
+All of these tests stay in this one file and compile in the test's own
+process for the same reason.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    assert len(topo.devices) == 4
+    return Mesh(np.array(topo.devices), ("x",))
+
+
+def _qkv(B, T, H, K, D, sharding):
+    q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, T, K, D), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+# head_dim 64 and 128, GQA everywhere; causal, one windowed, one non-causal.
+FLASH_CASES = [
+    pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
+    pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
+    pytest.param(128, 32, 8, True, 1024, id="d128-h32k8-window1024"),
+    pytest.param(64, 8, 4, False, None, id="d64-h8k4-noncausal"),
+]
+
+
+@pytest.mark.parametrize("D,H,K,causal,window", FLASH_CASES)
+def test_flash_forward_compiles_for_v5e(one_chip, D, H, K, causal, window):
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               interpret=False)
+
+    compiled = jax.jit(fwd).lower(*_qkv(1, 2048, H, K, D, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("D,H,K,causal,window", FLASH_CASES)
+def test_flash_backward_compiles_for_v5e(one_chip, D, H, K, causal, window):
+    from horovod_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal, window=window,
+                               interpret=False).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(1, 2048, H, K, D, one_chip)).compile()
+    # forward (for the residuals) + the dq and dk/dv backward kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_engine_fused_allreduce_compiles_for_four_chips(hvd, mesh4):
+    """The engine's fused allreduce program (``_build_program``) for a
+    three-tensor fusion group on a 4-device mesh: the chip's compiler must
+    keep ONE program with a cross-chip all-reduce in it."""
+    from horovod_tpu.ops import collectives as C
+    from horovod_tpu.ops import eager
+    from horovod_tpu.ops.engine import CollectiveType, TensorTableEntry
+
+    proto = TensorTableEntry(handle=0, name="g", ctype=CollectiveType.ALLREDUCE,
+                             tensor=None, reduce_op=C.ReduceOp.SUM)
+    shapes = ((4, 1024, 256), (4, 4096), (4, 7))
+    fn = eager._engine()._build_program(
+        proto, shapes, ("float32",) * len(shapes), mesh4, "x", 4,
+        donate=(True,) * len(shapes))
+    stacked = NamedSharding(mesh4, P("x"))
+    compiled = fn.lower(*[jax.ShapeDtypeStruct(s, jnp.float32,
+                                               sharding=stacked)
+                          for s in shapes]).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "noncausal"])
+def test_ring_attention_partitions_over_sp4(mesh4, causal):
+    """Ring attention wraps the flash kernels in ``shard_map`` over
+    ``sp=4``: forward and backward must partition for the chip, kernel
+    and ring (collective-permute) both present."""
+    from horovod_tpu.compat import shard_map
+    from horovod_tpu.parallel.ring_attention import ring_attention
+
+    def attn(q, k, v):
+        return ring_attention(q, k, v, axis_name="x", causal=causal,
+                              use_flash=True, interpret=False)
+
+    sharded = shard_map(attn, mesh=mesh4, in_specs=(P(None, "x"),) * 3,
+                        out_specs=P(None, "x"), check_vma=False)
+
+    def loss(q, k, v):
+        return sharded(q, k, v).astype(jnp.float32).sum()
+
+    seq = NamedSharding(mesh4, P(None, "x"))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(1, 4 * 1024, 32, 8, 128, seq)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
