@@ -22,6 +22,7 @@ same training script runs unmodified on one chip.
 
 from __future__ import annotations
 
+import itertools
 from typing import Any, Callable, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -32,6 +33,7 @@ from jax import lax
 from ..compat import axis_size as compat_axis_size
 
 from .compression import Compression
+from .. import trace
 from ..ops import collectives as C
 from ..common.process_sets import ProcessSet
 
@@ -79,6 +81,7 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
         return jax.tree_util.tree_unflatten(treedef, out)
 
     from ..ops import eager
+    from ..ops.engine import CollectiveType
     if not eager.per_process_mode():
         return grads  # single-controller SPMD: params/grads already global
     if any(isinstance(l, jax.core.Tracer) for l in leaves):
@@ -97,30 +100,105 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
     # scheduling through the engine's priority queue.  Pytree flatten order
     # is identical on every rank, so the stamps agree.
     prios = [len(leaves) - i for i in range(len(leaves))]
+    # Cast-style compression (``wire_mode``) rides INSIDE the fused program
+    # (cast-down before the psum, cast-up after): results come back in the
+    # gradients' own dtype with half the wire bytes and no extra launches.
+    # Any other compressor wraps the exchange on this thread.
     wire = getattr(compression, "wire_mode", None)
-    if wire is not None:
-        # Cast-style compression rides INSIDE the fused program (cast-down
-        # before the psum, cast-up after): results come back in the
-        # gradients' own dtype with half the wire bytes and no extra
-        # launches.
+    comp = None
+
+    def stage():
+        nonlocal comp
         arrs = [jnp.asarray(g) for g in leaves]
-        reduced = eager.grouped_allreduce(arrs, op=op,
-                                          name="allreduce_gradients",
-                                          process_set=process_set,
-                                          compression=wire,
-                                          priorities=prios)
+        if wire is None:
+            comp = [compression.compress(a) for a in arrs]
+            arrs = [c[0] for c in comp]
+        return arrs
+
+    gid, arrs, handles = _stage_submit(
+        stage, "allreduce_gradients", "grouped_allreduce",
+        CollectiveType.ALLREDUCE, process_set, prios, reduce_op=op,
+        compression=eager._wire_mode(wire))
+    reduced = _wait(gid, handles)
+    with trace.span("hvd/update/unpack") as sp:
         out = [jnp.asarray(eager.to_local(r)).reshape(a.shape)
                .astype(a.dtype) for r, a in zip(reduced, arrs)]
-        return jax.tree_util.tree_unflatten(treedef, out)
-    comp = [compression.compress(jnp.asarray(g)) for g in leaves]
-    reduced = eager.grouped_allreduce([c[0] for c in comp], op=op,
-                                      name="allreduce_gradients",
-                                      process_set=process_set,
-                                      priorities=prios)
-    reduced = [jnp.asarray(eager.to_local(r)).reshape(c[0].shape)
-               .astype(c[0].dtype) for r, c in zip(reduced, comp)]
-    out = [compression.decompress(r, c[1]) for r, c in zip(reduced, comp)]
+        if comp is not None:
+            out = [compression.decompress(r, c[1])
+                   for r, c in zip(out, comp)]
+        if sp is not None:
+            sp.set(n=len(out), bytes=_nbytes(out))
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+# ---- program spans of the eager update (docs/timeline.md).  Each phase of
+# one update on the calling thread is a span of ``horovod_tpu.trace``; with
+# tracing disarmed a site is one ``is None`` check.
+
+_update_steps = itertools.count()   # host-side count of traced updates
+
+
+def _update_span(grads=None, axis_name=None):
+    """``hvd/update`` around one eager, per-process update; the shared
+    no-op while tracing is disarmed and — ``grads`` given — wherever the
+    update is not on that branch (traced under ``jit`` / ``shard_map``,
+    single-controller), so a step program under trace enters no span.
+    ``group`` is the first group the update submits."""
+    if trace.installed() is None:
+        return trace.OFF
+    from ..ops import eager
+    if grads is not None and (
+            _axis_in_scope(axis_name) or not eager.per_process_mode()
+            or any(isinstance(l, jax.core.Tracer)
+                   for l in jax.tree_util.tree_leaves(grads))):
+        return trace.OFF
+    return trace.span("hvd/update", step=next(_update_steps),
+                      group=eager._group_counter.upcoming)
+
+
+def _inner_span(up):
+    """``hvd/update/inner`` (the wrapped optimizer's update), under an
+    open ``hvd/update`` only."""
+    return trace.OFF if up is None else trace.span("hvd/update/inner")
+
+
+def _nbytes(arrs) -> int:
+    return sum(int(a.nbytes) for a in arrs)
+
+
+def _stage_submit(make, name: str, prefix: str, ctype, process_set,
+                  priorities, kick: bool = True, **extra):
+    """One group into the engine: ``hvd/update/stage`` (``make()`` readies
+    the tensors — compress, ravel, pad — and ``eager._stage_group`` puts
+    them into the engine's stacked layout), then ``hvd/update/submit``
+    (``enqueue_group`` + ``kick``).  Returns ``(group id, the tensors,
+    handles)``."""
+    from ..ops import eager
+    with trace.span("hvd/update/stage") as sp:
+        tensors = make()
+        gid, items = eager._stage_group(tensors, name, prefix, ctype,
+                                        process_set, priorities, **extra)
+        if sp is not None:
+            sp.set(n=len(tensors), bytes=_nbytes(tensors))
+    eng = eager._engine()
+    with trace.span("hvd/update/submit", group=gid):
+        handles = eng.enqueue_group(items)
+        if kick:
+            eng.kick()
+    return gid, tensors, handles
+
+
+def _wait(gid: int, handles) -> List:
+    """``hvd/update/wait``: blocked on the engine, first to last handle
+    of one group."""
+    from ..ops import eager
+    with trace.span("hvd/update/wait", group=gid):
+        return [eager.synchronize(h) for h in handles]
+
+
+def _wait_by_leaf(gid: int, handles: dict) -> dict:
+    """``_wait`` for ``{leaf index: handle}``: ``{leaf index: result}``."""
+    return dict(zip(handles, _wait(gid, handles.values())))
 
 
 class _DistOptState(NamedTuple):
@@ -476,6 +554,7 @@ def _sharded_eager_update(optimizer, grads,
     scatter → update → gather stages overlap across buckets (the engine's
     in-flight window + priority backlog do the interleaving)."""
     from ..ops import eager
+    from ..ops.engine import CollectiveType
     plan = state.plan
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     if tuple(tuple(getattr(l, "shape", ())) for l in leaves) != plan.shapes:
@@ -493,67 +572,91 @@ def _sharded_eager_update(optimizer, grads,
     # window keeps later buckets' scatters on the wire while earlier
     # buckets update.  Reverse-registration priorities: the first
     # parameters the next forward pass needs lead each cycle.
-    rs_handles: List[dict] = []
-    for b, idxs in enumerate(plan.buckets):
-        live = [i for i in idxs if plan.pers[i] > 0]   # empty leaves skip
-        padded = []
-        for i in live:
-            flat = jnp.ravel(jnp.asarray(leaves[i]))
-            if plan.pads[i]:
-                flat = jnp.pad(flat, (0, plan.pads[i]))
-            padded.append(flat)
-        handles = eager.grouped_reducescatter_async(
-            padded, name=f"sharded_rs.b{b}", op=op,
-            process_set=process_set,
-            priorities=[nl - i for i in live], sharded=True) \
-            if padded else []
-        rs_handles.append(dict(zip(live, handles)))
-    eng = eager._engine()
-    eng.kick()
+    rs = [_stage_scatter(leaves, plan, b, f"sharded_rs.b{b}", op,
+                         process_set, True)
+          for b in range(len(plan.buckets))]
+    eager._engine().kick()
 
     p_leaves = jax.tree_util.tree_flatten(params)[0] \
         if params is not None else None
-    ag_handles: List = []
+    ag: List = []
     new_inner: List = []
     for b, idxs in enumerate(plan.buckets):
-        g_shards = tuple(
-            jnp.asarray(eager.to_local(
-                eager.synchronize(rs_handles[b][i]))).reshape(-1)
-            .astype(plan.dtypes[i]) if plan.pers[i] > 0
-            else jnp.zeros((0,), plan.dtypes[i])
-            for i in idxs)
+        g_shards = _wait_shards(plan, idxs, *rs[b])
         p_shards = None
         if p_leaves is not None:
             p_shards = tuple(
                 _device_shard(jnp.asarray(p_leaves[i]), plan.pads[i],
                               plan.pers[i], rank) for i in idxs)
-        updates_b, inner_b = optimizer.update(
-            g_shards, state.inner_states[b], p_shards)
+        with trace.span("hvd/update/inner"):
+            updates_b, inner_b = optimizer.update(
+                g_shards, state.inner_states[b], p_shards)
         new_inner.append(inner_b)
         # Phase 3 (overlapped): this bucket's updated deltas start their
         # allgather while later buckets are still scattering/updating.
         live = [i for i in idxs if plan.pers[i] > 0]
-        handles = eager.grouped_allgather_async(
-            [jnp.asarray(u) for u, i in zip(updates_b, idxs) if i in live],
-            name=f"sharded_ag.b{b}", process_set=process_set,
-            priorities=[nl - i for i in live], sharded=True) \
-            if live else []
-        ag_handles.append(dict(zip(live, handles)))
-        eng.kick()
+        gid, handles = -1, []
+        if live:
+            gid, _, handles = _stage_submit(
+                lambda: [jnp.asarray(u) for u, i in zip(updates_b, idxs)
+                         if i in live],
+                f"sharded_ag.b{b}", "grouped_allgather",
+                CollectiveType.ALLGATHER, process_set,
+                [nl - i for i in live], sharded=True, prefetch=False)
+        ag.append((gid, dict(zip(live, handles))))
 
     out: List[Any] = [None] * nl
     for b, idxs in enumerate(plan.buckets):
-        for i in idxs:
-            if plan.pers[i] == 0:
-                out[i] = jnp.zeros(plan.shapes[i], plan.dtypes[i])
-                continue
-            full = np.asarray(eager.to_local(
-                eager.synchronize(ag_handles[b][i])))
-            full = full.reshape(-1)[:plan.sizes[i]]
-            out[i] = jnp.asarray(full.reshape(plan.shapes[i])) \
-                .astype(plan.dtypes[i])
+        full = _wait_by_leaf(*ag[b])
+        with trace.span("hvd/update/unpack"):
+            for i in idxs:
+                if plan.pers[i] == 0:
+                    out[i] = jnp.zeros(plan.shapes[i], plan.dtypes[i])
+                    continue
+                flat = np.asarray(eager.to_local(full[i]))
+                flat = flat.reshape(-1)[:plan.sizes[i]]
+                out[i] = jnp.asarray(flat.reshape(plan.shapes[i])) \
+                    .astype(plan.dtypes[i])
     updates = jax.tree_util.tree_unflatten(treedef, out)
     return updates, ShardedOptimizerState(new_inner, plan, process_set)
+
+
+def _stage_scatter(leaves, plan: _ShardPlan, b: int, name: str, op,
+                   process_set, sharded):
+    """Stage and submit bucket ``b``'s gradient reduce-scatter (no kick:
+    the caller wakes the engine once every bucket is queued).  Returns
+    ``(group id, {leaf index: handle})``."""
+    from ..ops.engine import CollectiveType
+    live = [i for i in plan.buckets[b] if plan.pers[i] > 0]  # empty: skip
+    if not live:
+        return -1, {}
+    def padded():
+        out = []
+        for i in live:
+            flat = jnp.ravel(jnp.asarray(leaves[i]))
+            if plan.pads[i]:
+                flat = jnp.pad(flat, (0, plan.pads[i]))
+            out.append(flat)
+        return out
+
+    gid, _, handles = _stage_submit(
+        padded, name, "grouped_reducescatter", CollectiveType.REDUCESCATTER,
+        process_set, [len(leaves) - i for i in live], kick=False,
+        reduce_op=op, sharded=sharded)
+    return gid, dict(zip(live, handles))
+
+
+def _wait_shards(plan: _ShardPlan, idxs, gid: int, handles: dict):
+    """Bucket ``idxs``' reduced gradient shards, flat and in the plan's
+    dtypes, once its reduce-scatter (``handles`` by leaf) has settled."""
+    from ..ops import eager
+    res = _wait_by_leaf(gid, handles)
+    with trace.span("hvd/update/unpack"):
+        return tuple(
+            jnp.asarray(eager.to_local(res[i])).reshape(-1)
+            .astype(plan.dtypes[i]) if plan.pers[i] > 0
+            else jnp.zeros((0,), plan.dtypes[i])
+            for i in idxs)
 
 
 def _full_sharded_eager_init(optimizer, params, process_set, chunk_bytes):
@@ -601,26 +704,13 @@ def _full_sharded_eager_update(optimizer, grads, state: FullShardedState,
             'state for the new parameter tree')
     if op not in (C.ReduceOp.AVERAGE, C.ReduceOp.SUM):
         raise ValueError(f'sharded="full" supports SUM/AVERAGE, not {op!r}')
-    nl = len(leaves)
 
     # Phase 1: every bucket's reduce-scatter goes out before any update
     # runs (same overlap structure as the PR 15 pipeline), stamped with
     # reverse-registration priorities and the "full" digest token.
-    rs_handles: List[dict] = []
-    for b, idxs in enumerate(plan.buckets):
-        live = [i for i in idxs if plan.pers[i] > 0]
-        padded = []
-        for i in live:
-            flat = jnp.ravel(jnp.asarray(leaves[i]))
-            if plan.pads[i]:
-                flat = jnp.pad(flat, (0, plan.pads[i]))
-            padded.append(flat)
-        handles = eager.grouped_reducescatter_async(
-            padded, name=f"fsdp_rs.b{b}", op=op,
-            process_set=process_set,
-            priorities=[nl - i for i in live], sharded="full") \
-            if padded else []
-        rs_handles.append(dict(zip(live, handles)))
+    rs = [_stage_scatter(leaves, plan, b, f"fsdp_rs.b{b}", op, process_set,
+                         "full")
+          for b in range(len(plan.buckets))]
     eager._engine().kick()
 
     # Phase 2: shard-local update against the resident shards; the shards
@@ -629,17 +719,14 @@ def _full_sharded_eager_update(optimizer, grads, state: FullShardedState,
     new_inner: List = []
     new_shards: List = []
     for b, idxs in enumerate(plan.buckets):
-        g_shards = tuple(
-            jnp.asarray(eager.to_local(
-                eager.synchronize(rs_handles[b][i]))).reshape(-1)
-            .astype(plan.dtypes[i]) if plan.pers[i] > 0
-            else jnp.zeros((0,), plan.dtypes[i])
-            for i in idxs)
+        g_shards = _wait_shards(plan, idxs, *rs[b])
         p_shards = state.param_shards[b]
-        updates_b, inner_b = optimizer.update(
-            g_shards, state.inner_states[b], p_shards)
+        with trace.span("hvd/update/inner"):
+            updates_b, inner_b = optimizer.update(
+                g_shards, state.inner_states[b], p_shards)
+            shards_b = tuple(optax.apply_updates(p_shards, updates_b))
         new_inner.append(inner_b)
-        new_shards.append(tuple(optax.apply_updates(p_shards, updates_b)))
+        new_shards.append(shards_b)
     td = state.treedef if state.treedef is not None else treedef
     return None, FullShardedState(new_inner, plan, process_set,
                                   new_shards, td)
@@ -694,11 +781,13 @@ def _make_sharded(optimizer: optax.GradientTransformation,
                 average=op == C.ReduceOp.AVERAGE).update(grads, state,
                                                          params)
         if isinstance(state, FullShardedState):
-            return _full_sharded_eager_update(optimizer, grads, state,
-                                              op, process_set)
+            with _update_span():
+                return _full_sharded_eager_update(optimizer, grads, state,
+                                                  op, process_set)
         if isinstance(state, ShardedOptimizerState):
-            return _sharded_eager_update(optimizer, grads, state, params,
-                                         op, process_set)
+            with _update_span():
+                return _sharded_eager_update(optimizer, grads, state, params,
+                                             op, process_set)
         if _axis_in_scope(axis_name) and compat_axis_size(axis_name) > 1:
             # Mixed modes: a plain state initialized OUTSIDE the mesh axis
             # updating INSIDE shard_map.  The plain fallback below would
@@ -824,9 +913,15 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
                                    process_set=process_set)
 
     def update_fn(grads, state: _DistOptState, params=None):
+        with _update_span(grads, axis_name) as up:
+            return _update(grads, state, params, up)
+
+    def _update(grads, state: _DistOptState, params, up):
         if k == 1:
-            updates, inner = optimizer.update(_reduce(grads), state.inner_state,
-                                              params)
+            reduced = _reduce(grads)
+            with _inner_span(up):
+                updates, inner = optimizer.update(reduced, state.inner_state,
+                                                  params)
             return updates, _DistOptState(inner, (), state.counter + 1)
 
         acc = jax.tree_util.tree_map(lambda a, g: a + g, state.acc, grads)
@@ -835,8 +930,9 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
 
         def _do_apply_concrete(acc_, inner_):
             mean_acc = jax.tree_util.tree_map(lambda a: a / k, acc_)
-            updates, new_inner = optimizer.update(_reduce(mean_acc), inner_,
-                                                  params)
+            reduced = _reduce(mean_acc)
+            with _inner_span(up):
+                updates, new_inner = optimizer.update(reduced, inner_, params)
             zeroed = jax.tree_util.tree_map(jnp.zeros_like, acc_)
             return updates, new_inner, zeroed
 

@@ -1027,12 +1027,20 @@ def make_train_step(cfg: LlamaConfig, optimizer, with_rng: bool = False):
     jitter (required when cfg.router_noise > 0)."""
     import optax
 
+    # The four parts of the step under ``jax.named_scope`` (metadata only:
+    # what a device trace shows an operation to belong to);
+    # ``value_and_grad`` split into its halves so that each has its name.
     def _step(params, opt_state, tokens, targets, rng):
-        loss_partial, grads = jax.value_and_grad(loss_fn)(
-            params, tokens, targets, cfg, rng)
-        grads = sync_grads(grads, cfg)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("forward"):
+            loss_partial, backward = jax.vjp(
+                lambda p: loss_fn(p, tokens, targets, cfg, rng), params)
+        with jax.named_scope("backward"):
+            grads, = backward(jnp.ones_like(loss_partial))
+        with jax.named_scope("gradient_exchange"):
+            grads = sync_grads(grads, cfg)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, psum_loss(loss_partial, cfg)
 
     if with_rng:

@@ -190,15 +190,24 @@ def loss_fn(params, stats, images, labels, cfg: ResNetConfig,
 
 def make_train_step(cfg: ResNetConfig, optimizer,
                     axis_name: Optional[str] = "hvd"):
+    # The four parts of the step under ``jax.named_scope``: metadata only
+    # (the HLO is the same without them), and what a device trace shows an
+    # operation to belong to.  ``value_and_grad`` split into its two
+    # halves so that each has its own name.
     def step(params, stats, opt_state, images, labels):
-        (loss_partial, new_stats), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, stats, images, labels, cfg,
-                                   axis_name)
-        if axis_name:
-            grads = jax.tree_util.tree_map(
-                lambda g: lax.psum(g, axis_name), grads)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("forward"):
+            loss_partial, backward, new_stats = jax.vjp(
+                lambda p: loss_fn(p, stats, images, labels, cfg, axis_name),
+                params, has_aux=True)
+        with jax.named_scope("backward"):
+            grads, = backward(jnp.ones_like(loss_partial))
+        with jax.named_scope("gradient_exchange"):
+            if axis_name:
+                grads = jax.tree_util.tree_map(
+                    lambda g: lax.psum(g, axis_name), grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         loss = lax.psum(loss_partial, axis_name) if axis_name else loss_partial
         return params, new_stats, opt_state, loss
 

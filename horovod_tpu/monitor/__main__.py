@@ -113,6 +113,25 @@ def render(dump: dict) -> str:
             lines.append(f"{r:>4}  {_fmt(spans):>5}  "
                          + "  ".join(f"{_fmt(v):>11}" for v in means)
                          + f"  {_fmt(cyc):>9}")
+    # Program spans from the same digests: the phases of the eager update
+    # and of the engine's cycle, by name (docs/timeline.md).
+    names, span_rows = [], []
+    for r in sorted(table, key=lambda k: int(k)):
+        program = (table[r].get("trace") or {}).get("program")
+        if program:
+            names += [n for n in program if n not in names]
+            span_rows.append((r, program))
+    if span_rows:
+        lines.append("")
+        lines.append("program spans, mean us x count (trace digests):")
+        for name in sorted(names):      # a parent before its phases
+            cells = []
+            for r, program in span_rows:
+                total, count = (program.get(name) or [0, 0])[:2]
+                cells.append(f"rank {r}: "
+                             f"{_fmt(round(total / count, 1) if count else None)}"
+                             f" x {count}")
+            lines.append(f"  {name:<22}" + "   ".join(cells))
     return "\n".join(lines)
 
 

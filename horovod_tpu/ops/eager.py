@@ -28,8 +28,23 @@ from .engine import CollectiveType
 from ..common import basics
 from ..common.process_sets import ProcessSet
 
+class _GroupIds:
+    """``itertools.count`` that also shows the id to come: the span layer
+    labels an eager update with the first group it will submit, before it
+    stages anything (a label, so a racing thread may put it off by one)."""
+
+    def __init__(self):
+        self._ids = itertools.count(0)
+        self.upcoming = 0
+
+    def __next__(self) -> int:
+        gid = next(self._ids)
+        self.upcoming = gid + 1
+        return gid
+
+
 _name_counter = itertools.count(0)
-_group_counter = itertools.count(0)
+_group_counter = _GroupIds()
 
 # Auto-generated collective names are part of the negotiation wire protocol:
 # they must be identical on every rank.  init() resets all counters (every
@@ -45,7 +60,7 @@ def register_name_counter_reset(fn):
 def reset_name_counters():
     global _name_counter, _group_counter
     _name_counter = itertools.count(0)
-    _group_counter = itertools.count(0)
+    _group_counter = _GroupIds()
     for fn in _counter_reset_hooks:
         fn()
 
@@ -317,29 +332,12 @@ def grouped_allreduce_async(tensors: Sequence, name: Optional[str] = None,
     priority per member — the group still executes atomically, but its
     position among OTHER clusters in the cycle follows its members'
     priorities."""
-    ps_id = _ps(process_set)
-    comp = _wire_mode(compression)
-    gid = next(_group_counter)
-    base = _auto_name("grouped_allreduce", name)
-    if priorities is not None and len(priorities) != len(tensors):
-        raise ValueError(
-            f"priorities must have one entry per tensor: got "
-            f"{len(priorities)} for {len(tensors)} tensors")
-    items = []
-    for i, t in enumerate(tensors):
-        arr, owned = _as_stacked(t, ps_id)
-        items.append(dict(
-            name=f"{base}.{i}", ctype=CollectiveType.ALLREDUCE, tensor=arr,
-            reduce_op=op, process_set_id=ps_id,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor, group_id=gid, donate=owned,
-            compression=comp,
-            priority=int(priorities[i]) if priorities is not None else 0,
-            hierarchical=hierarchical))
-    # One atomic push: all members negotiate in the same round on every
-    # rank, which both preserves fusion atomicity and lets a negotiation
-    # error on one member abort the whole group (reference N13).
-    return _engine().enqueue_group(items)
+    return _grouped_async(tensors, name, "grouped_allreduce",
+                          CollectiveType.ALLREDUCE, process_set, priorities,
+                          reduce_op=op, prescale_factor=prescale_factor,
+                          postscale_factor=postscale_factor,
+                          compression=_wire_mode(compression),
+                          hierarchical=hierarchical)
 
 
 def grouped_allreduce(tensors: Sequence, name: Optional[str] = None,
@@ -372,15 +370,22 @@ def allgather(tensor, name: Optional[str] = None,
     return _sync_now(allgather_async(tensor, name, process_set))
 
 
-def _grouped_async(tensors, name, prefix, ctype, process_set,
-                   priorities=None, **extra):
-    """Shared grouped-enqueue core (reference N13 atomic groups): one
-    atomic push, every member negotiates/batches together.
+def _stage_group(tensors, name, prefix, ctype, process_set,
+                 priorities=None, **extra):
+    """Stage one atomic group (reference N13): every tensor into the
+    engine's stacked layout, under one fresh group id.  Returns ``(group
+    id, items)``; one ``enqueue_group(items)`` then pushes them
+    atomically, so all members negotiate in the same round on every rank
+    — which both preserves fusion atomicity and lets a negotiation error
+    on one member abort the whole group.  (The eager optimizer paths call
+    the two halves themselves: each is a program span of its own,
+    ``hvd/update/stage`` and ``hvd/update/submit``.)
 
     ``priorities`` (one int per tensor, identical on every rank): drain
-    priority per member, exactly like ``grouped_allreduce_async`` — the
-    sharded optimizer stamps its reduce-scatter/allgather legs with
-    reverse-registration order so first-needed parameters lead."""
+    priority per member — the group still executes atomically, but its
+    position among OTHER clusters in the cycle follows its members'
+    priorities.  The optimizers stamp reverse-registration order so
+    first-needed parameters lead."""
     ps_id = _ps(process_set)
     gid = next(_group_counter)
     base = _auto_name(prefix, name)
@@ -396,7 +401,14 @@ def _grouped_async(tensors, name, prefix, ctype, process_set,
                           priority=int(priorities[i])
                           if priorities is not None else 0,
                           **extra))
-    return _engine().enqueue_group(items)
+    return gid, items
+
+
+def _grouped_async(tensors, name, prefix, ctype, process_set,
+                   priorities=None, **extra):
+    """Stage and enqueue one atomic group; returns its handles."""
+    return _engine().enqueue_group(_stage_group(
+        tensors, name, prefix, ctype, process_set, priorities, **extra)[1])
 
 
 def grouped_allgather_async(tensors: Sequence, name: Optional[str] = None,

@@ -51,6 +51,7 @@ from .scheduler import (  # noqa: F401  (re-export: public engine surface)
     pop_gradient_batches,
 )
 from ..common.exceptions import ControlPlaneError
+from ..trace.core import OFF as _TRACE_OFF
 from ..utils.logging import get_logger
 
 log = get_logger()
@@ -182,6 +183,16 @@ def _live_span(e):
     """The entry's traceable span, or None (untraced / claim dropped)."""
     sp = e.span
     return None if (sp is None or sp is _SPAN_DROPPED) else sp
+
+
+def _span_cycle(batch) -> int:
+    """The negotiation round that readied ``batch`` (the id its entries'
+    ``TensorSpan``s carry), -1 where none of them is traced."""
+    for e in batch:
+        sp = _live_span(e)
+        if sp is not None:
+            return sp.cycle
+    return -1
 
 
 def _np_dtype(name: str) -> np.dtype:
@@ -964,18 +975,34 @@ class CollectiveEngine:
             # otherwise-idle single-controller cycles.)
             return
         tr = self.tracer
-        t_trace0 = t_drain = 0.0
-        if tr is not None:
-            t_drain = time.monotonic()
-            t_trace0 = t_drain - (time.perf_counter() - t_cycle0)
-            for e in entries:
-                if e.span is None:
-                    # queue phase closes at this first drain; requeued
-                    # entries keep their span (still in negotiation).  A
-                    # dropped claim latches the sentinel: claim at most
-                    # once per entry.
-                    e.span = tr.begin(e.name, e.enqueue_time, t_drain) \
-                        or _SPAN_DROPPED
+        if tr is None:
+            self._negotiate_and_dispatch(entries, t_cycle0)
+            return
+        t_drain = time.monotonic()
+        t_trace0 = t_drain - (time.perf_counter() - t_cycle0)
+        for e in entries:
+            if e.span is None:
+                # queue phase closes at this first drain; requeued
+                # entries keep their span (still in negotiation).  A
+                # dropped claim latches the sentinel: claim at most
+                # once per entry.
+                e.span = tr.begin(e.name, e.enqueue_time, t_drain) \
+                    or _SPAN_DROPPED
+        # ``groups``: the ids the calling thread's ``hvd/update/submit``
+        # spans carry ('|'-joined: the profile splits stats at commas).
+        groups = sorted({e.group_id for e in entries if e.group_id >= 0})
+        with tr.span("hvd/cycle", n=len(entries),
+                     groups="|".join(map(str, groups))) as cyc:
+            self._negotiate_and_dispatch(entries, t_cycle0, tr, cyc,
+                                         t_trace0, t_drain)
+
+    def _negotiate_and_dispatch(self, entries, t_cycle0: float, tr=None,
+                                cyc=None, t_trace0: float = 0.0,
+                                t_drain: float = 0.0):
+        """The cycle past the queue's drain: negotiate, batch, dispatch,
+        account.  Tracing armed, ``tr`` is the recorder and ``cyc`` the
+        open ``hvd/cycle`` span."""
+        tl = self._state.timeline
         # Multi-process mode: every rank must complete a (possibly empty)
         # lock-step negotiation round each cycle, or peers with pending
         # tensors would block on this rank's missing frame.
@@ -1019,14 +1046,16 @@ class CollectiveEngine:
         if not_ready:
             self.queue.requeue(not_ready)
         t_ready = 0.0
-        if tr is not None and responses:
-            # Globally-ready verdict: negotiation phase closes.  The cycle
-            # id is the cross-rank correlation key — the controller's
-            # lock-step round counter is identical on every rank for the
-            # same round; single-controller mode uses the local index.
-            t_ready = time.monotonic()
+        if tr is not None:
+            # The cycle id is the cross-rank correlation key — the
+            # controller's lock-step round counter is identical on every
+            # rank for the same round; single-controller mode uses the
+            # local index.
             ctl = self.controller
             cyc_id = ctl.rounds if ctl is not None else self._cycle_index
+            cyc.set(cycle=cyc_id)
+            # Globally-ready verdict: negotiation phase closes.
+            t_ready = time.monotonic()
             for batch in responses:
                 for e in batch:
                     sp = _live_span(e)
@@ -1159,8 +1188,15 @@ class CollectiveEngine:
             # (a deferred verdict is already in every rank's buffer).
             self.controller.spec_dispatch_ok = (
                 not self._serialize_launches and self.max_inflight > 1)
+            tr = self.tracer
             t0 = time.perf_counter()
-            ready, errored = self.controller.negotiate(entries)
+            if tr is None:
+                ready, errored = self.controller.negotiate(entries)
+            else:
+                # the interval negotiation_us_total times, as a span
+                with tr.span("hvd/cycle/negotiate") as sp:
+                    ready, errored = self.controller.negotiate(entries)
+                    sp.set(cycle=self.controller.rounds)
             dt_us = (time.perf_counter() - t0) * 1e6
             self.negotiation_us_total += dt_us
             self.negotiation_cycles += 1
@@ -1202,15 +1238,14 @@ class CollectiveEngine:
                         # reusing the name renegotiates from scratch.
                         self.controller.forget(e)
             tl = self._state.timeline
-            tr0 = self.tracer
             for e, msg in errored:
                 e.error = NegotiationError(msg)
                 if tl is not None:
                     tl.end_activity(e.name, "QUEUE")
-                sp = _live_span(e) if tr0 is not None else None
+                sp = _live_span(e) if tr is not None else None
                 if sp is not None:
                     sp.error = True
-                    tr0.commit(sp)
+                    tr.commit(sp)
                 self.queue.mark_done(e)
                 # A failed entry is finished: clear the stall inspector's
                 # live-stall state (and warn latch) like any completion.
@@ -1360,12 +1395,23 @@ class CollectiveEngine:
             if keys:
                 self._staging_tokens[id(batch)] = [pp.acquire(k)
                                                    for k in keys]
+        tr = self.tracer
         try:
-            results, chunks = self._execute_batch(batch)
+            if tr is None:
+                results, chunks = self._execute_batch(batch)
+            else:
+                # program fetch or build + the async launch; ``hit`` is
+                # the fused-program cache's (a pinned fast-lane program
+                # never asks it, so reads as one).
+                misses = self.cache.misses
+                with tr.span("hvd/cycle/dispatch", cycle=_span_cycle(batch),
+                             n=len(batch),
+                             bytes=self._batch_payload_bytes(batch)) as sp:
+                    results, chunks = self._execute_batch(batch)
+                    sp.set(hit=int(self.cache.misses == misses))
         except BaseException as exc:  # noqa: BLE001 - propagate to waiters
             self._settle_batch(batch, None, exc)
             return 0
-        tr = self.tracer
         if tr is not None:
             # copy_in phase closes: the fused program (fetch/build + the
             # async XLA launch — the fusion copy-in lives inside it) has
@@ -1384,13 +1430,23 @@ class CollectiveEngine:
             self.fast_lane_dispatches += 1
         ring = self._inflight_ring()
         if ring is None:
-            self._settle_batch(batch, results)
+            with self._settle_span(batch):
+                self._settle_batch(batch, results)
         else:
             if tl is not None:
                 for e in batch:
                     tl.start_activity(e.name, "INFLIGHT")
             ring.submit(batch, results)
         return chunks
+
+    def _settle_span(self, batch):
+        """``hvd/settle`` for one batch: from blocking on its results (the
+        in-flight watcher; the inline settle blocks on nothing) to the
+        last ``done.set()``."""
+        tr = self.tracer
+        if tr is None:
+            return _TRACE_OFF
+        return tr.span("hvd/settle", cycle=_span_cycle(batch), n=len(batch))
 
     def _settle_batch(self, batch: List[TensorTableEntry], results,
                       error: Optional[BaseException] = None,
@@ -1454,7 +1510,7 @@ class CollectiveEngine:
                 jax.block_until_ready,
                 lambda b, r, err: self._settle_batch(b, r, err,
                                                      inflight=True),
-                depth=self.max_inflight)
+                depth=self.max_inflight, span=self._settle_span)
             # Double-buffered fusion staging rides the same lifecycle: the
             # ring's watcher is what hands the ping-pong slots back.
             self._pingpong = PingPongBuffers(slots=2)

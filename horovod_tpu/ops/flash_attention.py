@@ -296,6 +296,7 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
                              window=window)
     o, lse = pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=(BH, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -452,6 +453,7 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_k=n_k,
                           tq_valid=Tq, tk_valid=Tk, window=window),
+        name="flash_bwd_dq",
         grid=(BH, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
@@ -479,6 +481,7 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_q=n_q, n_t=rep * n_q,
                           tq_valid=Tq, tk_valid=Tk, window=window),
+        name="flash_bwd_dkv",
         grid=(BK, n_k, rep * n_q),
         in_specs=[
             pl.BlockSpec((1, bq, D), _qix),

@@ -26,6 +26,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from ..trace.core import OFF as _TRACE_OFF
 from ..utils.logging import get_logger
 
 log = get_logger()
@@ -388,10 +389,15 @@ class InflightRing:
     testable without jax.
     """
 
-    def __init__(self, waiter: Callable, settler: Callable, depth: int = 2):
+    def __init__(self, waiter: Callable, settler: Callable, depth: int = 2,
+                 span: Callable = lambda batch: _TRACE_OFF):
         self.depth = max(1, int(depth))
         self._waiter = waiter
         self._settler = settler
+        # ``span(batch)``: a context manager around one batch's wait and
+        # settle on the watcher thread (the engine's ``hvd/settle``
+        # program span; the shared no-op while tracing is disarmed).
+        self._span = span
         self._cv = threading.Condition()
         self._items: deque = deque()
         self._stop = False
@@ -481,40 +487,43 @@ class InflightRing:
                 head = self._items[0]
                 batch, results = head[0], head[1]
                 abort_error = self._abort_error
-            error = None
-            if abort_error is not None:
-                # Control-plane abort: settle with the fault, never block
-                # on device results that may not be coming.
-                error = abort_error
-            else:
-                try:
-                    self._waiter(results)
-                except BaseException as exc:  # noqa: BLE001 - fail waiters
-                    error = exc
-            # Claim the settle atomically: if abort() got here first (it
-            # can run while this thread is wedged in the device wait) the
-            # batch is already settled with the fault — do not re-settle.
-            with self._cv:
-                claimed = not head[2]
-                head[2] = True
-            try:
-                if claimed:
-                    self._settler(batch, results, error)
-            except BaseException:  # noqa: BLE001 - watcher must survive
-                # A raising settler would otherwise kill this thread and
-                # deadlock every later submit against a never-draining
-                # window.  The settler owns waiter release; all the ring
-                # can do is keep the pipeline alive and make the failure
-                # visible.
-                log.exception("in-flight settle failed")
-            finally:
-                # Pop AFTER settling so the window bounds dispatched-but-
-                # unsettled work (a popped-then-settling batch would let
-                # depth+1 launches pile up).
+            with self._span(batch):
+                error = None
+                if abort_error is not None:
+                    # Control-plane abort: settle with the fault, never
+                    # block on device results that may not be coming.
+                    error = abort_error
+                else:
+                    try:
+                        self._waiter(results)
+                    except BaseException as exc:  # noqa: BLE001
+                        # fail the waiters with it
+                        error = exc
+                # Claim the settle atomically: if abort() got here first
+                # (it can run while this thread is wedged in the device
+                # wait) the batch is already settled with the fault — do
+                # not re-settle.
                 with self._cv:
-                    if self._items:
-                        self._items.popleft()
-                    self._cv.notify_all()
+                    claimed = not head[2]
+                    head[2] = True
+                try:
+                    if claimed:
+                        self._settler(batch, results, error)
+                except BaseException:  # noqa: BLE001 - watcher survives
+                    # A raising settler would otherwise kill this thread
+                    # and deadlock every later submit against a never-
+                    # draining window.  The settler owns waiter release;
+                    # all the ring can do is keep the pipeline alive and
+                    # make the failure visible.
+                    log.exception("in-flight settle failed")
+                finally:
+                    # Pop AFTER settling so the window bounds dispatched-
+                    # but-unsettled work (a popped-then-settling batch
+                    # would let depth+1 launches pile up).
+                    with self._cv:
+                        if self._items:
+                            self._items.popleft()
+                        self._cv.notify_all()
 
 
 class StagingToken:

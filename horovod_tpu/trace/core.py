@@ -26,6 +26,18 @@ response-cache **slot id** when one is known.  The merge tool
 (``python -m horovod_tpu.trace``) joins per-rank trace files on the cycle id
 and draws flow arrows tying the same cycle across ranks' lanes.
 
+**Program spans** (:meth:`TraceRecorder.span`, :func:`span`) are the second
+kind of record: one interval of one thread between two of the program's own
+layer boundaries (``hvd/update/stage``, ``hvd/cycle/negotiate``, ...), not
+one tensor's lifecycle.  Armed, a span is a ``jax.profiler.TraceAnnotation``
+(a TraceMe: with a profiler session active it lands in the ``.xplane.pb`` on
+the device operations' clock, on its own thread's line) and its duration is
+added to the recorder's sum and count by name, which ride the summary and
+the digest below so that a fleet without a profiler still gets the totals.
+Disarmed there is no recorder: :func:`span` hands out the shared
+:data:`OFF` and the engine's sites are the ``tracer is None`` check they
+already make.
+
 Compact per-cycle digests (:meth:`TraceRecorder.digest`) ride the existing
 MON1 monitor side-channel inside the agent's JSON snapshot — interval-gated,
 size-capped (``DIGEST_*`` caps below), and version-safe (old peers ignore
@@ -184,6 +196,80 @@ class CycleRecord:
             [int(round(v)) for v in self.phase_us]
 
 
+class _Off:
+    """What :func:`span` hands out while tracing is disarmed: one shared
+    object, entered and left without a record.  ``with span(..) as sp``
+    binds None, so a site that labels its span late (``sp.set``) guards
+    that with the one ``is not None`` check."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, *exc):
+        return False
+
+
+OFF = _Off()
+
+
+class ProgramSpan:
+    """One armed program span (context manager): a TraceMe on the calling
+    thread where the process has jax, and a duration for the recorder's
+    by-name totals.  ``ids`` become the event's stats in the profile;
+    :meth:`set` adds those known only once the span is open (a program
+    cache hit, the round id a negotiation came back with).  The profile
+    splits stats at commas: join a list with another character."""
+
+    __slots__ = ("name", "_rec", "_ann", "_t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str, ids: dict):
+        self.name = name
+        self._rec = rec
+        make = rec.annotation
+        self._ann = make(name, **ids) if make is not None else None
+        self._t0 = 0.0
+
+    def set(self, **ids) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**ids)
+
+    def __enter__(self) -> "ProgramSpan":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt_us = (time.perf_counter() - self._t0) * 1e6
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._rec.add_span(self.name, dt_us)
+        return False
+
+
+# The recorder the calling thread's sites reach through :func:`span`: the
+# one most recently installed (``trace.maybe_install``), None while
+# tracing is disarmed or once that recorder closed.
+_installed: Optional["TraceRecorder"] = None
+
+
+def installed() -> Optional["TraceRecorder"]:
+    return _installed
+
+
+def span(name: str, **ids):
+    """A program span on the calling thread: ``with trace.span("hvd/update/
+    wait", group=gid): ...``.  Disarmed (``HOROVOD_TRACE`` unset, no
+    recorder installed) this is one ``is None`` check and the shared
+    :data:`OFF`."""
+    rec = _installed
+    if rec is None:
+        return OFF
+    return ProgramSpan(rec, name, ids)
+
+
 class TraceRecorder:
     """Preallocated span ring + phase accumulators + optional file writer.
 
@@ -199,8 +285,14 @@ class TraceRecorder:
     _SCAN = 64
 
     def __init__(self, capacity: int = 4096, cycle_capacity: int = 512,
-                 writer=None, rank: int = 0):
+                 writer=None, rank: int = 0, annotation=None):
         self.rank = int(rank)
+        # What opens a program span's TraceMe: jax.profiler.TraceAnnotation
+        # where the installing process has jax (``maybe_install`` resolves
+        # it), None in a jax-free one, which keeps the totals alone.
+        self.annotation = annotation
+        # Program spans by name: [sum_us, count].
+        self._span_totals: Dict[str, List[float]] = {}
         self.capacity = max(16, int(capacity))
         self.cycle_capacity = max(16, int(cycle_capacity))
         self.buckets = PHASE_BUCKETS_US
@@ -258,6 +350,15 @@ class TraceRecorder:
             self.dropped += 1
             return None
 
+    def _count(self, counts: List[int], v: float) -> None:
+        """One observation into a per-bucket count list (the last slot is
+        the +Inf overflow)."""
+        for i, le in enumerate(self.buckets):
+            if v <= le:
+                counts[i] += 1
+                return
+        counts[-1] += 1
+
     def commit(self, span: Optional[TensorSpan]) -> None:
         """Finalize a span: accumulate its phases, fold them into its
         cycle's aggregate, emit it to the trace file.  Idempotent; must
@@ -284,13 +385,7 @@ class TraceRecorder:
             self.lifecycle_us_total += span.lifecycle_us()
             for p, v in phases.items():
                 self._phase_sum[p] += v
-                counts = self._phase_buckets[p]
-                for i, le in enumerate(self.buckets):
-                    if v <= le:
-                        counts[i] += 1
-                        break
-                else:
-                    counts[-1] += 1
+                self._count(self._phase_buckets[p], v)
             frac = span.cross_frac
             if frac > 0.0:
                 # Split the measured reduce duration into the modeled
@@ -300,24 +395,11 @@ class TraceRecorder:
                 for leg, v in ((REDUCE_LEGS[0], red * (1.0 - frac)),
                                (REDUCE_LEGS[1], red * frac)):
                     self._leg_sum[leg] += v
-                    counts = self._leg_buckets[leg]
-                    for i, le in enumerate(self.buckets):
-                        if v <= le:
-                            counts[i] += 1
-                            break
-                    else:
-                        counts[-1] += 1
+                    self._count(self._leg_buckets[leg], v)
             if span.prefetch:
                 self.prefetch_spans += 1
-                v = phases["reduce"]
-                self._prefetch_sum += v
-                counts = self._prefetch_buckets
-                for i, le in enumerate(self.buckets):
-                    if v <= le:
-                        counts[i] += 1
-                        break
-                else:
-                    counts[-1] += 1
+                self._prefetch_sum += phases["reduce"]
+                self._count(self._prefetch_buckets, phases["reduce"])
             rec = self._cycle_by_id.get(span.cycle)
             if rec is not None:
                 rec.n_committed += 1
@@ -342,7 +424,26 @@ class TraceRecorder:
         if w is not None:
             w.cycle(rec)
 
+    def span(self, name: str, **ids) -> ProgramSpan:
+        """A program span on the calling thread (see :func:`span`); the
+        engine's sites open theirs through their recorder."""
+        return ProgramSpan(self, name, ids)
+
+    def add_span(self, name: str, dt_us: float) -> None:
+        with self._lock:
+            tot = self._span_totals.get(name)
+            if tot is None:
+                tot = self._span_totals[name] = [0.0, 0]
+            tot[0] += dt_us
+            tot[1] += 1
+
     # -------------------------------------------------------------- reading
+    def span_totals(self) -> Dict[str, Tuple[float, int]]:
+        """Program spans by name -> (sum_us, count), cumulative."""
+        with self._lock:
+            return {n: (t[0], int(t[1]))
+                    for n, t in self._span_totals.items()}
+
     def open_spans(self, limit: int = DIGEST_MAX_OPEN) -> Dict[str, str]:
         """name -> current phase for in-progress spans (stall/digest)."""
         out: Dict[str, str] = {}
@@ -382,17 +483,24 @@ class TraceRecorder:
         with self._lock:
             n = self.spans_committed
             if not n:
-                return {"spans": 0, "phases_us": None, "cycle_us": None,
-                        "phase_sum_us": None}
-            phases = {p: round(self._phase_sum[p] / n, 2) for p in PHASES}
-            out = {"spans": n, "phases_us": phases,
-                   "cycle_us": round(self.lifecycle_us_total / n, 2),
-                   "phase_sum_us": round(sum(phases.values()), 2)}
+                out = {"spans": 0, "phases_us": None, "cycle_us": None,
+                       "phase_sum_us": None}
+            else:
+                phases = {p: round(self._phase_sum[p] / n, 2)
+                          for p in PHASES}
+                out = {"spans": n, "phases_us": phases,
+                       "cycle_us": round(self.lifecycle_us_total / n, 2),
+                       "phase_sum_us": round(sum(phases.values()), 2)}
             if self.leg_spans:
                 out["leg_spans"] = self.leg_spans
                 out["legs_us"] = {
                     p: round(self._leg_sum[p] / self.leg_spans, 2)
                     for p in REDUCE_LEGS}
+            if self._span_totals:
+                # program spans: mean per span, by name
+                out["program_us"] = {
+                    name: round(t[0] / t[1], 2)
+                    for name, t in self._span_totals.items()}
             return out
 
     def digest(self) -> dict:
@@ -405,8 +513,14 @@ class TraceRecorder:
             legs = {p: [int(round(self._leg_sum[p])), self.leg_spans]
                     for p in REDUCE_LEGS} if self.leg_spans else None
             n, total = self.spans_committed, self.lifecycle_us_total
+        program = {name: [int(round(sum_us)), count]
+                   for name, (sum_us, count) in self.span_totals().items()}
         out = {"v": 1, "spans": n, "phases": phases, "cycles": cycles,
                "dropped": self.dropped}
+        if program:
+            # Program spans by name: [sum_us, count].  Like ``legs``, a
+            # key old peers ignore.
+            out["program"] = program
         if legs:
             # Appears only once the two-level path engaged; old peers
             # ignore unknown digest keys (version-safe).
@@ -419,6 +533,9 @@ class TraceRecorder:
         return out
 
     def close(self) -> None:
+        global _installed
+        if _installed is self:
+            _installed = None
         w, self._writer = self._writer, None
         if w is not None:
             w.close()

@@ -1,0 +1,71 @@
+"""``jax.named_scope`` around the four parts of the step programs
+(``models/resnet.py``, ``models/llama.py``): names for a device trace, and
+nothing else.  Each step is lowered and compiled at test size with the
+scopes and with ``jax.named_scope`` turned into a no-op; the two HLO texts
+must be equal once the metadata is taken out, and the scoped one must name
+every part."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from horovod_tpu.parallel import make_mesh, spmd
+from horovod_tpu.parallel.mesh import infer_mesh
+
+SCOPES = ("forward", "backward", "gradient_exchange", "optimizer")
+METADATA = re.compile(r",? ?metadata=\{[^}]*\}")
+# the module's tables of source files, functions and stack frames, which
+# the instructions' metadata points into
+TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                    r"(\d+ .*\n)*", re.M)
+
+
+def stripped(hlo_text):
+    return METADATA.sub("", TABLES.sub("", hlo_text))
+
+
+def resnet_step():
+    from horovod_tpu.models import resnet
+    cfg = resnet.ResNetConfig(depth=18, num_classes=10, width=8,
+                              compute_dtype=jnp.float32)
+    mesh = make_mesh({"hvd": 8})
+    params, stats = resnet.init_params(cfg, jax.random.PRNGKey(0))
+    opt = optax.sgd(0.05, momentum=0.9)
+    x, y = resnet.synthetic_batch(16, image_size=32, num_classes=10)
+    step = resnet.make_sharded_train_step(cfg, opt, mesh)
+    return step.lower(params, stats, opt.init(params), jnp.asarray(x),
+                      jnp.asarray(y))
+
+
+def llama_step():
+    from horovod_tpu.models import llama
+    cfg = llama.tiny(dtype=jnp.float32)
+    mesh = infer_mesh(8, tp=2, sp=1)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    pspecs = llama.param_specs(cfg)
+    opt = optax.adam(1e-3)
+    opt_state = opt.init(params)
+    step = spmd.make_sharded_train_step(
+        llama.make_train_step(cfg, opt), mesh, pspecs,
+        spmd.infer_specs_like(opt_state, params, pspecs),
+        P(("dp", "ep", "pp"), "sp"))
+    tokens = jnp.zeros((8, 16), jnp.int32)
+    return step.lower(spmd.shard_params(params, pspecs, mesh), opt_state,
+                      tokens, tokens)
+
+
+@pytest.mark.parametrize("lower", [resnet_step, llama_step])
+def test_named_scopes_change_metadata_only(lower, monkeypatch):
+    scoped = lower().compile().as_text()
+    for scope in SCOPES:
+        assert re.search(r'op_name="[^"]*\b%s\b' % scope, scoped), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = lower().compile().as_text()
+    assert not re.search(r'op_name="[^"]*\b(%s)/' % "|".join(SCOPES), bare)
+    assert stripped(scoped) == stripped(bare)
