@@ -36,6 +36,14 @@ from .compression import Compression
 from .. import trace
 from ..ops import collectives as C
 from ..common.process_sets import ProcessSet
+from ..utils.logging import get_logger
+
+log = get_logger()
+
+
+def _any_tracer(tree) -> bool:
+    return any(isinstance(l, jax.core.Tracer)
+               for l in jax.tree_util.tree_leaves(tree))
 
 
 def _axis_in_scope(axis_name) -> bool:
@@ -84,7 +92,7 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
     from ..ops.engine import CollectiveType
     if not eager.per_process_mode():
         return grads  # single-controller SPMD: params/grads already global
-    if any(isinstance(l, jax.core.Tracer) for l in leaves):
+    if _any_tracer(leaves):
         raise RuntimeError(
             "allreduce_gradients was traced under jax.jit without a bound "
             f"mesh axis {axis_name!r} in a multi-process world: the reduce "
@@ -149,8 +157,7 @@ def _update_span(grads=None, axis_name=None):
     from ..ops import eager
     if grads is not None and (
             _axis_in_scope(axis_name) or not eager.per_process_mode()
-            or any(isinstance(l, jax.core.Tracer)
-                   for l in jax.tree_util.tree_leaves(grads))):
+            or _any_tracer(grads)):
         return trace.OFF
     return trace.span("hvd/update", step=next(_update_steps),
                       group=eager._group_counter.upcoming)
@@ -160,6 +167,63 @@ def _inner_span(up):
     """``hvd/update/inner`` (the wrapped optimizer's update), under an
     open ``hvd/update`` only."""
     return trace.OFF if up is None else trace.span("hvd/update/inner")
+
+
+# What JAX raises when a function being traced needs a value: a
+# transformation that branches on one cannot be compiled.
+# (``TracerBoolConversionError`` is a ``ConcretizationTypeError``.)
+_NEEDS_A_VALUE = (jax.errors.ConcretizationTypeError,
+                  jax.errors.TracerArrayConversionError,
+                  jax.errors.TracerIntegerConversionError)
+
+
+class _InnerUpdate:
+    """The wrapped optimizer's ``update`` for one ``DistributedOptimizer``:
+    one ``jax.jit`` of it, built at wrap time (jit's cache keys on tree
+    structure, shapes and dtypes), entered whenever gradients, state and
+    parameters are all concrete, so that an eager step runs the whole
+    transformation as one program instead of three dispatches a leaf.
+    Under a trace (``jit`` / ``shard_map`` step programs) the call is
+    ``optimizer.update`` itself and the step's HLO is what it was.  No
+    donation: callers may hold the old state.  A compiled update is not
+    bitwise the op-by-op one (XLA contracts ``g + mu * t``), so every
+    eager path goes through here and they agree with each other
+    (docs/performance.md "The eager path").
+
+    A transformation that cannot be traced (it branches on a value) takes
+    the direct call from its first concretization error on; any other
+    exception propagates."""
+
+    def __init__(self, optimizer: optax.GradientTransformation):
+        self._direct = optimizer.update
+
+        def hvd_inner_update(grads, state, params):
+            trace.inner_update["traces"] += 1       # Python: once a trace
+            return optimizer.update(grads, state, params)
+
+        self._compiled: Optional[Callable] = jax.jit(hvd_inner_update)
+
+    def __call__(self, grads, state, params, span=trace.OFF):
+        """``(updates, new inner state)``, under ``span`` (``hvd/update/
+        inner`` or the no-op), which gets ``compiled=1|0``."""
+        with span as sp:
+            compiled = self._compiled is not None \
+                and not _any_tracer((grads, state, params))
+            if compiled:
+                try:
+                    out = self._compiled(grads, state, params)
+                    trace.inner_update["compiled"] += 1
+                except _NEEDS_A_VALUE as e:
+                    log.warning(
+                        "DistributedOptimizer: the wrapped optimizer's "
+                        "update cannot be compiled (%s); this wrapper runs "
+                        "it op by op from here on", type(e).__name__)
+                    self._compiled, compiled = None, False
+            if not compiled:
+                out = self._direct(grads, state, params)
+            if sp is not None:
+                sp.set(compiled=int(compiled))
+        return out
 
 
 def _nbytes(arrs) -> int:
@@ -541,7 +605,7 @@ def _sharded_eager_init(optimizer, params, process_set, chunk_bytes):
     return ShardedOptimizerState(inner_states, plan, process_set)
 
 
-def _sharded_eager_update(optimizer, grads,
+def _sharded_eager_update(inner: _InnerUpdate, grads,
                           state: ShardedOptimizerState, params,
                           op: C.ReduceOp,
                           process_set: Optional[ProcessSet]):
@@ -588,9 +652,8 @@ def _sharded_eager_update(optimizer, grads,
             p_shards = tuple(
                 _device_shard(jnp.asarray(p_leaves[i]), plan.pads[i],
                               plan.pers[i], rank) for i in idxs)
-        with trace.span("hvd/update/inner"):
-            updates_b, inner_b = optimizer.update(
-                g_shards, state.inner_states[b], p_shards)
+        updates_b, inner_b = inner(g_shards, state.inner_states[b],
+                                   p_shards, trace.span("hvd/update/inner"))
         new_inner.append(inner_b)
         # Phase 3 (overlapped): this bucket's updated deltas start their
         # allgather while later buckets are still scattering/updating.
@@ -680,7 +743,8 @@ def _full_sharded_eager_init(optimizer, params, process_set, chunk_bytes):
                             param_shards, treedef)
 
 
-def _full_sharded_eager_update(optimizer, grads, state: FullShardedState,
+def _full_sharded_eager_update(inner: _InnerUpdate, grads,
+                               state: FullShardedState,
                                op: C.ReduceOp,
                                process_set: Optional[ProcessSet]):
     """The FSDP backward half: per-bucket **reduce-scatter straight into
@@ -721,10 +785,11 @@ def _full_sharded_eager_update(optimizer, grads, state: FullShardedState,
     for b, idxs in enumerate(plan.buckets):
         g_shards = _wait_shards(plan, idxs, *rs[b])
         p_shards = state.param_shards[b]
-        with trace.span("hvd/update/inner"):
-            updates_b, inner_b = optimizer.update(
-                g_shards, state.inner_states[b], p_shards)
-            shards_b = tuple(optax.apply_updates(p_shards, updates_b))
+        updates_b, inner_b = inner(g_shards, state.inner_states[b],
+                                   p_shards, trace.span("hvd/update/inner"))
+        # Applied outside the compiled update, as the replicated path's
+        # caller applies it: ``updates`` is rounded before it is added.
+        shards_b = tuple(optax.apply_updates(p_shards, updates_b))
         new_inner.append(inner_b)
         new_shards.append(shards_b)
     td = state.treedef if state.treedef is not None else treedef
@@ -746,6 +811,7 @@ def _make_sharded(optimizer: optax.GradientTransformation,
     records which mode AND which stage initialized it, so init and
     update can never silently mix modes."""
     from ..parallel import zero
+    inner = _InnerUpdate(optimizer)
 
     def _chunk_bytes() -> int:
         from ..common import basics
@@ -782,11 +848,11 @@ def _make_sharded(optimizer: optax.GradientTransformation,
                                                          params)
         if isinstance(state, FullShardedState):
             with _update_span():
-                return _full_sharded_eager_update(optimizer, grads, state,
+                return _full_sharded_eager_update(inner, grads, state,
                                                   op, process_set)
         if isinstance(state, ShardedOptimizerState):
             with _update_span():
-                return _sharded_eager_update(optimizer, grads, state, params,
+                return _sharded_eager_update(inner, grads, state, params,
                                              op, process_set)
         if _axis_in_scope(axis_name) and compat_axis_size(axis_name) > 1:
             # Mixed modes: a plain state initialized OUTSIDE the mesh axis
@@ -801,7 +867,7 @@ def _make_sharded(optimizer: optax.GradientTransformation,
                 "shard_map context (or build the state with "
                 "parallel.zero.init_sharded_state and pass its specs) so "
                 "the state is the sharded 1/world layout")
-        return optimizer.update(grads, state, params)
+        return inner(grads, state, params)
 
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -900,6 +966,8 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         return _make_sharded(optimizer, op, axis_name, process_set,
                              full=sharded == "full")
 
+    run_inner = _InnerUpdate(optimizer)
+
     def init_fn(params):
         inner = optimizer.init(params)
         if k == 1:
@@ -919,9 +987,8 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     def _update(grads, state: _DistOptState, params, up):
         if k == 1:
             reduced = _reduce(grads)
-            with _inner_span(up):
-                updates, inner = optimizer.update(reduced, state.inner_state,
-                                                  params)
+            updates, inner = run_inner(reduced, state.inner_state, params,
+                                       _inner_span(up))
             return updates, _DistOptState(inner, (), state.counter + 1)
 
         acc = jax.tree_util.tree_map(lambda a, g: a + g, state.acc, grads)
@@ -931,8 +998,8 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         def _do_apply_concrete(acc_, inner_):
             mean_acc = jax.tree_util.tree_map(lambda a: a / k, acc_)
             reduced = _reduce(mean_acc)
-            with _inner_span(up):
-                updates, new_inner = optimizer.update(reduced, inner_, params)
+            updates, new_inner = run_inner(reduced, inner_, params,
+                                           _inner_span(up))
             zeroed = jax.tree_util.tree_map(jnp.zeros_like, acc_)
             return updates, new_inner, zeroed
 

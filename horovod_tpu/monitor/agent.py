@@ -35,6 +35,7 @@ from typing import Dict, List, Optional
 from .aggregator import RankAggregator
 from .registry import MetricRegistry
 from ..trace.core import PHASES as _TRACE_PHASES
+from ..trace.core import inner_update as _INNER_UPDATE
 from ..utils.logging import get_logger
 
 log = get_logger()
@@ -136,6 +137,15 @@ class MonitorAgent:
             reg.counter("hvd_pipeline_dispatches_total",
                         "fused batches dispatched").set_total(
                 getattr(engine, "pipeline_dispatches", 0))
+            # The wrapped optimizer's eager update (jax/optimizer.py):
+            # calls through a wrapper's compiled callable, and traces of
+            # it — traces rising with calls is a retrace every step.
+            reg.counter("hvd_inner_update_compiled_total",
+                        "eager inner updates run as one compiled program"
+                        ).set_total(_INNER_UPDATE["compiled"])
+            reg.counter("hvd_inner_update_traces_total",
+                        "traces of the compiled inner update").set_total(
+                _INNER_UPDATE["traces"])
             # FSDP prefetch lane (ISSUE 18): dispatches count allgather
             # batches routed through the PREFETCH lane; overlapped counts
             # the ones issued while an earlier bucket was still unsettled

@@ -259,6 +259,14 @@ def installed() -> Optional["TraceRecorder"]:
     return _installed
 
 
+# Process-wide counts of the compiled inner update (``jax/optimizer.py``):
+# ``compiled`` eager calls that went through a wrapper's compiled callable,
+# ``traces`` of that callable, counted in Python from inside the traced
+# function — a retrace every step reads ``traces == compiled``.  Kept with
+# tracing armed or not; ``monitor/agent.py`` exports them.
+inner_update = {"compiled": 0, "traces": 0}
+
+
 def span(name: str, **ids):
     """A program span on the calling thread: ``with trace.span("hvd/update/
     wait", group=gid): ...``.  Disarmed (``HOROVOD_TRACE`` unset, no

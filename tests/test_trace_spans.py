@@ -235,7 +235,7 @@ def within(inner, outer):
     ("hvd/update/submit", {"group"}),
     ("hvd/update/wait", {"group"}),
     ("hvd/update/unpack", {"n", "bytes"}),
-    ("hvd/update/inner", set()),
+    ("hvd/update/inner", {"compiled"}),
 ])
 def test_calling_thread_span(traced, name, ids):
     spans = named(traced, name)
@@ -250,6 +250,8 @@ def test_calling_thread_span(traced, name, ids):
         if "n" in ids:
             assert sp["ids"]["n"] == traced["leaves"]
             assert sp["ids"]["bytes"] == traced["bytes"]
+        if "compiled" in ids:       # optax.sgd traces: the one program
+            assert sp["ids"]["compiled"] == 1
     if name == "hvd/update":
         steps = [u["ids"]["step"] for u in updates]
         assert steps == list(range(steps[0], steps[0] + UPDATES))
