@@ -6,7 +6,7 @@ framework deps) eagerly.
 """
 
 _FAMILIES = ("llama", "gpt2", "bert", "vit", "resnet", "moe", "dlrm",
-             "mnist", "convert")
+             "mnist", "convert", "qwen3_next")
 
 __all__ = list(_FAMILIES)
 

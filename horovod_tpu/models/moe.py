@@ -28,6 +28,7 @@ axis — shard over ``ep`` with ``param_specs``.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -244,6 +245,177 @@ def moe_ffn(x, params, cfg: MoEConfig,
 
     y = jnp.einsum("sec,ecd->sd", combine.astype(x.dtype), out)
     return y, aux.astype(jnp.float32), z_loss.astype(jnp.float32)
+
+
+# ------------------------------------------------- dropless share-aware layer
+@dataclasses.dataclass(frozen=True)
+class DroplessMoEConfig:
+    """A dropless top-k expert layer that is told which experts it holds.
+
+    The router keeps the model's full width (``n_experts``) and its
+    ``top_k``; this rank holds experts ``first_expert .. first_expert +
+    experts_held`` and computes the part of the layer's result that they
+    give for the tokens routed to them.  What the other experts would add
+    is another rank's part: summed over all shares (the shared expert
+    counted once) the parts are the whole layer.  No exchange is made
+    here — one share on one chip runs as it stands; the all-to-all that
+    brings every rank's tokens to a share is ROADMAP queue 2 A's."""
+    d_model: int = 64
+    d_ff: int = 128                     # a routed expert's width
+    n_experts: int = 8                  # the router's width
+    top_k: int = 2
+    first_expert: int = 0
+    experts_held: Optional[int] = None  # None = all of them
+    d_shared: int = 0                   # the shared expert's width, 0 = none
+    dtype: Any = jnp.float32
+
+    @property
+    def held(self) -> int:
+        return self.n_experts if self.experts_held is None \
+            else self.experts_held
+
+    def __post_init__(self):
+        if not 1 <= self.top_k <= self.n_experts:
+            raise ValueError(f"top_k={self.top_k} must be in "
+                             f"[1, {self.n_experts}]")
+        if not (0 <= self.first_expert
+                and self.first_expert + self.held <= self.n_experts
+                and self.held >= 1):
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.held}"
+                f" are not among the {self.n_experts}")
+
+
+def dropless_init_params(cfg: DroplessMoEConfig, key) -> Dict:
+    kr, k1, k2, k3, ks = jax.random.split(key, 5)
+    E, H, D, F = cfg.n_experts, cfg.held, cfg.d_model, cfg.d_ff
+
+    def dense(k, fan_in, shape):
+        return (jax.random.normal(k, shape, jnp.float32)
+                / np.sqrt(fan_in)).astype(cfg.dtype)
+
+    p = {"router": dense(kr, D, (D, E)), "w1": dense(k1, D, (H, D, F)),
+         "w3": dense(k3, D, (H, D, F)), "w2": dense(k2, F, (H, F, D))}
+    if cfg.d_shared:
+        s1, s3, s2, sg = jax.random.split(ks, 4)
+        p.update(shared_w1=dense(s1, D, (D, cfg.d_shared)),
+                 shared_w3=dense(s3, D, (D, cfg.d_shared)),
+                 shared_w2=dense(s2, cfg.d_shared, (cfg.d_shared, D)),
+                 shared_gate=dense(sg, D, (D,)))
+    return p
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(x, order, inverse, top_k):
+    """Rows of ``x [S, D]`` in the order of the sorted assignments,
+    ``[S * top_k, D]``: row ``i`` is token ``order[i] // top_k``.  Linear;
+    its transpose is :func:`_sum_rows`, and each is the other's backward
+    pass, so that both directions are gathers (the transpose JAX derives
+    for a gather with repeated rows is a scatter-add)."""
+    return x[order // top_k]
+
+
+def _gather_rows_fwd(x, order, inverse, top_k):
+    return _gather_rows(x, order, inverse, top_k), (order, inverse)
+
+
+def _gather_rows_bwd(top_k, res, g):
+    order, inverse = res
+    return _sum_rows(g, order, inverse, top_k), None, None
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sum_rows(y, order, inverse, top_k):
+    """``[S * top_k, D]`` in sorted order back to ``[S, D]``: each token
+    the sum of its ``top_k`` rows (:func:`_gather_rows`' transpose)."""
+    return y[inverse].reshape(-1, top_k, y.shape[-1]).sum(axis=1)
+
+
+def _sum_rows_fwd(y, order, inverse, top_k):
+    return _sum_rows(y, order, inverse, top_k), (order, inverse)
+
+
+def _sum_rows_bwd(top_k, res, g):
+    order, inverse = res
+    return _gather_rows(g, order, inverse, top_k), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def dropless_route(x, router_w, cfg: DroplessMoEConfig):
+    """``(ids [S, top_k], weights [S, top_k] float32)``: softmax over ALL
+    ``n_experts`` in float32 (the product at ``HIGHEST`` precision: which
+    expert is tenth hangs on it), the ``top_k`` largest renormalised to
+    sum to 1."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    top, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    return ids, top / jnp.sum(top, axis=-1, keepdims=True)
+
+
+def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
+    """The share's part of the layer for tokens ``x [S, D]``.
+
+    Returns ``(y [S, D], held_counts [experts_held] int32)``: ``y`` is the
+    routed part of the experts held here plus the shared expert (where
+    ``d_shared``), ``held_counts`` the assignments that landed on each held
+    expert.  Nothing is dropped for any routing: the assignments are
+    sorted by expert, the held ones first, into a buffer of all ``S *
+    top_k`` rows (the most that can land here), and the grouped products
+    (``lax.ragged_dot``) compute the rows the held experts' groups cover.
+    Static shapes; between no assignment here and all of them the result
+    is exact.
+    """
+    K, H = cfg.top_k, cfg.held
+    with jax.named_scope("moe/route"):
+        ids, weights = dropless_route(x, params["router"], cfg)
+        local = ids.reshape(-1) - cfg.first_expert          # [S * K]
+        here = (local >= 0) & (local < H)
+        # held assignments first, by expert; the others after every group
+        keys = jnp.where(here, local, H)
+        order = jnp.argsort(keys, stable=True)
+        inverse = jnp.argsort(order)
+        held_counts = jnp.sum(
+            keys[:, None] == jnp.arange(H, dtype=keys.dtype)[None, :],
+            axis=0, dtype=jnp.int32)
+        gate, here = weights.reshape(-1)[order], here[order][:, None]
+
+    def grouped(lhs, rhs):
+        """The held experts' groups of rows times their matrices.  Rows
+        past the groups belong to other shares, and the grouped product
+        leaves them as they were in both passes (uninitialised memory, NaN
+        at times): they are zeroed where they go in and where they come
+        out, and so is their gradient."""
+        lhs = jnp.where(here, lhs, 0)
+        return jnp.where(here, lax.ragged_dot(lhs, rhs, held_counts), 0)
+
+    with jax.named_scope("moe/dispatch"):
+        rows = _gather_rows(x, order, inverse, K)           # [S * K, D]
+    with jax.named_scope("moe/experts"):
+        hidden = jax.nn.silu(grouped(rows, params["w1"])) * grouped(
+            rows, params["w3"])
+        # the router's weight on the narrow side of the down projection
+        hidden = (hidden.astype(jnp.float32) * gate[:, None]).astype(x.dtype)
+        out = grouped(hidden, params["w2"])
+    with jax.named_scope("moe/combine"):
+        y = _sum_rows(out, order, inverse, K)
+    if cfg.d_shared:
+        with jax.named_scope("moe/shared"):
+            hidden = jax.nn.silu(x @ params["shared_w1"]) * (
+                x @ params["shared_w3"])
+            # One logit a token decides a whole row: it and the row it
+            # weighs stay float32 until they are multiplied (rounded to
+            # the storage type first, they are most of what the gate's own
+            # gradient, a sum that all but cancels, is off by).
+            gate = jax.nn.sigmoid(jnp.dot(
+                x, params["shared_gate"],
+                preferred_element_type=jnp.float32))[:, None]
+            y = y + (gate * jnp.dot(
+                hidden, params["shared_w2"],
+                preferred_element_type=jnp.float32)).astype(x.dtype)
+    return y, held_counts
 
 
 # ----------------------------------------------------------- tiny LM model

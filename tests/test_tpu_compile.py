@@ -63,12 +63,14 @@ def _kernels(hlo_text):
     return sorted(found)
 
 
-# head_dim 64 and 128, GQA everywhere; causal, one windowed, one non-causal.
+# head_dim 64, 128 and 256 (qwen3_next's full-attention layer: 16 query and
+# 2 key-value heads), GQA everywhere; causal, one windowed, one non-causal.
 FLASH_CASES = [
     pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
     pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
     pytest.param(128, 32, 8, True, 1024, id="d128-h32k8-window1024"),
     pytest.param(64, 8, 4, False, None, id="d64-h8k4-noncausal"),
+    pytest.param(256, 16, 2, True, None, id="d256-h16k2-causal"),
 ]
 
 
@@ -146,3 +148,53 @@ def test_ring_attention_partitions_over_sp4(mesh4, causal):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+def test_dropless_expert_layer_compiles_for_v5e(one_chip):
+    """The share-aware expert layer at the published widths (64 of 512
+    experts held, top-10, 4096 tokens), forward and backward: the grouped
+    products have to stay the TPU's own grouped-matmul kernels
+    (``ragged-dot-...`` custom calls), which skip the rows past the held
+    groups; a dense fallback would cost 64 times the work."""
+    from horovod_tpu.models import moe
+
+    cfg = moe.DroplessMoEConfig(d_model=2048, d_ff=512, n_experts=512,
+                                top_k=10, first_expert=0, experts_held=64,
+                                d_shared=512, dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda k: moe.dropless_init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+
+    def loss(p, x):
+        return moe.dropless_moe_ffn(x, p, cfg)[0].astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        at(params), jax.ShapeDtypeStruct((4096, 2048), jnp.bfloat16,
+                                         sharding=one_chip)).compile()
+    text = compiled.as_text()
+    # w1, w3, w2 forward; six transposes backward
+    assert text.count("%ragged-dot-none") >= 9
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+
+def test_chunked_delta_rule_compiles_for_v5e(one_chip):
+    """The chunked gated delta rule at the published head sizes (32 heads
+    of 128 x 128, chunk 64), forward and backward."""
+    from horovod_tpu.models.qwen3_next import chunked_gated_delta_rule
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    qkv = spec((1, 2048, 32, 128), jnp.bfloat16)
+    gate = spec((1, 2048, 32), jnp.float32)
+
+    def loss(q, k, v, g, beta):
+        return chunked_gated_delta_rule(q, k, v, g, beta, 64).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        qkv, qkv, qkv, gate, gate).compile()
+    assert "while" in compiled.as_text()        # the scan over chunk states
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
