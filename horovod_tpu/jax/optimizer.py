@@ -129,13 +129,20 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
         compression=eager._wire_mode(wire))
     reduced = _wait(gid, handles)
     with trace.span("hvd/update/unpack") as sp:
-        out = [jnp.asarray(eager.to_local(r)).reshape(a.shape)
-               .astype(a.dtype) for r, a in zip(reduced, arrs)]
+        out, host = [], 0
+        for r, a in zip(reduced, arrs):
+            # The fused program returns each leaf replicated, in its own
+            # shape and dtype: the buffer this chip holds is the result.
+            local = eager._local_shard(r)
+            if local is None:
+                host += 1
+                local = eager.local_array(r)
+            out.append(_as_leaf(local, a.shape, a.dtype))
         if comp is not None:
             out = [compression.decompress(r, c[1])
                    for r, c in zip(out, comp)]
         if sp is not None:
-            sp.set(n=len(out), bytes=_nbytes(out))
+            sp.set(n=len(out), bytes=_nbytes(out), host=host)
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
@@ -228,6 +235,19 @@ class _InnerUpdate:
 
 def _nbytes(arrs) -> int:
     return sum(int(a.nbytes) for a in arrs)
+
+
+def _as_leaf(a, shape, dtype, size: Optional[int] = None):
+    """Device array ``a`` as a leaf: its first ``size`` elements (a padded
+    flat gather), in ``shape`` and ``dtype``.  Each step runs only where
+    it changes something: one that does not still costs a call a leaf."""
+    if size is not None and a.size != size:
+        a = a.reshape(-1)[:size]
+    if a.shape != tuple(shape):
+        a = a.reshape(shape)
+    if a.dtype != jnp.dtype(dtype):
+        a = a.astype(dtype)
+    return a
 
 
 def _stage_submit(make, name: str, prefix: str, ctype, process_set,
@@ -435,10 +455,9 @@ class FullShardedState(ShardedOptimizerState):
                 dispatch(b + depth)     # before bucket b synchronizes
                 eng.kick()
             for i, h in handles[b].items():
-                full = np.asarray(eager.to_local(eager.synchronize(h)))
-                full = full.reshape(-1)[:plan.sizes[i]]
-                out[i] = jnp.asarray(full.reshape(plan.shapes[i])) \
-                    .astype(plan.dtypes[i])
+                out[i] = _as_leaf(
+                    eager.local_array(eager.synchronize(h)),
+                    plan.shapes[i], plan.dtypes[i], plan.sizes[i])
         for i in range(nl):
             if out[i] is None:
                 out[i] = jnp.zeros(plan.shapes[i], plan.dtypes[i])
@@ -676,10 +695,9 @@ def _sharded_eager_update(inner: _InnerUpdate, grads,
                 if plan.pers[i] == 0:
                     out[i] = jnp.zeros(plan.shapes[i], plan.dtypes[i])
                     continue
-                flat = np.asarray(eager.to_local(full[i]))
-                flat = flat.reshape(-1)[:plan.sizes[i]]
-                out[i] = jnp.asarray(flat.reshape(plan.shapes[i])) \
-                    .astype(plan.dtypes[i])
+                out[i] = _as_leaf(eager.local_array(full[i]),
+                                  plan.shapes[i], plan.dtypes[i],
+                                  plan.sizes[i])
     updates = jax.tree_util.tree_unflatten(treedef, out)
     return updates, ShardedOptimizerState(new_inner, plan, process_set)
 
@@ -716,8 +734,8 @@ def _wait_shards(plan: _ShardPlan, idxs, gid: int, handles: dict):
     res = _wait_by_leaf(gid, handles)
     with trace.span("hvd/update/unpack"):
         return tuple(
-            jnp.asarray(eager.to_local(res[i])).reshape(-1)
-            .astype(plan.dtypes[i]) if plan.pers[i] > 0
+            _as_leaf(eager.local_array(res[i]), (plan.pers[i],),
+                     plan.dtypes[i]) if plan.pers[i] > 0
             else jnp.zeros((0,), plan.dtypes[i])
             for i in idxs)
 

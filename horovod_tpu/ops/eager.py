@@ -236,6 +236,33 @@ def _index_key(index):
                  for sl in index)
 
 
+def _local_shard(result):
+    """The one device buffer this process holds of ``result``, or ``None``
+    where there is no such buffer: not a ``jax.Array``, or addressable
+    shards of several distinct indices (a process that drives several
+    devices and got a stacked sharded result)."""
+    if not isinstance(result, jax.Array):
+        return None
+    shards = result.addressable_shards
+    if len({_index_key(s.index) for s in shards}) != 1:
+        return None
+    return shards[0].data
+
+
+def local_array(result):
+    """:func:`to_local` without leaving the device: this process's view of
+    a collective result as a ``jax.Array``.
+
+    With one process to a chip every result is a single buffer already in
+    this chip's memory (replicated: the whole; stacked sharded: this
+    rank's ``[1, ...]`` slice), and that buffer is returned as a committed
+    single-device array: no copy, nothing waits for the device.  Otherwise
+    the values come through the host (``to_local``).  Same values either
+    way; ask :func:`to_local` for a NumPy array."""
+    shard = _local_shard(result)
+    return jnp.asarray(to_local(result)) if shard is None else shard
+
+
 def stack_per_rank(values: Sequence, process_set: Optional[ProcessSet] = None):
     """Stack one value per rank into the collective input representation.
 

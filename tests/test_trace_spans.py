@@ -234,7 +234,7 @@ def within(inner, outer):
     ("hvd/update/stage", {"n", "bytes"}),
     ("hvd/update/submit", {"group"}),
     ("hvd/update/wait", {"group"}),
-    ("hvd/update/unpack", {"n", "bytes"}),
+    ("hvd/update/unpack", {"n", "bytes", "host"}),
     ("hvd/update/inner", {"compiled"}),
 ])
 def test_calling_thread_span(traced, name, ids):
@@ -250,6 +250,8 @@ def test_calling_thread_span(traced, name, ids):
         if "n" in ids:
             assert sp["ids"]["n"] == traced["leaves"]
             assert sp["ids"]["bytes"] == traced["bytes"]
+        if "host" in ids:           # every leaf handed over on the device
+            assert sp["ids"]["host"] == 0
         if "compiled" in ids:       # optax.sgd traces: the one program
             assert sp["ids"]["compiled"] == 1
     if name == "hvd/update":
