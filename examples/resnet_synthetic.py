@@ -12,8 +12,9 @@ Two step modes:
   training loop exercises.
 - ``--step-mode spmd`` (single-process, >=1 local devices): the whole step —
   gradients, ``psum`` allreduce, parameter update — is one jitted
-  ``shard_map`` over the device mesh, the TPU-first fused path
-  (``bench.py`` measures MFU with this mode on the real chip).
+  ``shard_map`` over the device mesh, the TPU-first fused path (the
+  benchmark's ``resnet50-spmd-1c`` cell runs this mode on the chip;
+  ``--step-mode eager`` is ``resnet50-eager-1c`` / ``-np4``).
 
 Run::
 

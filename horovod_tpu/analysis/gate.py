@@ -34,7 +34,7 @@ import traceback
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-SCOPE = ("horovod_tpu", "examples", "tools", "bench.py")
+SCOPE = ("horovod_tpu", "examples", "tools")
 BASELINE = os.path.join("tools", "lint_baseline.json")
 
 

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives (no jax at import).
 
 One rule for every entry point (``hvd.init()`` on an accelerator, hence
-``chip_smoke.py``, ``bench.py`` and the workers ``torovodrun`` starts):
+``chip_smoke.py``, ``benchmark/`` and the workers ``torovodrun`` starts):
 where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and the
 program sets nothing; where it is not, the cache goes to ``.jax_cache/``
 beside the package (git-ignored).  The path is part of no key but a

@@ -188,8 +188,9 @@ class Config:
     # HOROVOD_TRACE=<path> arms per-tensor lifecycle spans AND writes this
     # rank's trace file there (the launcher suffixes the base per rank;
     # merge with `python -m horovod_tpu.trace`); HOROVOD_TRACE=1 arms the
-    # in-memory recorder only (digests still ride the monitor side-channel,
-    # bench reads the phase breakdown).  Unset = strictly zero cost.
+    # in-memory recorder only (digests still ride the monitor side-channel;
+    # benchmark/ reads the program spans of a --trace 1 run).  Unset =
+    # strictly zero cost.
     # HOROVOD_TRACE_RING bounds the preallocated span ring.
     trace: bool = False
     trace_filename: str = ""
@@ -381,10 +382,6 @@ class Config:
     num_collective_streams: int = 1
     donate_fusion_buffers: bool = True
     mesh_axis_name: str = "hvd"
-    # Run the coordinator cycle inline on the submitting thread for blocking
-    # single-controller ops (HOROVOD_INLINE_KICK; the small-tensor latency
-    # fast path — off = legacy wake-the-cycle-thread dispatch).
-    inline_kick: bool = True
     # Pod mode (HOROVOD_ONE_PROC_PER_HOST): one launched process drives all
     # of its host's chips.  jax.distributed auto-detects the world, and
     # rank()/local_rank()/local_size() come from the device topology — the
@@ -483,7 +480,6 @@ class Config:
             batch_d2d_memcopies=_env_bool("BATCH_D2D_MEMCOPIES", True),
             num_collective_streams=_env_int("NUM_STREAMS", 1),
             donate_fusion_buffers=_env_bool("DONATE_FUSION_BUFFERS", True),
-            inline_kick=_env_bool("INLINE_KICK", True),
             one_proc_per_host=_env_bool("ONE_PROC_PER_HOST", False),
             controller_addr=_env("CONTROLLER_ADDR", "") or "",
             controller_port=_env_int("CONTROLLER_PORT", 0),

@@ -61,7 +61,7 @@ _ZRT_MAGIC = 0x3754525A
 
 @dataclasses.dataclass
 class ResponseCacheStats:
-    """Client-side response-cache telemetry (timeline/bench/tests).
+    """Client-side response-cache telemetry (timeline, /metrics, tests).
 
     ``hits``/``misses`` count per-tensor announces by wire form (bitvector
     vs full metadata); ``invalidations`` counts slots dropped for any
@@ -152,8 +152,8 @@ class TCPController:
         # (the response is drained — bounded by the window — at the start
         # of a later _round call, where v4 aborts and LVE6 notices it may
         # carry are honored).  zero_rtt=False emulates a pre-v7 client:
-        # no ZRT7 ad, predictions ignored (the downgrade-matrix tests and
-        # the bench A/B ride this).  Both knobs are runtime-tunable
+        # no ZRT7 ad, predictions ignored (the downgrade-matrix tests
+        # ride this).  Both knobs are runtime-tunable
         # (autotune coordinates in multi-process mode).
         self.spec_ready_after = max(0, int(spec_ready_after))
         self.round_pipeline = max(1, int(round_pipeline))
@@ -167,7 +167,7 @@ class TCPController:
         # round frame to learn the verdict would deadlock against our
         # blocked cycle thread.  The engine clears this when its launches
         # are synchronous (the CPU tier's serialized-launch mode, or an
-        # inline-settling window); harness/bench controllers, which
+        # inline-settling window); test-harness controllers, which
         # dispatch nothing, keep the default True.
         self.spec_dispatch_ok = True
         # Slots the server predicted ready for the NEXT round (one-round
@@ -194,8 +194,8 @@ class TCPController:
         # rounds, None for plain pipelined rounds.  Never longer than
         # max(round_pipeline, 1) after a _round call returns.
         self._outstanding: List[Optional[frozenset]] = []
-        # Speculation observability (bench zero_rtt_ab, /metrics, the
-        # timeline counter track): hits/mispredicts resolve when the
+        # Speculation observability (/metrics, the timeline counter
+        # track, tests/test_zero_rtt.py): hits/mispredicts resolve when the
         # deferred response validates; spec_rounds counts verdicts
         # returned without waiting (round trips saved).
         self.spec_hits = 0

@@ -620,8 +620,8 @@ class HostAgent:
                 self._sever_local()
                 return
             # One long-lived selector (epoll on Linux — not select(),
-            # whose FD_SETSIZE the negotiation-scaling bench's hundreds of
-            # in-process sockets would blow past), registered ONCE per
+            # whose FD_SETSIZE a simulated world's hundreds of in-process
+            # sockets, testing/churn.py, would blow past), registered ONCE per
             # connection like the root's poller — never rebuilt per round.
             sel = selectors.DefaultSelector()
             for r, s in self._local.items():

@@ -329,7 +329,7 @@ class ShardedOptimizerState:
 
     def opt_state_bytes(self) -> int:
         """Bytes of optimizer state resident on THIS rank (the 1/N claim
-        the bench's ``sharded_ab`` section asserts)."""
+        ``tests/data/worker_sharded.py`` asserts)."""
         total = 0
         for s in self.inner_states:
             for leaf in jax.tree_util.tree_leaves(s):
@@ -403,8 +403,8 @@ class FullShardedState(ShardedOptimizerState):
 
     def resident_bytes(self) -> int:
         """Parameters + optimizer state resident on THIS rank — the ≈ 1/N
-        claim bench's ``fsdp_ab`` section and the acceptance worker
-        assert (small-leaf padding slack allowed)."""
+        claim ``tests/data/worker_fsdp.py`` asserts (small-leaf padding
+        slack allowed)."""
         return self.params_bytes() + self.opt_state_bytes()
 
     def gather_params(self, depth: Optional[int] = None):

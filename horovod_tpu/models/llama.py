@@ -71,9 +71,8 @@ class LlamaConfig:
     remat_stages: bool = False
     # Rematerialize each transformer layer in the NON-pipelined forward:
     # activation memory per layer collapses to the layer input, at ~1/3
-    # extra forward FLOPs.  The measured lever for the large-batch HBM
-    # falloff (docs/benchmarks.md "Llama batch scaling"): per-chip
-    # throughput decays past B=16 at T=512 without it.
+    # extra forward FLOPs.  A memory lever for steps that do not otherwise
+    # fit; what it costs a step on the chip is not measured.
     remat_layers: bool = False
     # Where the LM loss is computed under pp (docs/parallelism.md):
     # "broadcast"  — psum the [M, mb, T, D] pipeline output to every

@@ -422,7 +422,7 @@ def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
 @dataclasses.dataclass(frozen=True)
 class MoELMConfig:
     """Minimal MoE language model (embed → N × [attention-free mixer +
-    MoE FFN] → head) — the test/bench vehicle for expert parallelism."""
+    MoE FFN] → head) — the test vehicle for expert parallelism."""
     vocab_size: int = 256
     d_model: int = 64
     n_layers: int = 2

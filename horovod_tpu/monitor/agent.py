@@ -658,8 +658,8 @@ class MonitorAgent:
                 ctl.fault_enricher = None
         if self._stall is not None:
             # A replacement agent may have re-installed its own source
-            # (e.g. the bench A/B attaches a temporary agent to a live
-            # engine): only uninstall OUR callback, never someone else's.
+            # (e.g. a test attaches a temporary agent to a live engine):
+            # only uninstall OUR callback, never someone else's.
             if getattr(self._stall, "peer_ledger_source", None) \
                     is self._peer_cb:
                 self._stall.peer_ledger_source = None

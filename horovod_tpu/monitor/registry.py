@@ -5,9 +5,9 @@ process owns one :class:`MetricRegistry` that the engine, the scheduler
 primitives, the negotiation response cache, the in-flight ring and the
 runtime sanitizer publish into.  The registry is deliberately dumb — three
 metric kinds, a flat snapshot dict, and a Prometheus text rendering — so it
-can be read by the controller side-channel, the rank-0 HTTP exporter, the
-timeline counter track and ``bench.py`` without any of them knowing about
-the publishers.
+can be read by the controller side-channel, the rank-0 HTTP exporter and
+the timeline counter track without any of them knowing about the
+publishers.
 
 Reference mapping: the reference exposes per-rank state only through the
 timeline and log lines; this registry is the missing queryable surface the

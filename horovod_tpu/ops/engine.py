@@ -224,7 +224,6 @@ class CollectiveEngine:
                                     cfg.stall_shutdown_time_s,
                                     cfg.stall_check_disable)
         self.cycle_time_s = cfg.cycle_time_ms / 1000.0
-        self.inline_kick = cfg.inline_kick
         self.fusion_threshold = cfg.fusion_threshold_bytes
         # Pipelined data plane (HOROVOD_PIPELINE_CHUNK / HOROVOD_MAX_
         # INFLIGHT).  chunk 0 = off: one chunk per fused batch, the legacy
@@ -236,9 +235,9 @@ class CollectiveEngine:
         self.pipeline_chunk_bytes = cfg.pipeline_chunk_bytes
         self.max_inflight = cfg.max_inflight
         self._inflight: Optional[InflightRing] = None
-        # Pipeline observability (bench.py emits chunks_per_cycle /
-        # inflight_depth on every JSON line; the timeline gets a per-cycle
-        # "pipeline" counter track).
+        # Pipeline observability (/metrics hvd_pipeline_*_total through
+        # monitor/agent.py; the timeline gets a per-cycle "pipeline"
+        # counter track).
         self.pipeline_chunks_total = 0
         self.pipeline_dispatches = 0
         self.last_cycle_chunks = 0
@@ -361,12 +360,12 @@ class CollectiveEngine:
         self._world_changed: Optional[BaseException] = None
         # Control-plane observability: cumulative negotiation wall time and
         # round count (multi-process mode only — single-controller cycles
-        # have no negotiation).  bench.py derives negotiation_us_per_cycle;
-        # the timeline gets a per-cycle counter track.
+        # have no negotiation).  /metrics exports both through
+        # monitor/agent.py; the timeline gets a per-cycle counter track.
         self.negotiation_us_total = 0.0
         self.negotiation_cycles = 0
         self.last_negotiation_us = 0.0
-        # Zero-RTT warm path (protocol v7): cycles whose verdict came from
+        # Zero-RTT warm path (protocol v7): a cycle's verdict may come from
         # the coordinator's speculative prediction — negotiate() returned
         # without waiting for the response, so the negotiation phase
         # collapses toward zero.  The dispatch path below is deliberately
@@ -375,7 +374,6 @@ class CollectiveEngine:
         # reaches this layer — the controller absorbs it by merging the
         # next announce into the still-pending server entry, so results
         # stay bitwise identical and nothing needs un-dispatching here.
-        self.spec_cycles = 0
         # Whole-cycle wall-time accounting (drain + negotiate + fuse +
         # dispatch): the per-rank numbers the monitor subsystem aggregates
         # into slowest-rank / cycle-time-spread straggler attribution
@@ -391,7 +389,7 @@ class CollectiveEngine:
         # drain) stamped through the cycle below, ring-buffered, optionally
         # written to a per-rank trace file, and digested into the monitor
         # side-channel.  None when disarmed — every stamp site is then one
-        # attribute check (the bench trace A/B pins this at zero cost).
+        # attribute check (tests/test_trace.py::test_disarmed_recorder_is_none).
         from ..trace import maybe_install as _trace_install
         self.tracer = _trace_install(
             cfg, rank=cfg.rank_env if cfg.rank_env >= 0 else 0)
@@ -938,13 +936,8 @@ class CollectiveEngine:
         preserving fusion (a concurrent burst drains into the same cycle).
         Multi-process mode: negotiation must stay on the lock-step cycle
         thread; just wake it.
-
-        ``HOROVOD_INLINE_KICK=0`` disables the inline path (falling back to
-        waking the cycle thread) — the A/B knob behind the recorded
-        inline-vs-threaded dispatch-latency evidence
-        (``tools/latency_evidence.py``).
         """
-        if self.controller is None and self.inline_kick:
+        if self.controller is None:
             self.run_loop_once()
         else:
             self._wake.set()
@@ -1201,8 +1194,6 @@ class CollectiveEngine:
             self.negotiation_us_total += dt_us
             self.negotiation_cycles += 1
             self.last_negotiation_us = dt_us
-            if getattr(self.controller, "last_round_speculative", False):
-                self.spec_cycles += 1
             tl0 = self._state.timeline
             if tl0 is not None and tl0.enabled:
                 st = self.controller.cache_stats
@@ -1835,8 +1826,8 @@ class CollectiveEngine:
                 # copy_in closes HERE, before the invoke: the fast lane
                 # stages no fusion buffer and fetches no key — the device
                 # wait that follows belongs to the reduce phase (this is
-                # what makes copy_in ≈ 0 on the fast lane in the bench's
-                # phase breakdown).
+                # what makes copy_in ≈ 0 on the fast lane in the phase
+                # breakdown, tests/test_engine_fastlane.py).
                 sp.t_launch = time.monotonic()
         outs = fn(e.tensor)
         if not isinstance(outs, (list, tuple)):

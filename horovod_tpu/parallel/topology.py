@@ -23,7 +23,7 @@ the already-ordered rank list:
 
 Everything here is pure Python over duck-typed device objects — **no jax
 import** — so the purity tier can load it with jax hard-blocked and the
-analyzer/bench can model wire bytes without touching a backend.
+analyzer and the tests can model wire bytes without touching a backend.
 
 The whole module leans on one invariant established by
 ``common.topology.ordered_devices``: ranks are assigned slice-major (slice

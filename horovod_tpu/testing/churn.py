@@ -10,8 +10,7 @@ hierarchical (ranks behind real per-host
 :class:`~..common.host_agent.HostAgent` aggregators).  The simulated
 ranks speak raw warm-path frames (the steady-state floor: no full
 announces, empty bitvector, no tags), so what is measured is pure
-control-plane service — the same world the ``negotiation_scaling`` bench
-drives, now with churn injected mid-run.
+control-plane service, with churn injected mid-run.
 
 Execution model: the measured rounds are split into PHASES at each
 scripted event's round.  Rank threads free-run the rounds inside a phase

@@ -38,7 +38,7 @@ def maybe_install(cfg, rank: int = 0):
     (``HOROVOD_TRACE``), else None — the engine's ``tracer`` attribute.
     Called from the engine constructor; a None return keeps every stamp
     site a single attribute check (the strictly-zero-cost disarmed
-    contract, pinned by the bench trace A/B).  The recorder built here is
+    contract).  The recorder built here is
     also the one :func:`span` reaches from the calling thread, until it
     closes."""
     if not getattr(cfg, "trace", False):

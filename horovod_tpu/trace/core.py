@@ -485,9 +485,9 @@ class TraceRecorder:
             return out
 
     def phase_summary(self) -> dict:
-        """Mean per-phase microseconds + mean lifecycle — the bench.py
-        per-line breakdown.  ``phase_sum_us`` ~= ``cycle_us`` whenever all
-        five stamps landed (the consistency the acceptance test pins)."""
+        """Mean per-phase microseconds + mean lifecycle of the committed
+        spans.  ``phase_sum_us`` ~= ``cycle_us`` whenever all five stamps
+        landed (``tests/test_trace.py`` pins the partition)."""
         with self._lock:
             n = self.spans_committed
             if not n:

@@ -6,6 +6,7 @@ findings go in the inline allowlist below — each entry must carry a reason.
 """
 
 import os
+import re
 
 from horovod_tpu.analysis import lint_paths
 
@@ -128,8 +129,8 @@ def _gate_result():
 
 def test_whole_package_gate_green():
     """The interprocedural self-lint (tools/lint_gate.py semantics): the
-    two-pass analyzer over horovod_tpu/ + examples/ + tools/ + bench.py
-    must produce NO findings beyond the reviewed baseline."""
+    two-pass analyzer over horovod_tpu/ + examples/ + tools/ must produce
+    NO findings beyond the reviewed baseline."""
     new, _stale, _baselined = _gate_result()
     assert not new, (
         "new whole-package findings (fix them, pragma them with a reason, "
@@ -158,18 +159,46 @@ def test_whole_package_baseline_carries_no_errors():
 
 
 def test_known_out_of_scope_files_now_lint_clean_via_pragmas():
-    """ISSUE 13 satellite: bench.py's HVD103 and the deliberate divergence
-    in tests/data/worker_join.py / worker_sanitizer.py are annotated with
-    inline pragmas — the files lint error-free WITHOUT directory scoping,
-    so the old ROADMAP carve-out is gone (bench.py is in the gate scope)."""
+    """ISSUE 13 satellite: the deliberate divergence in
+    tests/data/worker_join.py / worker_sanitizer.py is annotated with
+    inline pragmas — the files lint error-free WITHOUT directory scoping."""
     findings = lint_paths([
-        os.path.join(REPO, "bench.py"),
         os.path.join(REPO, "tests", "data", "worker_join.py"),
         os.path.join(REPO, "tests", "data", "worker_sanitizer.py"),
     ])
     errors = [f for f in findings if f.is_error]
     assert not errors, "\n".join(f.render() for f in errors)
-    assert not any(f.rule == "HVD103" for f in findings)   # bench pragma
+
+
+# Pages whose named files must exist: the ones that say where the gate, the
+# tools and the benchmark live.  A path in backticks or a link target that
+# ends in .py, .md or .json, resolved against the page's directory, the
+# repo root or (bare names) the package.
+_PAGES = ("README.md", "tools/README.md", "docs/benchmarks.md")
+_NAMED_FILE = re.compile(
+    r"`([\w./-]+\.(?:py|md|json))`|\]\(([\w./-]+\.(?:py|md|json))[#)]")
+# What a run writes, and the driver's file outside the repo.
+_NOT_COMMITTED = ("benchmark/out/", "chiprun_out/", "/root/")
+
+
+def test_pages_and_gate_scope_name_files_that_exist():
+    """A page that names a file that is gone sends its reader nowhere:
+    every path in the gate's SCOPE exists, and so does every repo-relative
+    .py, .md or .json file the three pages name."""
+    from horovod_tpu.analysis.gate import SCOPE
+    missing = [p for p in SCOPE if not os.path.exists(os.path.join(REPO, p))]
+    for page in _PAGES:
+        with open(os.path.join(REPO, page)) as f:
+            text = f.read()
+        named = {a or b for a, b in _NAMED_FILE.findall(text)}
+        assert named, page
+        roots = (os.path.dirname(os.path.join(REPO, page)), REPO,
+                 os.path.join(REPO, "horovod_tpu"))
+        missing += [
+            f"{page}: {name}" for name in sorted(named)
+            if not name.startswith(_NOT_COMMITTED)
+            and not any(os.path.exists(os.path.join(r, name)) for r in roots)]
+    assert not missing, missing
 
 
 def test_allowlist_entries_still_fire():

@@ -172,8 +172,7 @@ def test_dlrm_sharded_matches_reference():
 
 def test_llama_remat_layers_matches():
     """remat_layers=True recomputes the forward in backward (memory
-    lever for models that do not otherwise fit — measured a throughput
-    LOSS at bench scale, docs/benchmarks.md) and must be numerically
+    lever for models that do not otherwise fit) and must be numerically
     invisible: same logits, same grads."""
     import jax
     import jax.numpy as jnp

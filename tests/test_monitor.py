@@ -223,31 +223,69 @@ def test_monitor_frames_aggregate_across_ranks():
     _pair(fn)
 
 
-def test_frame_guard_holds_with_monitoring_enabled():
+def _serve_traffic(stop):
+    """A batcher under load until ``stop``: an echo worker and one client
+    (jax-free).  Its registry is what a serving rank's agent reports."""
+    from horovod_tpu.serve.batcher import ContinuousBatcher
+    sb = ContinuousBatcher(max_batch=4, deadline_ms=1000.0, max_inflight=2)
+
+    def echo_worker():
+        while not stop.is_set():
+            batch = sb.next_batch(timeout=0.01)
+            if batch is not None:
+                sb.complete(batch, [np.asarray(r.inputs) * 2
+                                    for r in batch.requests])
+
+    def client():
+        while not stop.is_set():
+            try:
+                sb.submit(np.ones(4, np.float32)).wait(1.0)
+            except Exception:  # noqa: BLE001 - load generator only
+                pass
+
+    for target in (echo_worker, client):
+        threading.Thread(target=target, daemon=True).start()
+    return sb
+
+
+@pytest.mark.parametrize("serving", [False, True])
+def test_frame_guard_holds_with_monitoring_enabled(serving):
     """Acceptance guard: with a MonitorAgent attached, steady-state cycles
     still send ZERO per-tensor metadata, and the negotiation-critical
     bytes (total minus the separately-accounted monitor frames) stay the
-    same fixed handful per cycle as with monitoring off."""
+    same fixed handful per cycle as with monitoring off.  ``serving``: the
+    same with serve traffic hammering a batcher whose registry rides the
+    monitor side-channel."""
     names = [f"grad.{i}.with.a.long.parameter.path" for i in range(12)]
 
     def fn(ctl, rank):
+        stop = threading.Event()
+        sb = _serve_traffic(stop) if serving else None
         agent = MonitorAgent(engine=FakeEngine(), controller=ctl, rank=rank,
-                             world=2, interval_s=0.05)
-        mk = lambda: [E(n) for n in names]           # noqa: E731
-        _steps(ctl, mk, 2)                           # warm-up: learn slots
-        time.sleep(0.06)                             # arm the frame interval
-        st = ctl.cache_stats
-        full_before = st.full_announces
-        bytes_before = ctl.bytes_sent
-        mon_before = ctl.monitor_bytes_sent
-        orders = _steps(ctl, mk, 5)
-        assert st.full_announces == full_before, (
-            "monitoring pushed steady-state cycles off the bitvector path")
-        assert st.bit_announces >= 5 * len(names)
-        mon_bytes = ctl.monitor_bytes_sent - mon_before
-        assert mon_bytes > 0, "no monitor frame rode the measured window"
-        per_cycle = (ctl.bytes_sent - bytes_before - mon_bytes) / 5
-        assert per_cycle <= 16, per_cycle
+                             world=2, interval_s=0.05,
+                             registry=sb.registry if serving else None)
+        try:
+            mk = lambda: [E(n) for n in names]       # noqa: E731
+            _steps(ctl, mk, 2)                       # warm-up: learn slots
+            time.sleep(0.06)                         # arm the frame interval
+            st = ctl.cache_stats
+            full_before = st.full_announces
+            bytes_before = ctl.bytes_sent
+            mon_before = ctl.monitor_bytes_sent
+            orders = _steps(ctl, mk, 5)
+            assert st.full_announces == full_before, (
+                "monitoring pushed steady-state cycles off the bitvector "
+                "path")
+            assert st.bit_announces >= 5 * len(names)
+            mon_bytes = ctl.monitor_bytes_sent - mon_before
+            assert mon_bytes > 0, "no monitor frame rode the measured window"
+            per_cycle = (ctl.bytes_sent - bytes_before - mon_bytes) / 5
+            assert per_cycle <= 16, per_cycle
+            if serving:
+                assert sb.stats()["requests_total"] > 0
+        finally:
+            stop.set()
+            agent.close()
         return orders
 
     res = _pair(fn)

@@ -201,9 +201,14 @@ def test_ulysses_gqa(causal):
                                rtol=2e-4, atol=2e-5)
 
 
-def test_zero_sharded_optimizer_matches_plain():
-    """ZeRO-sharded adam == unsharded adam on the mean gradient."""
-    from horovod_tpu.parallel.zero import sharded_optimizer
+@pytest.mark.parametrize("full", [False, True], ids=["zero1", "fsdp"])
+def test_zero_sharded_optimizer_matches_plain(full):
+    """ZeRO-sharded adam == unsharded adam on the mean gradient; with
+    ``full`` the parameters live only as the state's shards and the tree
+    gathered back from them is the plainly updated one."""
+    from horovod_tpu.parallel.zero import (full_sharded_optimizer,
+                                           gather_full_params,
+                                           sharded_optimizer)
 
     params = {"w": jnp.asarray(np.random.RandomState(0).randn(13, 7)
                                .astype(np.float32)),
@@ -222,19 +227,23 @@ def test_zero_sharded_optimizer_matches_plain():
     ref_updates, _ = inner.update(mean_grads, ref_state, params)
 
     mesh = make_mesh({"dp": 8})
-    zopt = sharded_optimizer(optax.adam(1e-2), axis_name="dp")
+    wrap = full_sharded_optimizer if full else sharded_optimizer
+    zopt = wrap(optax.adam(1e-2), axis_name="dp")
 
     def run(params, *grads_stacked):
         # inside shard_map: this rank's grads
         grads = {"w": grads_stacked[0].reshape(params["w"].shape),
                  "b": grads_stacked[1].reshape(params["b"].shape)}
         state = zopt.init(params)
-        updates, _ = zopt.update(grads, state, params)
-        return updates
+        updates, state = zopt.update(grads, state,
+                                     None if full else params)
+        gathered = (gather_full_params(state, params, "dp") if full
+                    else optax.apply_updates(params, updates))
+        return updates, gathered
 
     gw = jnp.stack([g["w"] for g in per_rank_grads])
     gb = jnp.stack([g["b"] for g in per_rank_grads])
-    updates = jax.jit(shard_map(
+    updates, gathered = jax.jit(shard_map(
         run, mesh=mesh,
         in_specs=(P(), P("dp"), P("dp")), out_specs=P(),
         check_vma=False))(params, gw, gb)
@@ -242,6 +251,9 @@ def test_zero_sharded_optimizer_matches_plain():
         np.testing.assert_allclose(np.asarray(updates[kk]),
                                    np.asarray(ref_updates[kk]),
                                    rtol=1e-4, atol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(gathered[kk]),
+            np.asarray(params[kk] + ref_updates[kk]), rtol=1e-4, atol=1e-6)
 
 
 def test_distributed_optimizer_sharded_mixed_mode_raises():
@@ -390,17 +402,22 @@ def test_zero_shard_leaf_device_matches_host():
         np.testing.assert_array_equal(np.asarray(back), reduced)
 
 
-def test_zero_init_sharded_state_specs_and_memory():
-    """init_sharded_state: state leaves live sharded P('dp') on the mesh
-    (1/world per device), specs match the state structure, and the step
-    built from them (models.mnist path) runs."""
+@pytest.mark.parametrize("full", [False, True], ids=["zero1", "fsdp"])
+def test_zero_init_sharded_state_specs_and_memory(full):
+    """init_sharded_state / init_full_sharded_state: state leaves live
+    sharded P('dp') on the mesh (1/world per device), specs match the
+    state structure, and what one device holds is 1/world of the
+    replicated bytes: the optimizer state (ZeRO-1), or the optimizer
+    state and the parameters themselves (``full``)."""
     from horovod_tpu.parallel import zero
-    mesh = make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    world = 4
+    mesh = make_mesh({"dp": world}, devices=jax.devices()[:world])
     params = {"w": jnp.asarray(np.random.RandomState(0)
                                .randn(33, 3).astype(np.float32)),
               "s": jnp.asarray(1.5, jnp.float32)}
-    state, specs = zero.init_sharded_state(optax.adam(1e-2), params, mesh,
-                                           "dp")
+    inner = optax.adam(1e-2)
+    init = zero.init_full_sharded_state if full else zero.init_sharded_state
+    state, specs = init(inner, params, mesh, "dp")
     flat_state = jax.tree_util.tree_leaves(state)
     flat_specs = jax.tree_util.tree_leaves(
         specs, is_leaf=lambda x: isinstance(x, P))
@@ -410,9 +427,19 @@ def test_zero_init_sharded_state_specs_and_memory():
             assert spec == P("dp"), (leaf.shape, spec)
             # Each device holds exactly 1/world of the leaf.
             shard_sizes = {s.data.size for s in leaf.addressable_shards}
-            assert shard_sizes == {leaf.size // 4}, shard_sizes
+            assert shard_sizes == {leaf.size // world}, shard_sizes
         else:
             assert spec == P(), spec
+
+    def nbytes(tree):
+        return sum(int(l.nbytes) for l in jax.tree_util.tree_leaves(tree))
+
+    d0 = jax.devices()[0]
+    resident = sum(s.data.nbytes for leaf in flat_state
+                   for s in leaf.addressable_shards if s.device == d0)
+    replicated = nbytes(inner.init(params)) + (nbytes(params) if full else 0)
+    slack = 2 * world * 4 + 64      # pad rows and the replicated counter
+    assert resident <= replicated / world + slack, (resident, replicated)
 
 
 def test_hierarchical_allreduce():

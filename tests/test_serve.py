@@ -9,9 +9,10 @@ the monitor's ``/ready``-vs-``/health`` split, ``Histogram.percentile``
 plus the p50/p99 Prometheus export, the aggregator's fleet
 ``request_rate``/``latency_p99_ms`` gauges, and the ``ScalePolicy``
 request-rate / latency-target / serving-idle decisions.  The jax-backed
-replica half (broadcast fan-out, batched-vs-sequential parity, drain with
-in-flight work) lives in ``tests/data/worker_serve.py`` via
-``test_multiprocess.py``.
+replica half has one single-process test at the end (batched-vs-sequential
+parity, the recompile pin, drain with in-flight work); its two-process
+form (broadcast fan-out, rolling update) lives in
+``tests/data/worker_serve.py`` via ``test_multiprocess.py``.
 """
 
 import json
@@ -404,3 +405,45 @@ def test_policy_training_idle_unaffected_without_idle_qps():
     assert pol.observe(dict(mk), size=2, now=0.0).action == HOLD
     assert pol.observe(dict(mk), size=2, now=5.0).action == HOLD
     assert pol.observe(dict(mk), size=2, now=20.0).action == SCALE_IN
+
+
+# ----------------------------------------------------------- replica (jax)
+def test_replica_rows_independent_compiles_bounded_and_drains(hvd):
+    """The serving invariant on the jitted padded-bucket forward: a
+    request's result depends only on its own row (bitwise, same bucket
+    program), batch-size churn compiles at most the bucket menu, and a
+    drained batcher's queued work completes through ``serve_loop`` while
+    new work is refused."""
+    import numpy as np
+
+    from horovod_tpu.serve.replica import Replica
+
+    rng = np.random.RandomState(7)
+    rep = Replica(lambda params, x: x @ params["w"])
+    assert rep.load({"w": rng.randn(16, 8).astype(np.float32)}, version=1)
+    assert rep.load({"w": np.zeros((16, 8), np.float32)}, version=1) is False
+    x = rng.randn(8, 16).astype(np.float32)
+
+    batched = rep.forward(x)
+    alone = []
+    for i in range(8):
+        only = np.zeros_like(x)
+        only[0] = x[i]                    # row i alone, position 0
+        alone.append(rep.forward(only)[0])
+    np.testing.assert_array_equal(batched, np.stack(alone))     # bitwise
+
+    misses = rep.cache.misses
+    for n in (3, 5, 7, 8, 2, 6):          # churn across the bucket menu
+        rep.forward(x[:n])
+    assert rep.cache.misses - misses <= 2  # buckets 2 and 4; 8 is compiled
+
+    b = ContinuousBatcher(max_batch=4, deadline_ms=10000.0, max_inflight=2)
+    inflight = [b.submit(x[i]) for i in range(8)]
+    b.drain()
+    with pytest.raises(Draining):
+        b.submit(x[0])
+    assert rep.serve_loop(b) == 2         # 4 + 4, then drained and empty
+    got = np.stack([r.wait(0.0) for r in inflight])
+    np.testing.assert_array_equal(
+        got, np.concatenate([rep.forward(x[:4]), rep.forward(x[4:8])]))
+

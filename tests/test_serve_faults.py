@@ -291,26 +291,47 @@ def _consume(batcher, script):
     return stop
 
 
-def test_front_door_retries_replica_fault_to_success():
-    b = ContinuousBatcher(max_batch=4, deadline_ms=5000.0)
-    door = _door(b, retries=3)
+@pytest.mark.parametrize("clients,fault_at", [(1, 1), (32, 3)])
+def test_front_door_retries_replica_fault_to_success(clients, fault_at):
+    """A replica fault mid-batch is retried to success under the same
+    request id.  With 32 concurrent clients and the third dispatched batch
+    failing: zero accepted requests lost — every one gets exactly one
+    terminal 200 with its own row's result."""
+    b = ContinuousBatcher(max_batch=4, deadline_ms=10000.0,
+                          queue_depth=2 * clients)
+    door = _door(b, retries=4, breaker=CircuitBreaker(threshold=10000))
 
     def script(batch, n):
-        if n == 1:
+        if n == fault_at:
             b.fail_retryable(batch, RuntimeError("peer died mid-batch"))
         else:
             b.complete(batch, [r.inputs * 2 for r in batch.requests])
 
     stop = _consume(b, script)
+    outcomes = [None] * clients
+
+    def client(i):
+        outcomes[i] = door.infer_detailed(
+            21.0 + i, request_id=f"fault-{i}", deadline_ms=10000.0)
+
     try:
-        out = door.infer_detailed(21.0)
-        assert out["_code"] == 200, out
-        assert out["outputs"] == 42.0
-        assert out["attempts"] == 2
+        threads = [threading.Thread(target=client, args=(i,), daemon=True)
+                   for i in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert all(o is not None for o in outcomes), "a request was lost"
+        assert [o["_code"] for o in outcomes] == [200] * clients, outcomes
+        assert [o["outputs"] for o in outcomes] == \
+            [2 * (21.0 + i) for i in range(clients)]
+        retried = [o for o in outcomes if o["attempts"] > 1]
+        assert retried and all(o["attempts"] == 2 for o in retried)
         s = door.stats()
-        assert s["retries_total"] == 1
+        assert s["retries_total"] == len(retried)
         assert s["replica_faults_total"] == 1
-        assert s["availability"] == 1.0          # terminal outcome was OK
+        assert s["quarantined_total"] == 0
+        assert s["availability"] == 1.0          # terminal outcomes all OK
     finally:
         stop.set()
         door.stop()

@@ -6,10 +6,10 @@ memory wall at LONG sequence.  This sweep measures where that wall is on
 the chip and which block sizes the kernel wants there, so the auto routing
 (``flash_enabled`` / ``LlamaConfig.use_flash``) can pick the winner per
 shape instead of a blanket platform default.  Not yet run on the current
-machine (ROADMAP queue 1 item 4).
+machine (ROADMAP queue 1 item 4a).
 
 Per (seq, impl) it times a jitted fwd+bwd (grads wrt q,k,v — the training
-shape that the llama bench exercises) of causal GQA attention at fixed
+shape of a decoder step) of causal GQA attention at fixed
 token count (B*T = const), bf16 inputs:
 
     python tools/flash_sweep.py --out FLASH_SWEEP.json
@@ -34,7 +34,7 @@ import numpy as np
 SEQS = [512, 1024, 2048, 4096, 8192]
 BLOCKS = [(128, 128), (256, 256), (512, 512), (128, 512), (256, 1024)]
 TOKENS = 64 * 1024          # B = TOKENS // T  (fixed work per measurement)
-H, K, D = 8, 4, 64          # the llama bench head geometry
+H, K, D = 8, 4, 64          # toy heads; mistral7b-4l's are 32, 8, 128
 
 
 def _loss_fn(attn, iters):
