@@ -254,16 +254,17 @@ def _stage_submit(make, name: str, prefix: str, ctype, process_set,
                   priorities, kick: bool = True, **extra):
     """One group into the engine: ``hvd/update/stage`` (``make()`` readies
     the tensors — compress, ravel, pad — and ``eager._stage_group`` puts
-    them into the engine's stacked layout), then ``hvd/update/submit``
-    (``enqueue_group`` + ``kick``).  Returns ``(group id, the tensors,
-    handles)``."""
+    them into the engine's stacked layout, by one program over the group
+    where they are on this process's chip: the span's ``compiled`` counts
+    those), then ``hvd/update/submit`` (``enqueue_group`` + ``kick``).
+    Returns ``(group id, the tensors, handles)``."""
     from ..ops import eager
     with trace.span("hvd/update/stage") as sp:
         tensors = make()
-        gid, items = eager._stage_group(tensors, name, prefix, ctype,
-                                        process_set, priorities, **extra)
+        gid, items, compiled = eager._stage_group(
+            tensors, name, prefix, ctype, process_set, priorities, **extra)
         if sp is not None:
-            sp.set(n=len(tensors), bytes=_nbytes(tensors))
+            sp.set(n=len(tensors), bytes=_nbytes(tensors), compiled=compiled)
     eng = eager._engine()
     with trace.span("hvd/update/submit", group=gid):
         handles = eng.enqueue_group(items)

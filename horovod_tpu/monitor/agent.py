@@ -36,6 +36,7 @@ from .aggregator import RankAggregator
 from .registry import MetricRegistry
 from ..trace.core import PHASES as _TRACE_PHASES
 from ..trace.core import inner_update as _INNER_UPDATE
+from ..trace.core import stage_group as _STAGE_GROUP
 from ..utils.logging import get_logger
 
 log = get_logger()
@@ -146,6 +147,14 @@ class MonitorAgent:
             reg.counter("hvd_inner_update_traces_total",
                         "traces of the compiled inner update").set_total(
                 _INNER_UPDATE["traces"])
+            # A group's staging (ops/eager.py): members that went through
+            # the one program over their group, and traces of it.
+            reg.counter("hvd_stage_group_compiled_total",
+                        "group members staged by one compiled program"
+                        ).set_total(_STAGE_GROUP["compiled"])
+            reg.counter("hvd_stage_group_traces_total",
+                        "traces of the staging program").set_total(
+                _STAGE_GROUP["traces"])
             # FSDP prefetch lane (ISSUE 18): dispatches count allgather
             # batches routed through the PREFETCH lane; overlapped counts
             # the ones issued while an earlier bucket was still unsettled

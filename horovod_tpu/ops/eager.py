@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import collectives as C
 from .engine import CollectiveType
+from .. import trace
 from ..common import basics
 from ..common.process_sets import ProcessSet
 
@@ -124,8 +125,17 @@ def per_process_mode() -> bool:
     return cfg is not None and cfg.controller_addr != ""
 
 
+def _local_devices(ps) -> List:
+    """The devices of the set's mesh that this process drives."""
+    return [d for d in ps.mesh.devices.flat
+            if d.process_index == jax.process_index()]
+
+
 def _as_stacked(x, ps_id: int):
-    """Coerce input to a stacked [world, *S] jax.Array on the set's mesh.
+    """Coerce ONE input to a stacked [world, *S] jax.Array on the set's
+    mesh: the single-tensor calls' staging, and what a group's staging
+    (:func:`_stack_members`) gives every member it cannot put through its
+    one program.
 
     Single-process mode: ``x`` is the full stacked [world, *S] host/device
     array.  Multi-process mode (launched by torovodrun): ``x`` is this
@@ -156,8 +166,7 @@ def _as_stacked(x, ps_id: int):
                 "contribution (a host array or local device array), not a "
                 "global jax.Array; use hvd.to_local() on previous results "
                 "before resubmitting them.")
-        local_devs = [d for d in ps.mesh.devices.flat
-                      if d.process_index == jax.process_index()]
+        local_devs = _local_devices(ps)
         n_local = len(local_devs)
         device_resident = isinstance(x, jax.Array)
         if not device_resident:
@@ -192,6 +201,49 @@ def _as_stacked(x, ps_id: int):
             return (x if x.sharding == sharding
                     else jax.device_put(x, sharding)), False
     return jax.device_put(x, sharding), True
+
+
+@jax.jit
+def _stack_leaves(xs):
+    """Every leaf ``x`` as ``x[None]``: a group's members in the stacked
+    layout's local shard, by one program with a result a member.  A local,
+    single-device program (no mesh, so no other rank has to launch it),
+    built once here: jit's own cache keys it on shapes and dtypes.  No
+    donation: the caller may hold its gradients."""
+    trace.stage_group["traces"] += 1        # Python: once a trace
+    return [x[None] for x in xs]
+
+
+def _stack_members(tensors, ps_id: int):
+    """``_as_stacked`` for every member of a group: ``([(array, owned),
+    ...], compiled)``.  Members that are device arrays on this process's
+    one chip (the per-process branch, one device a process) go through
+    :func:`_stack_leaves` together and are wrapped a member — same shape,
+    dtype, sharding and values as ``_as_stacked`` gives, for one dispatch a
+    group where that takes three a member.  Anything else (host values, a
+    process driving several devices, the single-controller branch's
+    already stacked arrays) takes ``_as_stacked``; a group may mix the
+    two.  ``compiled`` counts the members the program took."""
+    ps = basics._get_state().process_set_table.get(ps_id)
+    out, together = [None] * len(tensors), []
+    if per_process_mode():
+        local_devs = _local_devices(ps)
+        if len(local_devs) == 1:
+            held = set(local_devs)
+            together = [i for i, t in enumerate(tensors)
+                        if isinstance(t, jax.Array) and t.devices() == held]
+    if together:
+        sharding = NamedSharding(ps.mesh, P(ps.axis_name))
+        world = ps.size()
+        shards = _stack_leaves([tensors[i] for i in together])
+        for i, shard in zip(together, shards):
+            out[i] = jax.make_array_from_single_device_arrays(
+                (world,) + shard.shape[1:], sharding, [shard]), True
+        trace.stage_group["compiled"] += len(together)
+    for i, t in enumerate(tensors):
+        if out[i] is None:
+            out[i] = _as_stacked(t, ps_id)
+    return out, len(together)
 
 
 def to_global(tensor, process_set: Optional[ProcessSet] = None):
@@ -400,13 +452,15 @@ def allgather(tensor, name: Optional[str] = None,
 def _stage_group(tensors, name, prefix, ctype, process_set,
                  priorities=None, **extra):
     """Stage one atomic group (reference N13): every tensor into the
-    engine's stacked layout, under one fresh group id.  Returns ``(group
-    id, items)``; one ``enqueue_group(items)`` then pushes them
-    atomically, so all members negotiate in the same round on every rank
-    — which both preserves fusion atomicity and lets a negotiation error
-    on one member abort the whole group.  (The eager optimizer paths call
-    the two halves themselves: each is a program span of its own,
-    ``hvd/update/stage`` and ``hvd/update/submit``.)
+    engine's stacked layout (:func:`_stack_members`: one program over
+    the members already on this process's chip), under one fresh group
+    id.  Returns ``(group id, items, compiled)``, ``compiled`` the number
+    of members that one program took; one ``enqueue_group(items)`` then
+    pushes them atomically, so all members negotiate in the same round on
+    every rank — which both preserves fusion atomicity and lets a
+    negotiation error on one member abort the whole group.  (The eager
+    optimizer paths call the two halves themselves: each is a program
+    span of its own, ``hvd/update/stage`` and ``hvd/update/submit``.)
 
     ``priorities`` (one int per tensor, identical on every rank): drain
     priority per member — the group still executes atomically, but its
@@ -420,15 +474,15 @@ def _stage_group(tensors, name, prefix, ctype, process_set,
         raise ValueError(
             f"priorities must have one entry per tensor: got "
             f"{len(priorities)} for {len(tensors)} tensors")
+    stacked, compiled = _stack_members(tensors, ps_id)
     items = []
-    for i, t in enumerate(tensors):
-        arr, owned = _as_stacked(t, ps_id)
+    for i, (arr, owned) in enumerate(stacked):
         items.append(dict(name=f"{base}.{i}", ctype=ctype, tensor=arr,
                           process_set_id=ps_id, group_id=gid, donate=owned,
                           priority=int(priorities[i])
                           if priorities is not None else 0,
                           **extra))
-    return gid, items
+    return gid, items, compiled
 
 
 def _grouped_async(tensors, name, prefix, ctype, process_set,

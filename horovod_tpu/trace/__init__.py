@@ -22,14 +22,14 @@ import sys
 from . import core
 from .core import (DIGEST_MAX_CYCLES, DIGEST_MAX_OPEN, OFF, PHASE_BUCKETS_US,
                    PHASES, REDUCE_LEGS, CycleRecord, ProgramSpan, TensorSpan,
-                   TraceRecorder, inner_update, installed, span)
+                   TraceRecorder, inner_update, installed, span, stage_group)
 from .writer import TraceWriter
 
 __all__ = [
     "PHASES", "REDUCE_LEGS", "PHASE_BUCKETS_US", "DIGEST_MAX_CYCLES",
     "DIGEST_MAX_OPEN", "CycleRecord", "TensorSpan", "TraceRecorder",
     "TraceWriter", "maybe_install", "span", "installed", "OFF",
-    "ProgramSpan", "inner_update",
+    "ProgramSpan", "inner_update", "stage_group",
 ]
 
 

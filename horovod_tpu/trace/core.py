@@ -266,6 +266,12 @@ def installed() -> Optional["TraceRecorder"]:
 # tracing armed or not; ``monitor/agent.py`` exports them.
 inner_update = {"compiled": 0, "traces": 0}
 
+# The same pair for a group's staging (``ops/eager.py`` ``_stage_group``):
+# ``compiled`` members put into the engine's stacked layout by the one
+# program over their group, ``traces`` of that program.  A group whose
+# shapes change from call to call costs one trace a distinct signature.
+stage_group = {"compiled": 0, "traces": 0}
+
 
 def span(name: str, **ids):
     """A program span on the calling thread: ``with trace.span("hvd/update/

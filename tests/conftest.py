@@ -36,6 +36,26 @@ def hvd():
     # speed (matching how real training uses one init per process).
 
 
+@pytest.fixture()
+def one_rank(hvd):
+    """A one-rank process set of the 8-virtual-device mesh."""
+    ps = hvd.add_process_set([0])
+    yield ps
+    hvd.remove_process_set(ps)
+
+
+@pytest.fixture()
+def per_process(hvd, one_rank, monkeypatch):
+    """This process as one rank of ``torovodrun -np 1``: the per-process
+    branch over ``one_rank``, no controller (the cycle runs inline)."""
+    from horovod_tpu.common import basics
+    from horovod_tpu.ops import eager
+    monkeypatch.setattr(basics._get_state().config, "controller_addr",
+                        "stub:0")
+    assert eager.per_process_mode()
+    return one_rank
+
+
 @pytest.fixture(scope="session")
 def world_size():
     import jax

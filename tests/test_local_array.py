@@ -5,7 +5,8 @@ no result of an eager ``allreduce_gradients`` goes through the host.
 
 The updates run over a one-rank process set of the 8-virtual-device CPU
 mesh with this process forced into the per-process branch and no
-controller (``torovodrun -np 1``: the cycle runs inline).  The span's
+controller (``torovodrun -np 1``: the cycle runs inline; ``conftest.py``'s
+``one_rank`` and ``per_process``).  The span's
 ``host`` id in a traced update is ``tests/test_trace_spans.py``'s; the
 sharded paths' sites are driven by ``tests/data/worker_sharded.py`` and
 ``worker_fsdp.py``.
@@ -27,13 +28,6 @@ from test_trace_spans import fresh_annotation
 def values(world, per=6, seed=0):
     rng = np.random.RandomState(seed)
     return [rng.randn(per).astype(np.float32) for _ in range(world)]
-
-
-@pytest.fixture()
-def one_rank(hvd):
-    ps = hvd.add_process_set([0])
-    yield ps
-    hvd.remove_process_set(ps)
 
 
 def result_of(hvd, layout, ps):
@@ -106,16 +100,6 @@ def grads(seed=3):
     return {"w": jnp.asarray(rng.randn(7, 5).astype(np.float32)),
             "b": jnp.asarray(rng.randn(5).astype(np.float32)),
             "s": jnp.asarray(np.float32(rng.randn()))}
-
-
-@pytest.fixture()
-def per_process(hvd, one_rank, monkeypatch):
-    """This process as one rank of ``torovodrun -np 1``."""
-    from horovod_tpu.common import basics
-    monkeypatch.setattr(basics._get_state().config, "controller_addr",
-                        "stub:0")
-    assert eager.per_process_mode()
-    return one_rank
 
 
 COMPRESSIONS = {"float32": Compression.none, "wire_bf16": Compression.bf16,

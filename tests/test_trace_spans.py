@@ -231,7 +231,7 @@ def within(inner, outer):
 
 @pytest.mark.parametrize("name,ids", [
     ("hvd/update", {"step", "group"}),
-    ("hvd/update/stage", {"n", "bytes"}),
+    ("hvd/update/stage", {"n", "bytes", "compiled"}),
     ("hvd/update/submit", {"group"}),
     ("hvd/update/wait", {"group"}),
     ("hvd/update/unpack", {"n", "bytes", "host"}),
@@ -252,7 +252,9 @@ def test_calling_thread_span(traced, name, ids):
             assert sp["ids"]["bytes"] == traced["bytes"]
         if "host" in ids:           # every leaf handed over on the device
             assert sp["ids"]["host"] == 0
-        if "compiled" in ids:       # optax.sgd traces: the one program
+        if name == "hvd/update/stage":  # every leaf through the one program
+            assert sp["ids"]["compiled"] == sp["ids"]["n"]
+        elif "compiled" in ids:     # optax.sgd traces: the one program
             assert sp["ids"]["compiled"] == 1
     if name == "hvd/update":
         steps = [u["ids"]["step"] for u in updates]
