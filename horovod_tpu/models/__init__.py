@@ -6,7 +6,7 @@ framework deps) eagerly.
 """
 
 _FAMILIES = ("llama", "gpt2", "bert", "vit", "resnet", "moe", "dlrm",
-             "mnist", "convert", "qwen3_next")
+             "mnist", "convert", "qwen3_next", "olmo_hybrid", "gated_delta")
 
 __all__ = list(_FAMILIES)
 

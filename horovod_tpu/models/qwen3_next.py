@@ -19,9 +19,10 @@ experts_held``.  Parameters are a list of per-layer dicts, each holding
 - **Gated DeltaNet**: ``[q|k|v|z] = h W_qkvz``, ``[b|a] = h W_ba``; causal
   depthwise convolution + SiLU over ``[q|k|v]``; q, k repeated to the value
   heads and L2-normalised; per head ``S <- exp(g_t) S + k_t (beta_t (v_t -
-  S^T k_t))^T``, ``o_t = S^T q_t``, computed in the **chunked** form
-  (:func:`chunked_gated_delta_rule`); gated RMSNorm with ``SiLU(z)``;
-  ``W_o``.
+  S^T k_t))^T``, ``o_t = S^T q_t``, computed in the **chunked** form;
+  gated RMSNorm with ``SiLU(z)``; ``W_o``.  The mixer is
+  ``models/gated_delta.py``'s (:func:`gated_delta.gated_delta_net`, shared
+  with ``olmo_hybrid``), told this config's sizes and beta in (0, 1).
 - **Gated attention**: a query and a gate per head from ``W_q``, RMSNorm0 on
   q and k heads, rotary on the first ``partial_rotary_factor`` of the
   head, causal attention (the Pallas flash kernel on a TPU), the result
@@ -48,7 +49,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import gated_delta as _gdn
 from . import moe as _moe
+from .gated_delta import chunked_gated_delta_rule  # noqa: F401  (this
+#   module's name for the rule: ``_gated_delta_net`` calls it by that name)
 from ..parallel.ring_attention import local_flash_attention
 
 
@@ -87,6 +91,13 @@ class Qwen3NextConfig:
     def is_full_attention(self, i: int) -> bool:
         return (i + 1) % self.full_attention_interval == 0
 
+    def gdn_dims(self) -> _gdn.GatedDeltaDims:
+        return _gdn.GatedDeltaDims(
+            k_heads=self.lin_k_heads, v_heads=self.lin_v_heads,
+            k_dim=self.lin_k_dim, v_dim=self.lin_v_dim,
+            conv_kernel=self.conv_kernel, chunk=self.chunk,
+            norm_eps=self.norm_eps)
+
     def moe_cfg(self) -> _moe.DroplessMoEConfig:
         return _moe.DroplessMoEConfig(
             d_model=self.d_model, d_ff=self.d_expert,
@@ -114,8 +125,6 @@ def qwen3_next_80b_a3b() -> Qwen3NextConfig:
 # ------------------------------------------------------------------- params
 def init_params(cfg: Qwen3NextConfig, key) -> Dict:
     d, dt = cfg.d_model, cfg.dtype
-    hk, hv, dk, dv = (cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim,
-                      cfg.lin_v_dim)
     keys = iter(jax.random.split(key, 2 + 12 * cfg.n_layers))
 
     def dense(fan_in, shape):
@@ -123,18 +132,7 @@ def init_params(cfg: Qwen3NextConfig, key) -> Dict:
                 / np.sqrt(fan_in)).astype(dt)
 
     def gdn():
-        # HF's draw: A uniform in (0, 16), dt log-uniform in (1e-3, 0.1)
-        a = jax.random.uniform(next(keys), (hv,), jnp.float32, 1e-3, 16.0)
-        step = jnp.exp(jax.random.uniform(
-            next(keys), (hv,), jnp.float32, np.log(1e-3), np.log(0.1)))
-        return {"w_qkvz": dense(d, (d, 2 * hk * dk + 2 * hv * dv)),
-                "w_ba": dense(d, (d, 2 * hv)),
-                "conv": dense(cfg.conv_kernel,
-                              (cfg.conv_kernel, 2 * hk * dk + hv * dv)),
-                "A_log": jnp.log(a).astype(dt),
-                "dt_bias": jnp.log(jnp.expm1(step)).astype(dt),
-                "out_norm": jnp.ones((dv,), dt),
-                "wo": dense(hv * dv, (hv * dv, d))}
+        return _gdn.init_params(cfg.gdn_dims(), d, dt, keys)
 
     def attn():
         h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -179,112 +177,9 @@ def _partial_rope(x, rotary, theta):
          (b * cos + a * sin).astype(x.dtype), x[..., rotary:]], axis=-1)
 
 
-def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
-    """The gated delta rule ``S <- exp(g_t) S + k_t (beta_t (v_t - S^T
-    k_t))^T``, ``o_t = S^T q_t`` with ``S_0 = 0``, a chunk of tokens at a
-    time (Yang et al., "Gated Delta Networks").
-
-    q, k ``[B, T, H, dk]`` (k of unit length), v ``[B, T, H, dv]``, g (the
-    log decay, <= 0) and beta ``[B, T, H]`` float32 -> o ``[B, T, H, dv]``
-    in v's type.  Within a chunk the ``C`` rank-one updates are one
-    unit-lower-triangular solve ``(I + tril(diag(beta) K K^T * D, -1)) [U |
-    W] = diag(beta) [V | K * exp(G)]`` (``D_ij = exp(G_i - G_j)``, ``G`` the
-    running sum of g inside the chunk), all chunks at once; a ``lax.scan``
-    then carries the ``dk x dv`` state over the chunks, and the outputs
-    follow from the states, again all chunks at once.  g, its sums, the
-    solve and the state are float32; the matrix products take their
-    operands in the inputs' type and accumulate in float32.  Plain JAX
-    operations: the backward pass is autodiff's.  ``T`` need not be a
-    multiple of ``chunk``."""
-    B, T, H, dk = q.shape
-    dv, dt, C = v.shape[-1], v.dtype, chunk
-    pad = (-T) % C
-    N = (T + pad) // C
-
-    def chunks(x):          # [B, T, H, ...] -> [B, H, N, C, ...]
-        x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-        x = x.reshape((B, N, C) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-
-    # padding: k = 0 and beta = 0 write nothing, g = 0 decays nothing
-    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
-    f32 = jnp.float32
-    mm = lambda spec, a, b: jnp.einsum(spec, a.astype(dt), b.astype(dt),
-                                       preferred_element_type=f32)
-    G = jnp.cumsum(g.astype(f32), axis=-1)                  # [B,H,N,C]
-    lower = jnp.tril(jnp.ones((C, C), bool))
-    # exp only of differences that are <= 0
-    decay = jnp.where(lower, jnp.exp(jnp.where(
-        lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
-    k_beta = k.astype(f32) * beta[..., None]
-    A = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
-                  mm("bhnik,bhnjk->bhnij", k_beta, k) * decay, 0.0)
-    rhs = jnp.concatenate([v.astype(f32) * beta[..., None],
-                           k_beta * jnp.exp(G)[..., None]], axis=-1)
-    solved = jax.scipy.linalg.solve_triangular(
-        A + jnp.eye(C, dtype=f32), rhs, lower=True, unit_diagonal=True)
-    U, W = solved[..., :dv], solved[..., dv:]               # [B,H,N,C,·]
-    total = G[..., -1]                                      # [B,H,N]
-    k_tail = k.astype(f32) * jnp.exp(total[..., None] - G)[..., None]
-
-    def carry(S, x):        # S [B,H,dk,dv]: the state a chunk starts from
-        U_n, W_n, k_n, decay_n = x
-        v_new = U_n - mm("bhck,bhkv->bhcv", W_n, S)
-        S_next = S * decay_n[..., None, None] + mm("bhck,bhcv->bhkv",
-                                                   k_n, v_new)
-        return S_next, (S, v_new)
-
-    time_first = lambda x: jnp.moveaxis(x, 2, 0)
-    _, (S, v_new) = lax.scan(
-        carry, jnp.zeros((B, H, dk, dv), f32),
-        tuple(time_first(x) for x in (U, W, k_tail, jnp.exp(total))))
-    S, v_new = jnp.moveaxis(S, 0, 2), jnp.moveaxis(v_new, 0, 2)
-    o = mm("bhnck,bhnkv->bhncv", q.astype(f32) * jnp.exp(G)[..., None], S)
-    o = o + mm("bhnij,bhnjv->bhniv",
-               mm("bhnik,bhnjk->bhnij", q, k) * decay, v_new)
-    o = jnp.moveaxis(o, 1, 3).reshape(B, N * C, H, dv)[:, :T]
-    return o.astype(dt)
-
-
 def _gated_delta_net(x, p, cfg: Qwen3NextConfig):
-    B, T, _ = x.shape
-    hk, hv, dk, dv = (cfg.lin_k_heads, cfg.lin_v_heads, cfg.lin_k_dim,
-                      cfg.lin_v_dim)
-    f32 = jnp.float32
-    with jax.named_scope("gdn/proj"):
-        qkvz = x @ p["w_qkvz"]
-        ba = jnp.einsum("btd,de->bte", x, p["w_ba"],
-                        preferred_element_type=f32)
-        qkv, z = qkvz[..., :2 * hk * dk + hv * dv], qkvz[..., -hv * dv:]
-    with jax.named_scope("gdn/conv"):
-        # causal and depthwise: tap j weighs the input conv_kernel-1-j back
-        taps = cfg.conv_kernel
-        padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
-        conv = p["conv"].astype(f32)
-        qkv = jax.nn.silu(sum(conv[j] * padded[:, j:j + T]
-                              for j in range(taps))).astype(x.dtype)
-    with jax.named_scope("gdn/scan"):
-        def heads(y, n, dim, repeat=1):
-            y = y.reshape(B, T, n, dim).astype(f32)
-            y = y * lax.rsqrt(jnp.sum(jnp.square(y), axis=-1,
-                                      keepdims=True) + 1e-6)
-            return jnp.repeat(y, repeat, axis=2)
-
-        q = (heads(qkv[..., :hk * dk], hk, dk, hv // hk)
-             / np.sqrt(dk)).astype(x.dtype)
-        k = heads(qkv[..., hk * dk:2 * hk * dk], hk, dk,
-                  hv // hk).astype(x.dtype)
-        v = qkv[..., 2 * hk * dk:].reshape(B, T, hv, dv)
-        beta = jax.nn.sigmoid(ba[..., :hv])
-        g = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(
-            ba[..., hv:] + p["dt_bias"].astype(f32))
-        o = chunked_gated_delta_rule(q, k, v, g, beta, cfg.chunk)
-    with jax.named_scope("gdn/out"):
-        of = o.astype(f32)
-        var = jnp.mean(jnp.square(of), axis=-1, keepdims=True)
-        o = (p["out_norm"].astype(f32) * (of * lax.rsqrt(var + cfg.norm_eps))
-             * jax.nn.silu(z.reshape(B, T, hv, dv).astype(f32)))
-        return o.astype(x.dtype).reshape(B, T, hv * dv) @ p["wo"]
+    return _gdn.gated_delta_net(x, p, cfg.gdn_dims(),
+                                rule=chunked_gated_delta_rule)
 
 
 def _gated_attention(x, p, cfg: Qwen3NextConfig):

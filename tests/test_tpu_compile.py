@@ -64,13 +64,15 @@ def _kernels(hlo_text):
 
 
 # head_dim 64, 128 and 256 (qwen3_next's full-attention layer: 16 query and
-# 2 key-value heads), GQA everywhere; causal, one windowed, one non-causal.
+# 2 key-value heads), GQA but for olmo_hybrid's 30 heads with keys of their
+# own; causal, one windowed, one non-causal.
 FLASH_CASES = [
     pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
     pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
     pytest.param(128, 32, 8, True, 1024, id="d128-h32k8-window1024"),
     pytest.param(64, 8, 4, False, None, id="d64-h8k4-noncausal"),
     pytest.param(256, 16, 2, True, None, id="d256-h16k2-causal"),
+    pytest.param(128, 30, 30, True, None, id="d128-h30k30-causal"),
 ]
 
 
@@ -179,22 +181,33 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
-def test_chunked_delta_rule_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("heads, dk, dv, grouped", [
+    pytest.param(32, 128, 128, False, id="h32-128x128"),
+    pytest.param(30, 96, 192, False, id="h30-96x192"),
+    pytest.param(30, 96, 192, True, id="h30-96x192-6-at-a-time"),
+])
+def test_chunked_delta_rule_compiles_for_v5e(one_chip, heads, dk, dv,
+                                             grouped):
     """The chunked gated delta rule at the published head sizes (32 heads
-    of 128 x 128, chunk 64), forward and backward."""
-    from horovod_tpu.models.qwen3_next import chunked_gated_delta_rule
+    of 128 x 128; 30 of 96 x 192, widths that are no multiple of the 128
+    lanes, also a group of heads at a time), chunk 64, forward and
+    backward."""
+    from horovod_tpu.models import gated_delta
 
     def spec(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    qkv = spec((1, 2048, 32, 128), jnp.bfloat16)
-    gate = spec((1, 2048, 32), jnp.float32)
+    qk = spec((1, 2048, heads, dk), jnp.bfloat16)
+    v = spec((1, 2048, heads, dv), jnp.bfloat16)
+    gate = spec((1, 2048, heads), jnp.float32)
+    rule = gated_delta.chunked_gated_delta_rule
+    if grouped:
+        rule = gated_delta.by_head_groups(rule, 2048 * 6)
 
     def loss(q, k, v, g, beta):
-        return chunked_gated_delta_rule(q, k, v, g, beta, 64).astype(
-            jnp.float32).sum()
+        return rule(q, k, v, g, beta, 64).astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
-        qkv, qkv, qkv, gate, gate).compile()
+        qk, qk, v, gate, gate).compile()
     assert "while" in compiled.as_text()        # the scan over chunk states
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
