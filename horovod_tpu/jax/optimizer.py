@@ -35,6 +35,7 @@ from ..compat import axis_size as compat_axis_size
 from .compression import Compression
 from .. import trace
 from ..ops import collectives as C
+from ..ops import eager
 from ..common.process_sets import ProcessSet
 from ..utils.logging import get_logger
 
@@ -70,7 +71,16 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
       collective — the compiler-native tensor fusion, reference N7).
     - **Eager, per-process** (torovodrun-launched, called outside any mesh
       context): one fused grouped allreduce through the collective engine —
-      the reference's hook→background-thread path (SURVEY §3.2).
+      the reference's hook→background-thread path (SURVEY §3.2).  Where
+      every leaf is a ``jax.Array`` on this process's one chip, the reduce
+      is elementwise (anything but Adasum) and the compression none or a
+      ``wire_mode`` cast, the group travels as **one flat buffer a dtype**:
+      packed by one program, one engine item a dtype, and taken apart into
+      the tree again by one program (``DistributedOptimizer.update`` skips
+      that one: its compiled inner update slices the buffers itself).
+      Anything else goes a leaf an engine item, as every group did.  The
+      same elements go through the same ``psum`` either way: the results
+      are bitwise equal.
 
     Either way compress → reduce → decompress mirrors the reference's hook
     pipeline.  Calling this from a plain ``jax.jit`` trace in a multi-process
@@ -78,6 +88,15 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
     silently be the identity and replicas would diverge) — compute gradients
     under jit but reduce/update eagerly, or use a ``shard_map`` step.
     """
+    return _allreduce_gradients(grads, op, axis_name, compression,
+                                process_set, keep_flat=False)
+
+
+def _allreduce_gradients(grads, op, axis_name, compression, process_set,
+                         keep_flat: bool):
+    """``allreduce_gradients``; with ``keep_flat`` a group that travelled
+    flat comes back as the ``eager.FlatGroup`` it is (for a consumer that
+    unpacks inside its own program) and not as the tree."""
     if process_set is not None:
         axis_name = process_set.axis_name
     leaves, treedef = jax.tree_util.tree_flatten(grads)
@@ -88,7 +107,6 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
         out = [compression.decompress(r, c[1]) for r, c in zip(reduced, comp)]
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    from ..ops import eager
     from ..ops.engine import CollectiveType
     if not eager.per_process_mode():
         return grads  # single-controller SPMD: params/grads already global
@@ -114,9 +132,18 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
     # Any other compressor wraps the exchange on this thread.
     wire = getattr(compression, "wire_mode", None)
     comp = None
+    # One flat buffer a dtype where the group's elements can be reduced in
+    # any grouping (Adasum reduces a tensor at a time; a compressor that
+    # is not a cast compresses one) and every leaf is on this process's
+    # chip already.
+    flat = (op != C.ReduceOp.ADASUM
+            and (wire is not None or compression is Compression.none)
+            and eager._all_held(leaves, process_set))
 
     def stage():
         nonlocal comp
+        if flat:
+            return leaves
         arrs = [jnp.asarray(g) for g in leaves]
         if wire is None:
             comp = [compression.compress(a) for a in arrs]
@@ -125,25 +152,33 @@ def allreduce_gradients(grads, op: C.ReduceOp = C.ReduceOp.AVERAGE,
 
     gid, arrs, handles = _stage_submit(
         stage, "allreduce_gradients", "grouped_allreduce",
-        CollectiveType.ALLREDUCE, process_set, prios, reduce_op=op,
-        compression=eager._wire_mode(wire))
+        CollectiveType.ALLREDUCE, process_set, prios, pack=flat,
+        reduce_op=op, compression=eager._wire_mode(wire))
     reduced = _wait(gid, handles)
     with trace.span("hvd/update/unpack") as sp:
-        out, host = [], 0
-        for r, a in zip(reduced, arrs):
-            # The fused program returns each leaf replicated, in its own
-            # shape and dtype: the buffer this chip holds is the result.
-            local = eager._local_shard(r)
-            if local is None:
-                host += 1
-                local = eager.local_array(r)
-            out.append(_as_leaf(local, a.shape, a.dtype))
-        if comp is not None:
-            out = [compression.decompress(r, c[1])
-                   for r, c in zip(out, comp)]
+        # The fused program returns each member replicated, in its own
+        # shape and dtype: the buffer this chip holds is the result.
+        local = [eager._local_shard(r) for r in reduced]
+        via_host = {i for i, shard in enumerate(local) if shard is None}
+        for i in via_host:
+            local[i] = eager.local_array(reduced[i])
+        host = len(via_host)
+        if flat:
+            layout = eager._flat_layout(arrs)
+            host = sum(k in via_host for k, _, _ in layout)     # in leaves
+            out = eager.FlatGroup(local, layout, treedef)
+            if not keep_flat:
+                out = eager._unpack_group(out)
+        else:
+            local = [_as_leaf(r, a.shape, a.dtype)
+                     for r, a in zip(local, arrs)]
+            if comp is not None:
+                local = [compression.decompress(r, c[1])
+                         for r, c in zip(local, comp)]
+            out = jax.tree_util.tree_unflatten(treedef, local)
         if sp is not None:
-            sp.set(n=len(out), bytes=_nbytes(out), host=host)
-    return jax.tree_util.tree_unflatten(treedef, out)
+            sp.set(n=len(arrs), bytes=_nbytes(local), host=host)
+    return out
 
 
 # ---- program spans of the eager update (docs/timeline.md).  Each phase of
@@ -161,7 +196,6 @@ def _update_span(grads=None, axis_name=None):
     ``group`` is the first group the update submits."""
     if trace.installed() is None:
         return trace.OFF
-    from ..ops import eager
     if grads is not None and (
             _axis_in_scope(axis_name) or not eager.per_process_mode()
             or _any_tracer(grads)):
@@ -197,8 +231,15 @@ class _InnerUpdate:
     eager path goes through here and they agree with each other
     (docs/performance.md "The eager path").
 
+    Gradients that travelled flat (``eager.FlatGroup``: one buffer a
+    dtype, layout and tree structure static) are sliced into the tree
+    inside the program, where XLA fuses the slices into the optimizer's
+    elementwise passes: still one trace a signature, and no result buffer
+    a leaf in between.
+
     A transformation that cannot be traced (it branches on a value) takes
-    the direct call from its first concretization error on; any other
+    the direct call from its first concretization error on, flat gradients
+    unpacked for it by ``eager._unpack_group``'s one program; any other
     exception propagates."""
 
     def __init__(self, optimizer: optax.GradientTransformation):
@@ -206,6 +247,8 @@ class _InnerUpdate:
 
         def hvd_inner_update(grads, state, params):
             trace.inner_update["traces"] += 1       # Python: once a trace
+            if isinstance(grads, eager.FlatGroup):
+                grads = grads.tree()
             return optimizer.update(grads, state, params)
 
         self._compiled: Optional[Callable] = jax.jit(hvd_inner_update)
@@ -227,6 +270,8 @@ class _InnerUpdate:
                         "it op by op from here on", type(e).__name__)
                     self._compiled, compiled = None, False
             if not compiled:
+                if isinstance(grads, eager.FlatGroup):
+                    grads = eager._unpack_group(grads)
                 out = self._direct(grads, state, params)
             if sp is not None:
                 sp.set(compiled=int(compiled))
@@ -251,20 +296,33 @@ def _as_leaf(a, shape, dtype, size: Optional[int] = None):
 
 
 def _stage_submit(make, name: str, prefix: str, ctype, process_set,
-                  priorities, kick: bool = True, **extra):
+                  priorities, kick: bool = True, pack: bool = False,
+                  **extra):
     """One group into the engine: ``hvd/update/stage`` (``make()`` readies
     the tensors — compress, ravel, pad — and ``eager._stage_group`` puts
     them into the engine's stacked layout, by one program over the group
     where they are on this process's chip: the span's ``compiled`` counts
     those), then ``hvd/update/submit`` (``enqueue_group`` + ``kick``).
-    Returns ``(group id, the tensors, handles)``."""
-    from ..ops import eager
+    With ``pack`` (the caller has seen that the group may travel flat)
+    ``eager._stage_packed`` makes one engine item a dtype of it, under the
+    group's highest priority.  The span says which: ``packed`` the tensors
+    that went in inside a flat buffer (``n`` or 0), ``buffers`` the engine
+    items the group became (one a dtype, or ``n``).
+    Returns ``(group id, the tensors, handles)``: a handle an item."""
     with trace.span("hvd/update/stage") as sp:
         tensors = make()
-        gid, items, compiled = eager._stage_group(
-            tensors, name, prefix, ctype, process_set, priorities, **extra)
+        if pack:
+            gid, items = eager._stage_packed(
+                tensors, name, prefix, ctype, process_set, max(priorities),
+                **extra)
+            compiled = len(tensors)
+        else:
+            gid, items, compiled = eager._stage_group(
+                tensors, name, prefix, ctype, process_set, priorities,
+                **extra)
         if sp is not None:
-            sp.set(n=len(tensors), bytes=_nbytes(tensors), compiled=compiled)
+            sp.set(n=len(tensors), bytes=_nbytes(tensors), compiled=compiled,
+                   packed=compiled if pack else 0, buffers=len(items))
     eng = eager._engine()
     with trace.span("hvd/update/submit", group=gid):
         handles = eng.enqueue_group(items)
@@ -276,7 +334,6 @@ def _stage_submit(make, name: str, prefix: str, ctype, process_set,
 def _wait(gid: int, handles) -> List:
     """``hvd/update/wait``: blocked on the engine, first to last handle
     of one group."""
-    from ..ops import eager
     with trace.span("hvd/update/wait", group=gid):
         return [eager.synchronize(h) for h in handles]
 
@@ -345,7 +402,6 @@ class ShardedOptimizerState:
         digests require it) and a (re-)joining rank re-slices exactly its
         own 1/N with :func:`load_sharded_saveable`.  ``process_set=None``
         gathers over the set the state was initialized with."""
-        from ..ops import eager
         if process_set is None:
             process_set = self.process_set
         if self.plan.world > 1 and not eager.per_process_mode():
@@ -418,7 +474,6 @@ class FullShardedState(ShardedOptimizerState):
         FUSED, budget-exempt) and ``sharded="full"`` (own digest token).
         Gathered buffers belong to the caller and are dropped after the
         step — peak HBM stays shard + the depth-bounded window."""
-        from ..ops import eager
         plan = self.plan
         nb = len(plan.buckets)
         nl = len(plan.shapes)
@@ -469,7 +524,6 @@ class FullShardedState(ShardedOptimizerState):
     def hvd_sharded_saveable(self, process_set: Optional[ProcessSet] = None):
         """Rank-invariant saveable: the PR 15 form plus the gathered
         parameter shards, under the ``__hvd_full_sharded__`` marker."""
-        from ..ops import eager
         base = super().hvd_sharded_saveable(process_set)
         if process_set is None:
             process_set = self.process_set
@@ -637,7 +691,6 @@ def _sharded_eager_update(inner: _InnerUpdate, grads,
     first bucket's update runs, so with HOROVOD_PIPELINE_CHUNK set the
     scatter → update → gather stages overlap across buckets (the engine's
     in-flight window + priority backlog do the interleaving)."""
-    from ..ops import eager
     from ..ops.engine import CollectiveType
     plan = state.plan
     leaves, treedef = jax.tree_util.tree_flatten(grads)
@@ -731,7 +784,6 @@ def _stage_scatter(leaves, plan: _ShardPlan, b: int, name: str, op,
 def _wait_shards(plan: _ShardPlan, idxs, gid: int, handles: dict):
     """Bucket ``idxs``' reduced gradient shards, flat and in the plan's
     dtypes, once its reduce-scatter (``handles`` by leaf) has settled."""
-    from ..ops import eager
     res = _wait_by_leaf(gid, handles)
     with trace.span("hvd/update/unpack"):
         return tuple(
@@ -777,7 +829,6 @@ def _full_sharded_eager_update(inner: _InnerUpdate, grads,
     step through the prefetch lane.  Wire per step is therefore
     RS(grads) + AG(params) — byte-equal to the PR 15 sharded path's
     RS + delta-AG."""
-    from ..ops import eager
     plan = state.plan
     leaves, treedef = jax.tree_util.tree_flatten(grads)
     if tuple(tuple(getattr(l, "shape", ())) for l in leaves) != plan.shapes:
@@ -845,7 +896,6 @@ def _make_sharded(optimizer: optax.GradientTransformation,
                 else zero.sharded_optimizer
             return wrap(optimizer, axis_name=axis_name,
                         average=op == C.ReduceOp.AVERAGE).init(params)
-        from ..ops import eager
         if eager.per_process_mode():
             if full:
                 return _full_sharded_eager_init(optimizer, params,
@@ -995,9 +1045,9 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         return _DistOptState(inner, acc, jnp.zeros((), jnp.int32))
 
     def _reduce(grads):
-        return allreduce_gradients(grads, op=op, axis_name=axis_name,
-                                   compression=compression,
-                                   process_set=process_set)
+        # flat where the group travelled flat: ``run_inner`` unpacks
+        return _allreduce_gradients(grads, op, axis_name, compression,
+                                    process_set, keep_flat=True)
 
     def update_fn(grads, state: _DistOptState, params=None):
         with _update_span(grads, axis_name) as up:
@@ -1091,7 +1141,6 @@ def broadcast_parameters(params, root_rank: int = 0,
     """
     if jax.process_count() == 1:
         return params
-    from ..ops import eager
     out = eager.broadcast_pytree(params, root_rank=root_rank,
                                  process_set=process_set)
     return jax.tree_util.tree_map(jnp.asarray, out)

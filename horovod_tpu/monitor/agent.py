@@ -148,13 +148,17 @@ class MonitorAgent:
                         "traces of the compiled inner update").set_total(
                 _INNER_UPDATE["traces"])
             # A group's staging (ops/eager.py): members that went through
-            # the one program over their group, and traces of it.
+            # the one program over their group, traces of it, and the
+            # members it packed into one flat buffer a dtype.
             reg.counter("hvd_stage_group_compiled_total",
                         "group members staged by one compiled program"
                         ).set_total(_STAGE_GROUP["compiled"])
             reg.counter("hvd_stage_group_traces_total",
                         "traces of the staging program").set_total(
                 _STAGE_GROUP["traces"])
+            reg.counter("hvd_stage_group_packed_total",
+                        "group members staged inside one flat buffer a "
+                        "dtype").set_total(_STAGE_GROUP["packed"])
             # FSDP prefetch lane (ISSUE 18): dispatches count allgather
             # batches routed through the PREFETCH lane; overlapped counts
             # the ones issued while an earlier bucket was still unsettled

@@ -16,11 +16,13 @@ as a stacked global array ``[world, *S]`` sharded over the world axis.
 from __future__ import annotations
 
 import itertools
+import math
 from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import collectives as C
@@ -203,6 +205,41 @@ def _as_stacked(x, ps_id: int):
     return jax.device_put(x, sharding), True
 
 
+def _own_chip(ps) -> Optional[set]:
+    """``{the one device of the set's mesh this process drives}`` on the
+    per-process branch, else ``None``: what a group's one staging program
+    (:func:`_stack_leaves`, :func:`_pack_leaves`) may take its members
+    from."""
+    if per_process_mode():
+        local_devs = _local_devices(ps)
+        if len(local_devs) == 1:
+            return set(local_devs)
+    return None
+
+
+def _held_on(t, chip: Optional[set]) -> bool:
+    return chip is not None and isinstance(t, jax.Array) \
+        and t.devices() == chip
+
+
+def _all_held(tensors, process_set: Optional[ProcessSet]) -> bool:
+    """True where there are tensors and each is :func:`_held_on` this
+    process's chip of the set's mesh: as far as its members go, the group
+    may travel flat (:func:`_stage_packed`)."""
+    chip = _own_chip(basics._get_state().process_set_table.get(
+        _ps(process_set)))
+    return bool(tensors) and all(_held_on(t, chip) for t in tensors)
+
+
+def _stacked_from_shards(ps, shards) -> List:
+    """Each ``[1, *S]`` array on this process's chip as the process's
+    shard of a stacked ``[world, *S]`` array over the set's mesh."""
+    sharding = NamedSharding(ps.mesh, P(ps.axis_name))
+    world = ps.size()
+    return [jax.make_array_from_single_device_arrays(
+        (world,) + shard.shape[1:], sharding, [shard]) for shard in shards]
+
+
 @jax.jit
 def _stack_leaves(xs):
     """Every leaf ``x`` as ``x[None]``: a group's members in the stacked
@@ -225,25 +262,113 @@ def _stack_members(tensors, ps_id: int):
     already stacked arrays) takes ``_as_stacked``; a group may mix the
     two.  ``compiled`` counts the members the program took."""
     ps = basics._get_state().process_set_table.get(ps_id)
-    out, together = [None] * len(tensors), []
-    if per_process_mode():
-        local_devs = _local_devices(ps)
-        if len(local_devs) == 1:
-            held = set(local_devs)
-            together = [i for i, t in enumerate(tensors)
-                        if isinstance(t, jax.Array) and t.devices() == held]
+    out, held = [None] * len(tensors), _own_chip(ps)
+    together = [i for i, t in enumerate(tensors) if _held_on(t, held)]
     if together:
-        sharding = NamedSharding(ps.mesh, P(ps.axis_name))
-        world = ps.size()
         shards = _stack_leaves([tensors[i] for i in together])
-        for i, shard in zip(together, shards):
-            out[i] = jax.make_array_from_single_device_arrays(
-                (world,) + shard.shape[1:], sharding, [shard]), True
+        for i, arr in zip(together, _stacked_from_shards(ps, shards)):
+            out[i] = arr, True
         trace.stage_group["compiled"] += len(together)
     for i, t in enumerate(tensors):
         if out[i] is None:
             out[i] = _as_stacked(t, ps_id)
     return out, len(together)
+
+
+# ---- a group as one flat buffer a dtype (the eager gradient path of
+# ``jax/optimizer.py``): packed by one program with a result a dtype,
+# reduced as one engine item a dtype, taken apart inside the program that
+# consumes it.
+
+def _flat_layout(leaves) -> tuple:
+    """Where each leaf lies in the flat buffers: ``(k, offset, shape)`` a
+    leaf, ``k`` its dtype's place in order of first appearance and
+    ``offset`` counted in elements of buffer ``k``.  From the leaves'
+    shapes and dtypes alone, in flatten order, so every rank agrees."""
+    order, ends, layout = {}, [], []
+    for x in leaves:
+        k = order.setdefault(x.dtype, len(order))
+        if k == len(ends):
+            ends.append(0)
+        layout.append((k, ends[k], tuple(x.shape)))
+        ends[k] += x.size
+    return tuple(layout)
+
+
+@jax.jit
+def _pack_leaves(xs):
+    """The leaves of each dtype raveled, concatenated and given the
+    stacked layout's leading 1: one ``[1, total]`` result a dtype, laid
+    out as :func:`_flat_layout` says.  Local and single-device like
+    :func:`_stack_leaves`, built once here, no donation: the caller may
+    hold its gradients."""
+    trace.stage_group["traces"] += 1        # Python: once a trace
+    parts: List[list] = []
+    for x, (k, _, _) in zip(xs, _flat_layout(xs)):
+        if k == len(parts):
+            parts.append([])
+        parts[k].append(x.reshape(-1))
+    return [jnp.concatenate(p)[None] for p in parts]
+
+
+@jax.tree_util.register_pytree_node_class
+class FlatGroup:
+    """A tree of arrays held as one flat 1-D buffer a dtype: ``buffers``
+    the pytree's children, ``layout`` (:func:`_flat_layout`) and the
+    tree's ``treedef`` its static part, so a ``jax.jit`` that is handed
+    one traces once a tree signature and can slice the leaves out inside
+    its own program, where XLA fuses the slices into their consumers."""
+
+    def __init__(self, buffers, layout, treedef):
+        self.buffers, self.layout, self.treedef = buffers, layout, treedef
+
+    def tree_flatten(self):
+        return tuple(self.buffers), (self.layout, self.treedef)
+
+    @classmethod
+    def tree_unflatten(cls, aux, buffers):
+        return cls(buffers, *aux)
+
+    def tree(self):
+        """The tree: every leaf a static slice of its buffer, reshaped.
+        An operation a leaf, so call it under a trace (:func:`_unpack_group`
+        is the program for a caller that is not in one)."""
+        leaves = [
+            lax.slice_in_dim(self.buffers[k], off,
+                             off + math.prod(shape)).reshape(shape)
+            for k, off, shape in self.layout]
+        return jax.tree_util.tree_unflatten(self.treedef, leaves)
+
+
+@jax.jit
+def _unpack_group(flat: FlatGroup):
+    """``flat.tree()`` as one program, a result a leaf: for a consumer
+    that needs the tree itself (the public ``allreduce_gradients``, an
+    inner update that cannot be compiled)."""
+    return flat.tree()
+
+
+def _stage_packed(tensors, name, prefix, ctype, process_set, priority,
+                  **extra):
+    """:func:`_stage_group` for a group that travels flat: ``tensors``
+    (every one :func:`_held_on` this process's chip: the caller's test)
+    through :func:`_pack_leaves`, one engine item a dtype named
+    ``<base>.flat.<k>``, all under one fresh group id and the one
+    ``priority``.  The items are this layer's own copies, so the fused
+    program may take them over (``donate``).  Returns ``(group id,
+    items)``."""
+    ps_id = _ps(process_set)
+    ps = basics._get_state().process_set_table.get(ps_id)
+    gid = next(_group_counter)
+    base = _auto_name(prefix, name)
+    items = [dict(name=f"{base}.flat.{k}", ctype=ctype, tensor=arr,
+                  process_set_id=ps_id, group_id=gid, donate=True,
+                  priority=int(priority), **extra)
+             for k, arr in enumerate(
+                 _stacked_from_shards(ps, _pack_leaves(tensors)))]
+    for count in ("compiled", "packed"):
+        trace.stage_group[count] += len(tensors)
+    return gid, items
 
 
 def to_global(tensor, process_set: Optional[ProcessSet] = None):

@@ -266,11 +266,14 @@ def installed() -> Optional["TraceRecorder"]:
 # tracing armed or not; ``monitor/agent.py`` exports them.
 inner_update = {"compiled": 0, "traces": 0}
 
-# The same pair for a group's staging (``ops/eager.py`` ``_stage_group``):
-# ``compiled`` members put into the engine's stacked layout by the one
-# program over their group, ``traces`` of that program.  A group whose
-# shapes change from call to call costs one trace a distinct signature.
-stage_group = {"compiled": 0, "traces": 0}
+# The same pair for a group's staging (``ops/eager.py`` ``_stage_group``,
+# ``_stage_packed``): ``compiled`` members put into the engine's stacked
+# layout by the one program over their group, ``traces`` of that program.
+# A group whose shapes change from call to call costs one trace a distinct
+# signature.  ``packed``: those of ``compiled`` that went in as part of one
+# flat buffer a dtype (``_pack_leaves``: the eager gradient path) and not
+# as a member each (``_stack_leaves``).
+stage_group = {"compiled": 0, "traces": 0, "packed": 0}
 
 
 def span(name: str, **ids):

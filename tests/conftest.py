@@ -19,6 +19,36 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+
+
+def _share_the_cores():
+    """Under pytest-xdist, pin this worker (and the child processes its
+    tests start, which inherit it) to half of the machine's cores, the
+    halves staggered over the workers.  XLA's CPU backend sizes its thread
+    pool from the cores it may run on, so six workers and their
+    ``benchmark/run.py`` children otherwise put eight spinning threads each
+    on eight cores, and a rehearsed cell's 3 s window can pass without one
+    step completing (``tests/benchmark/test_benchmark_rehearse_*``: under
+    six such neighbours a step of 84 ms took 540 ms, with four cores each
+    230).  One process alone (no xdist) keeps every core."""
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "")
+    count = int(os.environ.get("PYTEST_XDIST_WORKER_COUNT", "0") or 0)
+    if not (worker.startswith("gw") and worker[2:].isdigit() and count > 2
+            and hasattr(os, "sched_setaffinity")):
+        return
+    cores = sorted(os.sched_getaffinity(0))
+    if len(cores) < 4:
+        return
+    start = int(worker[2:]) * len(cores) // count
+    try:
+        os.sched_setaffinity(0, {cores[(start + j) % len(cores)]
+                                 for j in range(len(cores) // 2)})
+    except OSError:  # pragma: no cover - a sandbox that forbids it
+        pass
+
+
+_share_the_cores()
+
 if "jax" in sys.modules:  # pragma: no cover - belt and braces
     import jax
     jax.config.update("jax_platforms", "cpu")

@@ -591,6 +591,21 @@ def test_torovodrun_fast_lane():
         f"stderr:\n{res.stderr[-3000:]}")
 
 
+WORKER_FLAT = os.path.join(REPO, "tests", "data", "worker_flat.py")
+
+
+def test_torovodrun_flat_gradients():
+    """ISSUE 34: a gradient tree through the engine as one flat buffer a
+    dtype, across two real processes — reduced gradients, updates and
+    optimizer state bitwise those of the leaf-an-item path on a
+    rank-dependent stream (assertions live in the worker)."""
+    res = _run_torovodrun(2, WORKER_FLAT, timeout=300)
+    ok = res.stdout.count("FLAT_OK")
+    assert res.returncode == 0 and ok == 2, (
+        f"rc={res.returncode}\nstdout:\n{res.stdout[-3000:]}\n"
+        f"stderr:\n{res.stderr[-3000:]}")
+
+
 WORKER_SHARDED = os.path.join(REPO, "tests", "data", "worker_sharded.py")
 
 

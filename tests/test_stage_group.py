@@ -129,7 +129,8 @@ def test_one_dispatch_a_group_and_none_a_member(hvd, per_process,
     for _ in range(3):
         stage(tensors, per_process)
     assert called == ["_stack_leaves"] * 3
-    assert moved(before) == {"compiled": 3 * len(tensors), "traces": 0}
+    assert moved(before) == {"compiled": 3 * len(tensors), "traces": 0,
+                             "packed": 0}
     monkeypatch.undo()
     assert eager._stack_leaves is program
 
@@ -182,13 +183,19 @@ SIGNATURES = {"allreduce_gradients": (run_flat, (11, 3), (11, 4)),
 def test_traced_once_a_signature_and_counted_a_member(hvd, per_process,
                                                       through):
     run, first, second = SIGNATURES[through]
+    # a gradient tree travels flat (tests/test_flat_group.py): its leaves
+    # count as packed too; a grouped call's members never do
+    packed = 0 if through == "grouped_allreduce" else 3
     before = counts()
     run(hvd, per_process, first, 5)
-    assert moved(before) == {"compiled": 5 * 3, "traces": 1}
+    assert moved(before) == {"compiled": 5 * 3, "traces": 1,
+                             "packed": 5 * packed}
     run(hvd, per_process, second, 2)        # another signature: once more
-    assert moved(before) == {"compiled": 7 * 3, "traces": 2}
+    assert moved(before) == {"compiled": 7 * 3, "traces": 2,
+                             "packed": 7 * packed}
     run(hvd, per_process, first, 2)         # the first is still cached
-    assert moved(before) == {"compiled": 9 * 3, "traces": 2}
+    assert moved(before) == {"compiled": 9 * 3, "traces": 2,
+                             "packed": 9 * packed}
 
 
 # ----------------- (c) what the program cannot take goes a leaf at a time
@@ -248,7 +255,8 @@ def test_branches_the_program_never_serves(hvd, world_size, branch,
         tensors = [hvd.stack_per_rank(list(v)) for v in vals]
     before = counts()
     gid, items, compiled = stage(tensors, None)
-    assert compiled == 0 and moved(before) == {"compiled": 0, "traces": 0}
+    assert compiled == 0 and moved(before) == {"compiled": 0, "traces": 0,
+                                               "packed": 0}
     for item, v in zip(items, vals):
         assert item["tensor"].shape == v.shape
         assert np.array_equal(np.asarray(item["tensor"]), v)
@@ -325,9 +333,10 @@ def test_results_equal_the_leaf_at_a_time_staging(hvd, per_process, caller,
     assert moved(before)["compiled"] > 0
     with monkeypatch.context() as m:
         m.setattr(eager, "_stack_members", a_leaf_at_a_time)
+        m.setattr(eager, "_all_held", lambda *a: False)     # nor packed
         before = counts()
         want = CALLERS[caller](hvd, per_process, (9, 4), 3)
-        assert moved(before) == {"compiled": 0, "traces": 0}
+        assert moved(before) == {"compiled": 0, "traces": 0, "packed": 0}
     la, lb = jax.tree_util.tree_leaves(out), jax.tree_util.tree_leaves(want)
     assert len(la) == len(lb) > 0
     for a, b in zip(la, lb):
@@ -346,12 +355,18 @@ def test_stage_span_counts_the_members_the_program_took(hvd, per_process,
     monkeypatch.setattr(core, "_installed", rec)
     g = grads_of((5, 2), 6)
     opt_mod.allreduce_gradients(g, process_set=per_process)
+    monkeypatch.setattr(eager, "_all_held", lambda *a: False)
+    opt_mod.allreduce_gradients(g, process_set=per_process)
     monkeypatch.setattr(eager, "_stack_members", a_leaf_at_a_time)
     opt_mod.allreduce_gradients(g, process_set=per_process)
     nbytes = sum(int(v.nbytes) for v in g.values())
     seen = [e["ids"] for e in ann.events if e["name"] == "hvd/update/stage"]
-    assert seen == [{"n": 3, "bytes": nbytes, "compiled": 3},
-                    {"n": 3, "bytes": nbytes, "compiled": 0}]
+    assert seen == [{"n": 3, "bytes": nbytes, "compiled": 3, "packed": 3,
+                     "buffers": 1},
+                    {"n": 3, "bytes": nbytes, "compiled": 3, "packed": 0,
+                     "buffers": 3},
+                    {"n": 3, "bytes": nbytes, "compiled": 0, "packed": 0,
+                     "buffers": 3}]
 
 
 def test_monitor_agent_exports_the_two_counts(hvd, per_process):

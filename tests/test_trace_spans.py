@@ -217,6 +217,7 @@ def traced():
     return {"events": sorted(ann.events, key=lambda e: e["t0"]),
             "totals": rec.span_totals(), "tensor_cycles": tensor_cycles,
             "caller": threading.get_ident(), "leaves": 2,
+            "items": 1,             # both float32: one flat engine item
             "bytes": 4 * 8}        # 5 + 3 float32
 
 
@@ -231,7 +232,7 @@ def within(inner, outer):
 
 @pytest.mark.parametrize("name,ids", [
     ("hvd/update", {"step", "group"}),
-    ("hvd/update/stage", {"n", "bytes", "compiled"}),
+    ("hvd/update/stage", {"n", "bytes", "compiled", "packed", "buffers"}),
     ("hvd/update/submit", {"group"}),
     ("hvd/update/wait", {"group"}),
     ("hvd/update/unpack", {"n", "bytes", "host"}),
@@ -254,6 +255,8 @@ def test_calling_thread_span(traced, name, ids):
             assert sp["ids"]["host"] == 0
         if name == "hvd/update/stage":  # every leaf through the one program
             assert sp["ids"]["compiled"] == sp["ids"]["n"]
+            assert sp["ids"]["packed"] == sp["ids"]["n"]    # and flat
+            assert sp["ids"]["buffers"] == traced["items"]
         elif "compiled" in ids:     # optax.sgd traces: the one program
             assert sp["ids"]["compiled"] == 1
     if name == "hvd/update":
@@ -283,7 +286,7 @@ def test_engine_thread_span(traced, name):
         for c, sub in zip(cycles, submits):
             assert c["thread"] != traced["caller"]
             assert set(c["ids"]) == {"cycle", "n", "groups"}
-            assert c["ids"]["n"] == traced["leaves"]
+            assert c["ids"]["n"] == traced["items"]
             # caused by that update's submit: its group, and after it began
             assert c["ids"]["groups"] == str(sub["ids"]["group"])
             assert c["t0"] >= sub["t0"]
@@ -303,7 +306,7 @@ def test_engine_thread_span(traced, name):
         for k, (d, c) in enumerate(zip(spans, cycles)):
             assert within(d, c)
             assert d["ids"] == {"cycle": c["ids"]["cycle"],
-                                "n": traced["leaves"],
+                                "n": traced["items"],
                                 "bytes": traced["bytes"],
                                 "hit": int(k > 0)}   # built once, then found
             neg = [s for s in named(traced, "hvd/cycle/negotiate")
@@ -317,7 +320,7 @@ def test_engine_thread_span(traced, name):
         for s, c in zip(spans, cycles):     # the in-flight watcher's
             assert s["thread"] not in (cycle_thread, traced["caller"])
             assert s["ids"] == {"cycle": c["ids"]["cycle"],
-                                "n": traced["leaves"]}
+                                "n": traced["items"]}
 
 
 # ---------------------------------------- what stays out while tracing a step
