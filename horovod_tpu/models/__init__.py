@@ -6,7 +6,8 @@ framework deps) eagerly.
 """
 
 _FAMILIES = ("llama", "gpt2", "bert", "vit", "resnet", "moe", "dlrm",
-             "mnist", "convert", "qwen3_next", "olmo_hybrid", "gated_delta")
+             "mnist", "convert", "qwen3_next", "olmo_hybrid", "gated_delta",
+             "nemotron_h", "mamba2")
 
 __all__ = list(_FAMILIES)
 

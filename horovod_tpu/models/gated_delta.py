@@ -187,6 +187,20 @@ def by_head_groups(rule, token_heads):
     return grouped
 
 
+def causal_conv_silu(x, kernel, bias=None):
+    """``SiLU(conv(x) + bias)`` for x ``[B, T, channels]`` and a causal
+    depthwise ``kernel [taps, channels]``: tap ``j`` weighs the input
+    ``taps - 1 - j`` back.  Float32 inside, x's type out.  The recurrent
+    mixers' (this one's, without a bias, and ``mamba2``'s)."""
+    f32, taps, T = jnp.float32, kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
+    conv = kernel.astype(f32)
+    y = sum(conv[j] * padded[:, j:j + T] for j in range(taps))
+    if bias is not None:
+        y = y + bias.astype(f32)
+    return jax.nn.silu(y).astype(x.dtype)
+
+
 def gate_inputs(ba, p, dims: GatedDeltaDims):
     """``(beta, g)`` float32 ``[B, T, v_heads]`` from the ``[b|a]``
     projection (float32) and the layer's ``A_log`` and ``dt_bias``."""
@@ -213,12 +227,7 @@ def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
                         preferred_element_type=f32)
         qkv, z = qkvz[..., :dims.qkv_width], qkvz[..., -hv * dv:]
     with jax.named_scope("gdn/conv"):
-        # causal and depthwise: tap j weighs the input conv_kernel-1-j back
-        taps = dims.conv_kernel
-        padded = jnp.pad(qkv, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
-        conv = p["conv"].astype(f32)
-        qkv = jax.nn.silu(sum(conv[j] * padded[:, j:j + T]
-                              for j in range(taps))).astype(x.dtype)
+        qkv = causal_conv_silu(qkv, p["conv"])
     with jax.named_scope("gdn/scan"):
         def heads(y, n, dim, repeat=1):
             y = y.reshape(B, T, n, dim).astype(f32)

@@ -250,16 +250,39 @@ def moe_ffn(x, params, cfg: MoEConfig,
 # ------------------------------------------------- dropless share-aware layer
 @dataclasses.dataclass(frozen=True)
 class DroplessMoEConfig:
-    """A dropless top-k expert layer that is told which experts it holds.
+    """A dropless top-k expert layer that is told which experts it holds,
+    and what the model says of its experts.
 
     The router keeps the model's full width (``n_experts``) and its
     ``top_k``; this rank holds experts ``first_expert .. first_expert +
     experts_held`` and computes the part of the layer's result that they
     give for the tokens routed to them.  What the other experts would add
-    is another rank's part: summed over all shares (the shared expert
-    counted once) the parts are the whole layer.  No exchange is made
+    is another rank's part: summed over all shares (what every rank
+    computes alike — the shared expert, and with a latent its projections
+    — counted once) the parts are the whole layer.  No exchange is made
     here — one share on one chip runs as it stands; the all-to-all that
-    brings every rank's tokens to a share is ROADMAP queue 2 A's."""
+    brings every rank's tokens to a share is ROADMAP queue 2 A's.
+
+    Fields of the model, not options of the system:
+
+    ``scoring``      ``softmax``: probabilities over all experts, the
+                     ``top_k`` largest renormalised to sum to 1.
+                     ``sigmoid``: scores ``s = sigmoid(logits)``; the
+                     ``top_k`` with the largest ``s + b`` are chosen (``b``
+                     the selection bias ``router_bias``, a buffer no
+                     gradient reaches) and weighed by their ``s`` over its
+                     sum.  Either way the weights are then multiplied by
+                     ``routed_scale``.
+    ``expert_form``  ``swiglu``: three matrices, ``(silu(x W1) * x W3)
+                     W2``.  ``relu2``: two, ``relu(x W1)^2 W2``.  The
+                     shared expert has the same form.
+    ``d_latent``     0: the routed experts act on ``d_model``.  Otherwise
+                     they act in a latent of that width between ``w_down``
+                     and ``w_up``, which every rank computes (``w_up`` is
+                     linear: the shares' latent sums add).  The router and
+                     the shared expert read ``d_model`` either way.
+    ``shared_gate``  the shared expert's result times ``sigmoid(x w_g)``,
+                     or as it is."""
     d_model: int = 64
     d_ff: int = 128                     # a routed expert's width
     n_experts: int = 8                  # the router's width
@@ -268,11 +291,25 @@ class DroplessMoEConfig:
     experts_held: Optional[int] = None  # None = all of them
     d_shared: int = 0                   # the shared expert's width, 0 = none
     dtype: Any = jnp.float32
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    expert_form: str = "swiglu"
+    d_latent: int = 0
+    shared_gate: bool = True
 
     @property
     def held(self) -> int:
         return self.n_experts if self.experts_held is None \
             else self.experts_held
+
+    @property
+    def d_expert_io(self) -> int:
+        """The width a routed expert reads and writes."""
+        return self.d_latent or self.d_model
+
+    @property
+    def gated(self) -> bool:
+        return self.expert_form == "swiglu"
 
     def __post_init__(self):
         if not 1 <= self.top_k <= self.n_experts:
@@ -284,24 +321,39 @@ class DroplessMoEConfig:
             raise ValueError(
                 f"experts {self.first_expert}..{self.first_expert + self.held}"
                 f" are not among the {self.n_experts}")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring={self.scoring!r}: softmax or sigmoid")
+        if self.expert_form not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_form={self.expert_form!r}: swiglu or "
+                             f"relu2")
 
 
 def dropless_init_params(cfg: DroplessMoEConfig, key) -> Dict:
     kr, k1, k2, k3, ks = jax.random.split(key, 5)
     E, H, D, F = cfg.n_experts, cfg.held, cfg.d_model, cfg.d_ff
+    L = cfg.d_expert_io
 
     def dense(k, fan_in, shape):
         return (jax.random.normal(k, shape, jnp.float32)
                 / np.sqrt(fan_in)).astype(cfg.dtype)
 
-    p = {"router": dense(kr, D, (D, E)), "w1": dense(k1, D, (H, D, F)),
-         "w3": dense(k3, D, (H, D, F)), "w2": dense(k2, F, (H, F, D))}
+    p = {"router": dense(kr, D, (D, E)), "w1": dense(k1, L, (H, L, F)),
+         "w2": dense(k2, F, (H, F, L))}
+    if cfg.gated:
+        p["w3"] = dense(k3, L, (H, L, F))
+    if cfg.scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((E,), cfg.dtype)
+    if cfg.d_latent:
+        kd, ku = jax.random.split(kr)
+        p.update(w_down=dense(kd, D, (D, L)), w_up=dense(ku, L, (L, D)))
     if cfg.d_shared:
         s1, s3, s2, sg = jax.random.split(ks, 4)
         p.update(shared_w1=dense(s1, D, (D, cfg.d_shared)),
-                 shared_w3=dense(s3, D, (D, cfg.d_shared)),
-                 shared_w2=dense(s2, cfg.d_shared, (cfg.d_shared, D)),
-                 shared_gate=dense(sg, D, (D,)))
+                 shared_w2=dense(s2, cfg.d_shared, (cfg.d_shared, D)))
+        if cfg.gated:
+            p["shared_w3"] = dense(s3, D, (D, cfg.d_shared))
+        if cfg.shared_gate:
+            p["shared_gate"] = dense(sg, D, (D,))
     return p
 
 
@@ -344,15 +396,139 @@ _gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
 _sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
 
 
-def dropless_route(x, router_w, cfg: DroplessMoEConfig):
-    """``(ids [S, top_k], weights [S, top_k] float32)``: softmax over ALL
-    ``n_experts`` in float32 (the product at ``HIGHEST`` precision: which
-    expert is tenth hangs on it), the ``top_k`` largest renormalised to
-    sum to 1."""
+def dropless_route(x, router_w, cfg: DroplessMoEConfig, bias=None):
+    """``(ids [S, top_k], weights [S, top_k] float32)`` over ALL
+    ``n_experts``, in float32 (the product at ``HIGHEST`` precision: which
+    expert is last among the chosen hangs on it).  ``softmax`` scoring: the
+    ``top_k`` largest probabilities, renormalised to sum to 1.  ``sigmoid``
+    scoring: the ``top_k`` largest of ``s + bias`` (the layer's
+    ``router_bias``), weighed by their ``s`` over its sum: the bias chooses
+    and does not weigh.  The weights times ``routed_scale``."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    top, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
-    return ids, top / jnp.sum(top, axis=-1, keepdims=True)
+    if cfg.scoring == "softmax":
+        top, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, ids = lax.top_k(scores + bias.astype(jnp.float32), cfg.top_k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    return ids, (weights if cfg.routed_scale == 1.0
+                 else weights * cfg.routed_scale)
+
+
+# a block of the sorted assignments is this many times the rows that even
+# routing sends to the held experts (``dropless_blocks``)
+BLOCK_OVER_EXPECTED = 8
+
+
+def dropless_blocks(rows, cfg: DroplessMoEConfig) -> int:
+    """In how many equal blocks the ``rows`` sorted assignments are taken:
+    as many as leave a block ``BLOCK_OVER_EXPECTED`` times the rows that
+    even routing sends to the held experts, among the divisors of ``rows``.
+    One block down to a share of an eighth; four at a thirty-second."""
+    most = max(1, cfg.n_experts // (BLOCK_OVER_EXPECTED * cfg.held))
+    return max(n for n in range(1, most + 1) if rows % n == 0)
+
+
+def _expert_products(rows, gate, here, counts, params, cfg):
+    """The held experts' part for a run of sorted assignments: ``rows [R,
+    d_expert_io]`` of which ``counts [experts_held]`` lead, expert by
+    expert, ``gate [R]`` the router's weights, ``here [R, 1]`` which rows
+    are held at all."""
+    def grouped(lhs, rhs):
+        """The held experts' groups of rows times their matrices.  Rows
+        past the groups belong to other shares, and the grouped product
+        leaves them as they were in both passes (uninitialised memory, NaN
+        at times): they are zeroed where they go in and where they come
+        out, and so is their gradient."""
+        lhs = jnp.where(here, lhs, 0)
+        return jnp.where(here, lax.ragged_dot(lhs, rhs, counts), 0)
+
+    if cfg.gated:
+        hidden = jax.nn.silu(grouped(rows, params["w1"])) * grouped(
+            rows, params["w3"])
+        # the router's weight on the narrow side of the down projection
+        hidden = (hidden.astype(jnp.float32) * gate[:, None]).astype(
+            rows.dtype)
+    else:
+        hidden = jax.nn.relu(grouped(rows, params["w1"])).astype(jnp.float32)
+        hidden = (hidden * hidden * gate[:, None]).astype(rows.dtype)
+    return grouped(hidden, params["w2"])
+
+
+def _in_blocks(blocks, *buffers):
+    """``buffers`` (sorted assignments lead) cut into ``blocks`` equal runs,
+    and where each run begins."""
+    R = buffers[0].shape[0] // blocks
+    return tuple(b.reshape((blocks, R) + b.shape[1:]) for b in buffers) + (
+        jnp.arange(blocks, dtype=jnp.int32) * R,)
+
+
+def _block_groups(lo, rows, held_counts):
+    """``(counts, here, any)`` for the block of ``rows`` sorted assignments
+    that begins at ``lo``: the part of each held expert's group that lies
+    in it, which of its rows are held at all, and whether any is."""
+    ends = jnp.cumsum(held_counts)
+    starts = ends - held_counts
+    counts = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo),
+                      0)
+    return counts, (lo + jnp.arange(rows) < ends[-1])[:, None], lo < ends[-1]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _blocked_products(cfg, blocks, rows, gate, held_counts, w):
+    """:func:`_expert_products` over the sorted assignments in ``blocks``
+    equal blocks, one after the other; a block that begins past the last
+    held row is skipped in both passes (``lax.cond``), and its part of the
+    result is zero.  The backward pass recomputes a block's products inside
+    the branch that differentiates them, so that nothing an expert's width
+    wide crosses a branch's boundary, and carries the matrices' gradients
+    through the blocks: a skipped block neither fills nor adds one."""
+    def block(of_block):
+        rows_b, gate_b, lo = of_block
+        counts, here, held = _block_groups(lo, rows_b.shape[0], held_counts)
+        return lax.cond(
+            held,
+            lambda: _expert_products(rows_b, gate_b, here, counts, w, cfg),
+            lambda: jnp.zeros_like(rows_b))
+
+    return lax.map(block, _in_blocks(blocks, rows, gate)).reshape(rows.shape)
+
+
+def _blocked_products_fwd(cfg, blocks, rows, gate, held_counts, w):
+    return (_blocked_products(cfg, blocks, rows, gate, held_counts, w),
+            (rows, gate, held_counts, w))
+
+
+def _blocked_products_bwd(cfg, blocks, res, ct):
+    rows, gate, held_counts, w = res
+
+    def block(d_w, of_block):
+        rows_b, gate_b, ct_b, lo = of_block
+        counts, here, held = _block_groups(lo, rows_b.shape[0], held_counts)
+
+        def run(d_w):
+            _, back = jax.vjp(
+                lambda r, g, w_: _expert_products(r, g, here, counts, w_,
+                                                  cfg), rows_b, gate_b, w)
+            d_rows, d_gate, d_w_b = back(ct_b)
+            return jax.tree_util.tree_map(jnp.add, d_w, d_w_b), (d_rows,
+                                                                 d_gate)
+
+        return lax.cond(
+            held, run,
+            lambda d_w: (d_w, (jnp.zeros_like(rows_b),
+                               jnp.zeros_like(gate_b))), d_w)
+
+    d_w, (d_rows, d_gate) = lax.scan(
+        block, jax.tree_util.tree_map(jnp.zeros_like, w),
+        _in_blocks(blocks, rows, gate, ct))
+    return (d_rows.reshape(rows.shape), d_gate.reshape(gate.shape), None,
+            d_w)
+
+
+_blocked_products.defvjp(_blocked_products_fwd, _blocked_products_bwd)
 
 
 def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
@@ -367,10 +543,19 @@ def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
     (``lax.ragged_dot``) compute the rows the held experts' groups cover.
     Static shapes; between no assignment here and all of them the result
     is exact.
+
+    Where the share is small the sorted rows are taken in equal blocks
+    (:func:`dropless_blocks`, :func:`_blocked_products`), each recomputed
+    in the backward pass, and a block that begins past the last held row
+    is skipped (``lax.cond``): an expert's hidden width is then held for a
+    block, not for every assignment made anywhere, and with even routing
+    the first block is the only one computed.  With a latent (``d_latent``) the rows are the
+    latent's, under the scope ``moe/latent`` with the projection back.
     """
     K, H = cfg.top_k, cfg.held
     with jax.named_scope("moe/route"):
-        ids, weights = dropless_route(x, params["router"], cfg)
+        ids, weights = dropless_route(x, params["router"], cfg,
+                                      params.get("router_bias"))
         local = ids.reshape(-1) - cfg.first_expert          # [S * K]
         here = (local >= 0) & (local < H)
         # held assignments first, by expert; the others after every group
@@ -382,29 +567,35 @@ def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
             axis=0, dtype=jnp.int32)
         gate, here = weights.reshape(-1)[order], here[order][:, None]
 
-    def grouped(lhs, rhs):
-        """The held experts' groups of rows times their matrices.  Rows
-        past the groups belong to other shares, and the grouped product
-        leaves them as they were in both passes (uninitialised memory, NaN
-        at times): they are zeroed where they go in and where they come
-        out, and so is their gradient."""
-        lhs = jnp.where(here, lhs, 0)
-        return jnp.where(here, lax.ragged_dot(lhs, rhs, held_counts), 0)
-
+    z = x
+    if cfg.d_latent:
+        with jax.named_scope("moe/latent"):
+            z = x @ params["w_down"]
     with jax.named_scope("moe/dispatch"):
-        rows = _gather_rows(x, order, inverse, K)           # [S * K, D]
+        rows = _gather_rows(z, order, inverse, K)           # [S * K, L]
     with jax.named_scope("moe/experts"):
-        hidden = jax.nn.silu(grouped(rows, params["w1"])) * grouped(
-            rows, params["w3"])
-        # the router's weight on the narrow side of the down projection
-        hidden = (hidden.astype(jnp.float32) * gate[:, None]).astype(x.dtype)
-        out = grouped(hidden, params["w2"])
+        blocks = dropless_blocks(rows.shape[0], cfg)
+        if blocks == 1:
+            out = _expert_products(rows, gate, here, held_counts, params,
+                                   cfg)
+        else:
+            out = _blocked_products(
+                cfg, blocks, rows, gate, held_counts,
+                {k: params[k] for k in ("w1", "w2", "w3") if k in params})
     with jax.named_scope("moe/combine"):
         y = _sum_rows(out, order, inverse, K)
+    if cfg.d_latent:
+        with jax.named_scope("moe/latent"):
+            y = y @ params["w_up"]
     if cfg.d_shared:
         with jax.named_scope("moe/shared"):
-            hidden = jax.nn.silu(x @ params["shared_w1"]) * (
-                x @ params["shared_w3"])
+            if cfg.gated:
+                hidden = jax.nn.silu(x @ params["shared_w1"]) * (
+                    x @ params["shared_w3"])
+            else:
+                hidden = jnp.square(jax.nn.relu(x @ params["shared_w1"]))
+            if not cfg.shared_gate:
+                return y + hidden @ params["shared_w2"], held_counts
             # One logit a token decides a whole row: it and the row it
             # weighs stay float32 until they are multiplied (rounded to
             # the storage type first, they are most of what the gate's own
