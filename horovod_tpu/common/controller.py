@@ -203,6 +203,10 @@ class TCPController:
         self.spec_rounds = 0
         self.inflight_high_water = 0
         self.last_round_speculative = False
+        # Whether the responses the last _round read carried no verdict
+        # for ANY rank (ready, error, slot assignment: the server
+        # broadcasts them all).  The engine's idle back-off reads it.
+        self.last_round_quiet = True
         # Ranks the server reported as cleanly departed (LVE6 notice
         # sections), cumulative for this controller generation.  A
         # non-empty list means the world SHRANK without a fault: the
@@ -342,6 +346,12 @@ class TCPController:
         under speculation or ``round_pipeline > 1``)."""
         return len(self._outstanding)
 
+    @property
+    def join_open(self) -> bool:
+        """This rank asked to join, or is joined and synthesizing its
+        peers' collectives, until every rank has joined."""
+        return self._joined or self._join_pending
+
     def _round(self, announces: Sequence) -> tuple:
         """announces: (name, required_ranks, digest, group, datadep, tag
         [, entry]) tuples; required 0 = world.  Tuples whose slot is known
@@ -365,6 +375,7 @@ class TCPController:
         acc_warns: List[str] = []
         acc_errors: List[tuple] = []
         acc = (acc_ready, acc_warns, acc_errors)
+        self.last_round_quiet = True        # until a response says otherwise
         depth = max(1, int(self.round_pipeline))
         # Deferred responses first: bound the in-flight window, then
         # opportunistically consume anything already buffered (refreshes
@@ -627,6 +638,7 @@ class TCPController:
         # announced in full (the server broadcasts to every rank).
         # Processed BEFORE the ready bitvector so a slot assigned and made
         # ready in the same round resolves.
+        n_assign = 0
         if off < len(data):
             (n_assign,) = struct.unpack_from("<I", data, off)
             off += 4
@@ -667,6 +679,8 @@ class TCPController:
                 key = self._slot_keys.get(i)
                 if key is not None:
                     ready.append((key[0], key[1], "-1"))
+        if ready or errors or n_assign or actual_bits:
+            self.last_round_quiet = False
         if spec_slots is not None:
             if spec_slots <= actual_bits:
                 self.spec_hits += 1

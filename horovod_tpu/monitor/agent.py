@@ -126,6 +126,13 @@ class MonitorAgent:
             reg.gauge("hvd_last_cycle_age_s",
                       "seconds since the last coordinator cycle").set(
                 round(time.time() - last, 3) if last else -1)
+            reg.counter("hvd_idle_cycles_total",
+                        "coordinator cycles that did nothing (each "
+                        "doubles the idle wait)").set_total(
+                getattr(engine, "idle_cycles", 0))
+            reg.gauge("hvd_idle_wait_s",
+                      "the wait in force between coordinator cycles "
+                      "(s)").set(getattr(engine, "idle_wait_s", 0.0))
             reg.counter("hvd_negotiation_us_total",
                         "cumulative negotiation wall time (us)").set_total(
                 getattr(engine, "negotiation_us_total", 0.0))

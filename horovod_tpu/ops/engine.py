@@ -56,6 +56,12 @@ from ..utils.logging import get_logger
 
 log = get_logger()
 
+# Idle back-off (docs/tensor-fusion.md "Cycle time"): the longest the cycle
+# thread waits between cycles while nothing is enqueued.  A cycle that did
+# something is followed by a wait of ``cycle_time_s``; each cycle that did
+# nothing doubles it, up to this.  Any wake ends the wait at once.
+IDLE_WAIT_CAP_S = 0.032
+
 
 class CollectiveType(enum.Enum):
     ALLREDUCE = "allreduce"
@@ -383,6 +389,12 @@ class CollectiveEngine:
         self.cycle_us_total = 0.0
         self.cycle_count = 0
         self.last_cycle_ts = 0.0
+        # Idle back-off: cycles that did nothing (``_cycle_did_nothing``),
+        # the wait now in force between cycles, and the wait the cycle
+        # thread last sat out (the ``hvd/cycle`` span's ``waited_ms``).
+        self.idle_cycles = 0
+        self.idle_wait_s = self.cycle_time_s
+        self._waited_s = 0.0
         self.monitor = None
         # Distributed collective tracing (HOROVOD_TRACE, horovod_tpu.trace):
         # per-tensor lifecycle spans (queue/negotiation/copy_in/reduce/
@@ -919,8 +931,13 @@ class CollectiveEngine:
     # ------------------------------------------------------------- main loop
     def _background_loop(self):
         while not self._shutdown.is_set():
-            self._wake.wait(timeout=self.cycle_time_s)
+            # Callers enqueue, then set; this loop clears, then drains: a
+            # wake that lands after the clear ends the NEXT wait at once,
+            # so no entry ever sits out an idle wait.
+            t0 = time.monotonic()
+            self._wake.wait(timeout=self.idle_wait_s)
             self._wake.clear()
+            self._waited_s = time.monotonic() - t0
             try:
                 self.run_loop_once()
             except Exception:       # pragma: no cover - engine bug surface
@@ -953,24 +970,26 @@ class CollectiveEngine:
         them — or waiters in ``synchronize()`` would hang forever.
         """
         with self._cycle_lock:
-            self._run_cycle_locked()
+            self._set_idle_wait(self._run_cycle_locked())
 
-    def _run_cycle_locked(self):
+    def _run_cycle_locked(self) -> bool:
+        """One cycle; returns whether it did nothing
+        (``_cycle_did_nothing``)."""
         t_cycle0 = time.perf_counter()
         self._cycle_index += 1
         tl = self._state.timeline
         if tl is not None:
             tl.mark_cycle(self._cycle_index)
+        waited_s, self._waited_s = self._waited_s, 0.0
         self._drain_ckpt_staging()
         entries = self.queue.drain()
         if not entries and self.controller is None and not self._backlog:
             # (The backlog check keeps the checkpoint lane draining on
             # otherwise-idle single-controller cycles.)
-            return
+            return True
         tr = self.tracer
         if tr is None:
-            self._negotiate_and_dispatch(entries, t_cycle0)
-            return
+            return self._negotiate_and_dispatch(entries, t_cycle0)
         t_drain = time.monotonic()
         t_trace0 = t_drain - (time.perf_counter() - t_cycle0)
         for e in entries:
@@ -985,16 +1004,52 @@ class CollectiveEngine:
         # spans carry ('|'-joined: the profile splits stats at commas).
         groups = sorted({e.group_id for e in entries if e.group_id >= 0})
         with tr.span("hvd/cycle", n=len(entries),
-                     groups="|".join(map(str, groups))) as cyc:
-            self._negotiate_and_dispatch(entries, t_cycle0, tr, cyc,
-                                         t_trace0, t_drain)
+                     groups="|".join(map(str, groups)),
+                     waited_ms=round(waited_s * 1e3, 3)) as cyc:
+            return self._negotiate_and_dispatch(entries, t_cycle0, tr, cyc,
+                                                t_trace0, t_drain)
+
+    def _set_idle_wait(self, idle: bool) -> None:
+        """The wait before the next cycle: ``cycle_time_s`` after a cycle
+        that did something, twice the last wait after one that did
+        nothing, up to ``IDLE_WAIT_CAP_S`` — and to a quarter of the
+        controller's round deadline, so a live idle rank never misses a
+        round — but never under ``cycle_time_s``."""
+        cycle = self.cycle_time_s
+        if not idle:
+            self.idle_wait_s = cycle
+            return
+        self.idle_cycles += 1
+        cap = IDLE_WAIT_CAP_S
+        deadline = getattr(self.controller, "round_timeout_s", 0.0)
+        if deadline:
+            cap = min(cap, deadline / 4)
+        self.idle_wait_s = max(cycle, min(self.idle_wait_s * 2, cap))
+
+    def _cycle_did_nothing(self, entries, responses) -> bool:
+        """Whether this cycle may lengthen the next wait: nothing drained
+        (a not-ready entry is requeued, so drained again), nothing
+        dispatched or left in the backlog or the checkpoint staging, and a
+        controller with no round in flight, no join pending or open (a
+        joined rank synthesizes its peers' collectives and keeps their
+        pace) and a last round that carried no verdict for ANY rank — the
+        server broadcasts every verdict, so a rank outside a process set
+        stays on the short wait while its peers' collectives stream."""
+        if entries or responses or self._backlog or self._ckpt_staging:
+            return False
+        ctl = self.controller
+        return ctl is None or (
+            getattr(ctl, "last_round_quiet", True)
+            and not getattr(ctl, "inflight_rounds", 0)
+            and not getattr(ctl, "join_open", False))
 
     def _negotiate_and_dispatch(self, entries, t_cycle0: float, tr=None,
                                 cyc=None, t_trace0: float = 0.0,
                                 t_drain: float = 0.0):
         """The cycle past the queue's drain: negotiate, batch, dispatch,
         account.  Tracing armed, ``tr`` is the recorder and ``cyc`` the
-        open ``hvd/cycle`` span."""
+        open ``hvd/cycle`` span.  Returns whether the cycle did nothing
+        (``_cycle_did_nothing``)."""
         tl = self._state.timeline
         # Multi-process mode: every rank must complete a (possibly empty)
         # lock-step negotiation round each cycle, or peers with pending
@@ -1035,7 +1090,7 @@ class CollectiveEngine:
                     tr.commit(sp)
                 self.queue.mark_done(e)
                 e.done.set()
-            return
+            return False
         if not_ready:
             self.queue.requeue(not_ready)
         t_ready = 0.0
@@ -1153,6 +1208,7 @@ class CollectiveEngine:
         self.last_cycle_ts = time.time()
         if self.monitor is not None:
             self.monitor.on_cycle(dt_us)
+        return self._cycle_did_nothing(entries, responses)
 
     # --------------------------------------------------------- negotiation
     def _compute_response_list(self, entries) -> List[List[TensorTableEntry]]:

@@ -326,6 +326,28 @@ def test_http_exporter_metrics_health_snapshot():
         agent.close()
 
 
+def test_http_exporter_carries_idle_backoff_series():
+    """The cycle thread's idle back-off (ops/engine.py): how many cycles
+    did nothing, and the wait in force between cycles."""
+    eng = FakeEngine()
+    eng.idle_cycles, eng.idle_wait_s = 7, 0.016
+    agent = MonitorAgent(engine=eng, rank=0, world=1, interval_s=0.1)
+    srv = agent.serve_http(0)
+    try:
+        url = f"http://127.0.0.1:{srv.port}/metrics"
+        text = urllib.request.urlopen(url).read().decode()
+        assert "# TYPE hvd_idle_cycles_total counter" in text
+        assert 'hvd_idle_cycles_total{rank="0"} 7' in text
+        assert "# TYPE hvd_idle_wait_s gauge" in text
+        assert 'hvd_idle_wait_s{rank="0"} 0.016' in text
+        eng.idle_cycles, eng.idle_wait_s = 9, 0.001     # work came
+        text = urllib.request.urlopen(url).read().decode()
+        assert 'hvd_idle_cycles_total{rank="0"} 9' in text
+        assert 'hvd_idle_wait_s{rank="0"} 0.001' in text
+    finally:
+        agent.close()
+
+
 def test_http_exporter_carries_zero_rtt_counters():
     """ISSUE 11 observability: with a real controller attached, /metrics
     exports the speculation outcome counters and the in-flight round

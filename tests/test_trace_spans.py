@@ -200,7 +200,13 @@ def traced():
         for i in range(UPDATES):
             grads = {"w": jnp.full((5,), 1.0 + i), "b": jnp.full((3,), 2.0)}
             updates, state = opt.update(grads, state, params)
-            time.sleep(0.03)        # a few empty lock-step rounds between
+            # an empty lock-step round between: wake the loop (idle, it
+            # may be waiting out IDLE_WAIT_CAP_S) and see the round end
+            rounds, deadline = eng.controller.rounds, time.monotonic() + 10
+            eng.kick()
+            while eng.controller.rounds == rounds:
+                assert time.monotonic() < deadline, "the loop never woke"
+                time.sleep(0.001)
         assert float(updates["b"][0]) < 0.0
         if eng._inflight is not None:
             eng._inflight.flush(10.0)
@@ -285,7 +291,7 @@ def test_engine_thread_span(traced, name):
     if name == "hvd/cycle":
         for c, sub in zip(cycles, submits):
             assert c["thread"] != traced["caller"]
-            assert set(c["ids"]) == {"cycle", "n", "groups"}
+            assert set(c["ids"]) == {"cycle", "n", "groups", "waited_ms"}
             assert c["ids"]["n"] == traced["items"]
             # caused by that update's submit: its group, and after it began
             assert c["ids"]["groups"] == str(sub["ids"]["group"])
@@ -295,6 +301,9 @@ def test_engine_thread_span(traced, name):
         # a lock-step round with no tensor in it: its span, n = 0
         empty = [c for c in spans if c["ids"]["n"] == 0]
         assert empty and all(c["ids"]["groups"] == "" for c in empty)
+        # the wait the cycle thread sat out before each cycle, in ms
+        assert all(isinstance(c["ids"]["waited_ms"], float)
+                   and c["ids"]["waited_ms"] >= 0.0 for c in spans)
     elif name == "hvd/cycle/negotiate":
         assert len(spans) == len(named(traced, "hvd/cycle"))
         for c in cycles:            # one a round, inside it, its round id
