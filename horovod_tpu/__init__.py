@@ -18,6 +18,14 @@ surface so users can write ``import horovod_tpu as hvd``:
 
 __version__ = "0.1.0"
 
+# The start-up record (trace/core.py): ``hvd/process`` ends and
+# ``hvd/import`` begins at this line, before anything else is imported.
+import sys as _sys
+import time as _time
+_import_t0, _jax_imported = _time.time(), "jax" in _sys.modules
+from .trace import core as _startup_record  # noqa: E402
+_import_span = _startup_record.begin_import(_import_t0, _jax_imported)
+
 from .common.basics import (  # noqa: F401
     init, shutdown, is_initialized,
     rank, size, local_rank, local_size, cross_rank, cross_size,
@@ -55,3 +63,5 @@ from . import callbacks  # noqa: F401
 from . import checkpoint  # noqa: F401
 from . import data  # noqa: F401
 from . import analysis  # noqa: F401  (collective-correctness analyzer)
+
+_import_span.__exit__(None, None, None)     # ``hvd/import`` ends here

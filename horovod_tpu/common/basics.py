@@ -24,6 +24,7 @@ from typing import List, Optional, Sequence
 
 import jax
 
+from ..trace import core as _trace
 from .config import Config
 from .process_sets import ProcessSet, ProcessSetTable, global_process_set
 from .topology import Topology, build_topology
@@ -89,6 +90,18 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None,
     with st._lock:
         if st.initialized:
             return
+        # The start-up record (trace/core.py): ``hvd/init`` and a span a
+        # phase below it, kept whether tracing is armed or not.
+        _trace.startup_freeze(False)
+        with _trace.startup_span("hvd/init") as whole:
+            _init_locked(st, process_sets, devices, axis_name, whole)
+        st.initialized = True
+
+
+def _init_locked(st, process_sets, devices, axis_name, whole) -> None:
+    """``init()`` under the state's lock and the ``hvd/init`` span."""
+    span = _trace.startup_span
+    with span("hvd/init/config") as sp:
         st.config = Config.from_env()
 
         # Elastic workers fetch rank/size/coordinator from the driver's
@@ -96,17 +109,20 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None,
         if st.config.elastic and _env_has_rendezvous():
             from ..elastic.worker import elastic_bootstrap
             st.config = elastic_bootstrap()
+        sp.set(elastic=int(st.config.elastic))
 
-        # Multi-process bootstrap (launched by torovodrun, SURVEY.md §3.3):
-        # jax.distributed forms the global device world at controller_port;
-        # the native negotiation controller lives at controller_port + 1.
-        cfg = st.config
-        multi_process = (cfg.controller_addr != ""
-                         and (cfg.size_env > 1 or cfg.elastic))
-        # NB: must not touch jax.devices()/process_count() before
-        # jax.distributed.initialize — any backend query finalizes the
-        # single-process world.
-        from jax._src import distributed as _jdist
+    # Multi-process bootstrap (launched by torovodrun, SURVEY.md §3.3):
+    # jax.distributed forms the global device world at controller_port;
+    # the native negotiation controller lives at controller_port + 1.
+    cfg = st.config
+    multi_process = (cfg.controller_addr != ""
+                     and (cfg.size_env > 1 or cfg.elastic))
+    # NB: must not touch jax.devices()/process_count() before
+    # jax.distributed.initialize — any backend query finalizes the
+    # single-process world.
+    from jax._src import distributed as _jdist
+    with span("hvd/init/distributed", processes=(
+            cfg.size_env if multi_process else 1)):
         if _jdist.global_state.client is None:
             # torovodrun spawns one process per rank (reference §3.3) and
             # provides the coordinator; in pod mode
@@ -136,29 +152,50 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None,
             elif cfg.one_proc_per_host and not cfg.controller_addr:
                 jax.distributed.initialize()
 
+    from jax._src import xla_bridge
+    # ``fresh`` 0: the script asked jax for its devices before
+    # ``hvd.init()`` and the client opened there, not here
+    with span("hvd/init/backend",
+              fresh=int(not xla_bridge.backends_are_initialized())) as sp:
         st.topology = build_topology(axis_name=axis_name, devices=devices)
-        if multi_process and not cfg.one_proc_per_host:
-            mine = st.topology.ranks_of_process(st.topology.my_process)
-            if len(mine) == 1 and mine[0] != cfg.rank_env:
-                # On a TPU host the runtime numbers processes by where
-                # their chips sit, and the world mesh is in ICI order, so
-                # the launcher's HOROVOD_RANK (which only numbered the
-                # rendezvous) need not be this process's place in the
-                # mesh.  Eager collectives put a contribution at the
-                # device's mesh index, so that index IS the rank: adopt it
-                # before the controller and the engine are built.  Never
-                # fires on the CPU, where process i owns device i.
-                import dataclasses
-                local = (mine[0] if cfg.cross_size_env <= 1
-                         else cfg.local_rank_env)
-                cfg = st.config = dataclasses.replace(
-                    cfg, rank_env=mine[0], local_rank_env=local)
-        if st.topology.devices[0].platform != "cpu":
+        platform = st.topology.devices[0].platform
+        sp.set(platform=platform, devices=len(st.topology.devices))
+    if multi_process and not cfg.one_proc_per_host:
+        mine = st.topology.ranks_of_process(st.topology.my_process)
+        if len(mine) == 1 and mine[0] != cfg.rank_env:
+            # On a TPU host the runtime numbers processes by where
+            # their chips sit, and the world mesh is in ICI order, so
+            # the launcher's HOROVOD_RANK (which only numbered the
+            # rendezvous) need not be this process's place in the
+            # mesh.  Eager collectives put a contribution at the
+            # device's mesh index, so that index IS the rank: adopt it
+            # before the controller and the engine are built.  Never
+            # fires on the CPU, where process i owns device i.
+            import dataclasses
+            local = (mine[0] if cfg.cross_size_env <= 1
+                     else cfg.local_rank_env)
+            cfg = st.config = dataclasses.replace(
+                cfg, rank_env=mine[0], local_rank_env=local)
+    with span("hvd/init/cache") as sp:
+        from . import compile_cache
+        before = jax.config.jax_compilation_cache_dir
+        if platform != "cpu":
             # Accelerator compiles are long (a ResNet-50 step ~45 s): keep
             # them across processes and runs.  CPU runs (tests) stay as
             # they were unless the environment places a cache itself.
-            from . import compile_cache
             compile_cache.enable()
+        compile_cache.place_process_file(accelerator=platform != "cpu")
+        compile_cache.register_ledger()
+        now = jax.config.jax_compilation_cache_dir
+        sp.set(dir=now or "", reset=int(now != before))
+    _trace.startup_identity(
+        role="rank" if multi_process else "single", platform=platform,
+        rank=max(cfg.rank_env, 0), world=len(st.topology.devices))
+    whole.set(world=len(st.topology.devices), rank=max(cfg.rank_env, 0),
+              multi_process=int(multi_process))
+    # the runtime's own objects: process sets, timeline, the engine (its
+    # threads start last, below: two intervals, one name)
+    with span("hvd/init/engine"):
         gs = st.process_set_table.initialize(
             st.topology.devices, axis_name, extra_sets=process_sets)
         # Rebind the module-level global_process_set singleton.
@@ -176,125 +213,140 @@ def init(process_sets: Optional[Sequence[ProcessSet]] = None,
 
         from ..ops.engine import CollectiveEngine
         st.engine = CollectiveEngine(st)
-        if multi_process:
-            from .controller import TCPController
-            ctrl_port = (cfg.controller_port2 if cfg.controller_port2
-                         else cfg.controller_port + 1)
-            connect_addr, connect_port = cfg.controller_addr, ctrl_port
-            server_port = None
-            hier = cfg.hierarchical_controller
-            if hier and (cfg.local_rank_env < 0 or cfg.local_size_env <= 0
-                         or cfg.cross_rank_env < 0):
-                # Manual launches may set only RANK/SIZE/CONTROLLER_ADDR
-                # (enough for flat mode).  Deriving a host topology from
-                # the -1 defaults would give every process local_rank 0 on
-                # cross_rank 0 — each trying to bind its own agent on ONE
-                # derived port (EADDRINUSE out of init()).  Fall back to
-                # the flat plane loudly instead.
-                from ..utils.logging import get_logger
-                get_logger().warning(
-                    "HOROVOD_HIERARCHICAL_CONTROLLER=1 but HOROVOD_"
-                    "LOCAL_RANK/LOCAL_SIZE/CROSS_RANK are not set (launch "
-                    "through torovodrun to get them); using the flat "
-                    "control plane")
-                hier = False
-            if hier:
-                # Two-level control plane (protocol v5): ranks talk to a
-                # per-host agent that presents the whole host to the root
-                # as ONE connection (common/host_agent.py).  The
-                # local_rank-0 process owns its host's agent; rank 0 still
-                # hosts the root server at the launcher-advertised port
-                # while its own client goes through host 0's agent like
-                # everyone else's.  Elastic worlds compose (ISSUE 12): the
-                # agent object SURVIVES re-rendezvous generations — keyed
-                # on the host, listening on the stable per-host port the
-                # elastic driver allocated (HOROVOD_AGENT_PORT via the
-                # rendezvous assignment) — and each generation re-forms
-                # its uplink/local connections via new_generation.
-                from .host_agent import HostAgent
-                local_rank = cfg.local_rank_env
-                local_size = cfg.local_size_env
-                cross_rank = cfg.cross_rank_env
-                agent_port = (cfg.agent_port
-                              or ctrl_port + 1 + cross_rank)
-                if local_rank == 0:
-                    first = cfg.rank_env - local_rank
-                    ranks = list(range(first,
-                                       min(cfg.size_env,
-                                           first + local_size)))
-                    reused = False
-                    if (st.host_agent is not None and cfg.elastic
-                            and st.host_agent.port == agent_port):
-                        try:
-                            st.host_agent.new_generation(
-                                cfg.controller_addr, ctrl_port, ranks,
-                                host_index=cross_rank)
-                            reused = True
-                        except RuntimeError:
-                            # A wedged previous-generation thread: fall
-                            # back to a fresh agent on the same port
-                            # (stop() closes the listener first).
-                            from ..utils.logging import get_logger
-                            get_logger().warning(
-                                "host agent could not serve a new "
-                                "generation; replacing it")
-                    if not reused:
-                        if st.host_agent is not None:
-                            st.host_agent.stop()
-                        st.host_agent = HostAgent(
-                            agent_port, cfg.controller_addr, ctrl_port,
-                            ranks, host_index=cross_rank).start()
-                connect_addr, connect_port = "127.0.0.1", agent_port
-                if cfg.rank_env == 0:
-                    server_port = ctrl_port
-            # Zero-RTT streak carryover (ISSUE 12): a surviving elastic
-            # worker seeds the new generation from the hint captured at
-            # the previous shutdown — 0 on the first generation and in
-            # non-elastic worlds.
-            spec_carry = _elastic_carry["spec_seed"] if cfg.elastic else 0
-            st.controller = TCPController(
-                connect_addr, connect_port,
-                rank=cfg.rank_env, world=cfg.size_env,
-                stall_warn_s=cfg.stall_check_time_s
-                if not cfg.stall_check_disable else 1e18,
-                cache_capacity=cfg.response_cache_capacity,
-                round_timeout_s=cfg.round_timeout_s,
-                connect_retries=cfg.connect_retries,
-                connect_backoff_ms=cfg.connect_backoff_ms,
-                server_port=server_port,
-                spec_ready_after=cfg.spec_ready_after,
-                round_pipeline=cfg.round_pipeline,
-                spec_seed=spec_carry,
-                spec_streak_hint=spec_carry)
-            st.engine.controller = st.controller
+    if multi_process:
+        from . import native
+        native.load()       # ``hvd/init/native``, before its first user
+        with span("hvd/init/controller") as sp:
+            hier = _connect_controller(st, cfg)
+            sp.set(attempts=st.controller.connect_attempts, hier=int(hier))
 
-        if cfg.monitor:
-            # Cross-rank telemetry & health subsystem (docs/monitoring.md):
-            # per-rank registry + coordinator side-channel aggregation; the
-            # HTTP exporter serves /metrics + /health on rank 0 when a
-            # port is configured.  Installed before engine.start() so the
-            # very first cycle is observed.
-            from ..monitor.agent import MonitorAgent
-            mon_rank = cfg.rank_env if cfg.rank_env >= 0 else 0
-            mon_world = cfg.size_env if (multi_process
-                                         and cfg.size_env > 0) else 1
-            st.monitor = MonitorAgent(
-                engine=st.engine, controller=st.controller,
-                rank=mon_rank, world=mon_world,
-                interval_s=cfg.monitor_interval_s, timeline=st.timeline)
-            if cfg.monitor_port > 0 and mon_rank == 0:
-                try:
-                    st.monitor.serve_http(cfg.monitor_port)
-                except OSError as exc:
-                    # A taken port must not kill training — the telemetry
-                    # plane is strictly best-effort.
-                    from ..utils.logging import get_logger
-                    get_logger().warning(
-                        "monitor: could not bind HTTP port %d (%s); "
-                        "exporter disabled", cfg.monitor_port, exc)
+    if cfg.monitor:
+        with span("hvd/init/monitor"):
+            _install_monitor(st, cfg, multi_process)
+    with span("hvd/init/engine"):
         st.engine.start()
 
-        st.initialized = True
+
+def _connect_controller(st, cfg) -> bool:
+    """The negotiation controller of a launched process (and its host's
+    agent where the control plane is two-level); returns whether it is."""
+    from .controller import TCPController
+    ctrl_port = (cfg.controller_port2 if cfg.controller_port2
+                 else cfg.controller_port + 1)
+    connect_addr, connect_port = cfg.controller_addr, ctrl_port
+    server_port = None
+    hier = cfg.hierarchical_controller
+    if hier and (cfg.local_rank_env < 0 or cfg.local_size_env <= 0
+                 or cfg.cross_rank_env < 0):
+        # Manual launches may set only RANK/SIZE/CONTROLLER_ADDR
+        # (enough for flat mode).  Deriving a host topology from
+        # the -1 defaults would give every process local_rank 0 on
+        # cross_rank 0 — each trying to bind its own agent on ONE
+        # derived port (EADDRINUSE out of init()).  Fall back to
+        # the flat plane loudly instead.
+        from ..utils.logging import get_logger
+        get_logger().warning(
+            "HOROVOD_HIERARCHICAL_CONTROLLER=1 but HOROVOD_"
+            "LOCAL_RANK/LOCAL_SIZE/CROSS_RANK are not set (launch "
+            "through torovodrun to get them); using the flat "
+            "control plane")
+        hier = False
+    if hier:
+        # Two-level control plane (protocol v5): ranks talk to a
+        # per-host agent that presents the whole host to the root
+        # as ONE connection (common/host_agent.py).  The
+        # local_rank-0 process owns its host's agent; rank 0 still
+        # hosts the root server at the launcher-advertised port
+        # while its own client goes through host 0's agent like
+        # everyone else's.  Elastic worlds compose (ISSUE 12): the
+        # agent object SURVIVES re-rendezvous generations — keyed
+        # on the host, listening on the stable per-host port the
+        # elastic driver allocated (HOROVOD_AGENT_PORT via the
+        # rendezvous assignment) — and each generation re-forms
+        # its uplink/local connections via new_generation.
+        from .host_agent import HostAgent
+        local_rank = cfg.local_rank_env
+        local_size = cfg.local_size_env
+        cross_rank = cfg.cross_rank_env
+        agent_port = (cfg.agent_port
+                      or ctrl_port + 1 + cross_rank)
+        if local_rank == 0:
+            first = cfg.rank_env - local_rank
+            ranks = list(range(first,
+                               min(cfg.size_env,
+                                   first + local_size)))
+            reused = False
+            if (st.host_agent is not None and cfg.elastic
+                    and st.host_agent.port == agent_port):
+                try:
+                    st.host_agent.new_generation(
+                        cfg.controller_addr, ctrl_port, ranks,
+                        host_index=cross_rank)
+                    reused = True
+                except RuntimeError:
+                    # A wedged previous-generation thread: fall
+                    # back to a fresh agent on the same port
+                    # (stop() closes the listener first).
+                    from ..utils.logging import get_logger
+                    get_logger().warning(
+                        "host agent could not serve a new "
+                        "generation; replacing it")
+            if not reused:
+                if st.host_agent is not None:
+                    st.host_agent.stop()
+                st.host_agent = HostAgent(
+                    agent_port, cfg.controller_addr, ctrl_port,
+                    ranks, host_index=cross_rank).start()
+        connect_addr, connect_port = "127.0.0.1", agent_port
+        if cfg.rank_env == 0:
+            server_port = ctrl_port
+    # Zero-RTT streak carryover (ISSUE 12): a surviving elastic
+    # worker seeds the new generation from the hint captured at
+    # the previous shutdown — 0 on the first generation and in
+    # non-elastic worlds.
+    spec_carry = _elastic_carry["spec_seed"] if cfg.elastic else 0
+    st.controller = TCPController(
+        connect_addr, connect_port,
+        rank=cfg.rank_env, world=cfg.size_env,
+        stall_warn_s=cfg.stall_check_time_s
+        if not cfg.stall_check_disable else 1e18,
+        cache_capacity=cfg.response_cache_capacity,
+        round_timeout_s=cfg.round_timeout_s,
+        connect_retries=cfg.connect_retries,
+        connect_backoff_ms=cfg.connect_backoff_ms,
+        server_port=server_port,
+        spec_ready_after=cfg.spec_ready_after,
+        round_pipeline=cfg.round_pipeline,
+        spec_seed=spec_carry,
+        spec_streak_hint=spec_carry)
+    st.engine.controller = st.controller
+    return hier
+
+
+def _install_monitor(st, cfg, multi_process) -> None:
+    # Cross-rank telemetry & health subsystem (docs/monitoring.md):
+    # per-rank registry + coordinator side-channel aggregation; the
+    # HTTP exporter serves /metrics + /health on rank 0 when a
+    # port is configured.  Installed before engine.start() so the
+    # very first cycle is observed.
+    from ..monitor.agent import MonitorAgent
+    mon_rank = cfg.rank_env if cfg.rank_env >= 0 else 0
+    mon_world = cfg.size_env if (multi_process
+                                 and cfg.size_env > 0) else 1
+    st.monitor = MonitorAgent(
+        engine=st.engine, controller=st.controller,
+        rank=mon_rank, world=mon_world,
+        interval_s=cfg.monitor_interval_s, timeline=st.timeline)
+    if cfg.monitor_port > 0 and mon_rank == 0:
+        try:
+            st.monitor.serve_http(cfg.monitor_port)
+        except OSError as exc:
+            # A taken port must not kill training — the telemetry
+            # plane is strictly best-effort.
+            from ..utils.logging import get_logger
+            get_logger().warning(
+                "monitor: could not bind HTTP port %d (%s); "
+                "exporter disabled", cfg.monitor_port, exc)
 
 
 def shutdown() -> None:
@@ -302,6 +354,11 @@ def shutdown() -> None:
     with st._lock:
         if not st.initialized:
             return
+        # The start-up record stands as it is now, and its line goes
+        # beside the compile cache (once a process; nowhere on a CPU run
+        # by itself).
+        _trace.startup_freeze(True)
+        _trace.write_startup()
         # A control-plane fault (dead peer — HVD303) means the jax world's
         # cooperative teardown can never complete: take the abrupt path
         # below.  Captured before the engine is torn down.
@@ -401,6 +458,8 @@ def shutdown() -> None:
 
 
 atexit.register(shutdown)
+# a process that never initialised (the launcher) or never shut down
+atexit.register(_trace.write_startup)
 
 
 def is_initialized() -> bool:

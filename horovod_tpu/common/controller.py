@@ -249,7 +249,10 @@ class TCPController:
         per_ms = (connect_timeout_ms if retries == 0
                   else max(1000, int(connect_timeout_ms / (retries + 1))))
 
+        self.connect_attempts = 0       # the start-up record's ``attempts``
+
         def _connect():
+            self.connect_attempts += 1
             handle = self._lib.hvdtpu_client_connect(
                 addr.encode(), port, rank, per_ms)
             if not handle:

@@ -16,6 +16,8 @@ import subprocess
 import threading
 from typing import Optional
 
+from ..trace.core import startup_span
+
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
@@ -90,8 +92,12 @@ def load() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        path = _build()
-        lib = ctypes.CDLL(path)
+        # the start-up record's ``hvd/init/native``: ``built`` 1 where g++
+        # ran, 0 where the artifact of this source was there
+        with startup_span("hvd/init/native") as sp:
+            sp.set(built=int(not os.path.exists(_out_path())))
+            path = _build()
+            lib = ctypes.CDLL(path)
         lib.hvdtpu_server_start.restype = ctypes.c_void_p
         lib.hvdtpu_server_start.argtypes = [ctypes.c_int, ctypes.c_int,
                                             ctypes.c_double, ctypes.c_int,

@@ -1139,11 +1139,16 @@ def broadcast_parameters(params, root_rank: int = 0,
     is the identity.  In multi-process mode each process holds its own copy
     and the byte-level broadcast runs through the coordinator.
     """
-    if jax.process_count() == 1:
-        return params
-    out = eager.broadcast_pytree(params, root_rank=root_rank,
-                                 process_set=process_set)
-    return jax.tree_util.tree_map(jnp.asarray, out)
+    leaves = jax.tree_util.tree_leaves(params)
+    # the start-up record's span (trace/core.py): every call, armed or not
+    with trace.startup_span(
+            "hvd/broadcast_parameters", n=len(leaves), root=root_rank,
+            bytes=sum(getattr(x, "nbytes", 0) for x in leaves)):
+        if jax.process_count() == 1:
+            return params
+        out = eager.broadcast_pytree(params, root_rank=root_rank,
+                                     process_set=process_set)
+        return jax.tree_util.tree_map(jnp.asarray, out)
 
 
 def broadcast_optimizer_state(opt_state, root_rank: int = 0,

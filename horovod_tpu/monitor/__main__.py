@@ -7,10 +7,15 @@ Usage::
     python -m horovod_tpu.monitor snapshot.json             # dumped file
     python -m horovod_tpu.monitor --url ... --json          # raw JSON
     python -m horovod_tpu.monitor --url ... --watch 2       # refresh loop
+    python -m horovod_tpu.monitor --startup [dir]           # the newest launch
 
 The live mode reads the rank-0 HTTP exporter started by
 ``HOROVOD_MONITOR_PORT`` (``/snapshot``); the file mode reads a JSON dump
 of the same shape (e.g. ``curl :9090/snapshot > snap.json``).
+``--startup`` reads the start-up records the processes of a launch left
+beside the compile cache (``_hvd_processes.jsonl``; ``docs/monitoring.md``
+"Start-up"): a row a process and a column a phase, then the programs that
+took longest to compile and whether each rank asked the cache and hit.
 """
 
 from __future__ import annotations
@@ -135,6 +140,112 @@ def render(dump: dict) -> str:
     return "\n".join(lines)
 
 
+def newest_launch(records: List[dict]) -> List[dict]:
+    """The newest launch among the file's records: its launcher's line
+    (written last, when its workers had gone) and the lines of the
+    processes it started, by rank; one process alone where there was no
+    launcher."""
+    if not records:
+        return []
+    last = records[-1]
+    head = last if last.get("role") == "launcher" else next(
+        (r for r in reversed(records) if r.get("role") == "launcher"
+         and r.get("pid") == last.get("ppid")), None)
+    def started(r):
+        return r.get("process_started_at") or 0.0
+
+    # the launcher's children; without a launcher, the processes that
+    # last's parent started within ten minutes of it (ssh, a scheduler)
+    parent, since = ((head["pid"], started(head)) if head
+                     else (last.get("ppid"), started(last) - 600))
+    rows = [r for r in records if r.get("role") != "launcher"
+            and r.get("ppid") == parent and started(r) >= since]
+    rows.sort(key=lambda r: r.get("rank", 0))
+    return ([head] if head else []) + rows
+
+
+def render_startup(records: List[dict], source: str = "") -> str:
+    """The newest launch as a table: seconds a process and phase, when
+    each phase ended on the launch's one clock, and the ten programs with
+    the most backend-compile seconds."""
+    rows = newest_launch(records)
+    if not rows:
+        return f"no start-up record in {source or 'the file'}"
+    began = min(r.get("process_started_at") or r["spans"][0]["t0"]
+                for r in rows if r.get("spans"))
+    phases: List[str] = []
+    for r in rows:
+        for s in r["spans"]:
+            if s["name"] not in phases:
+                phases.append(s["name"])
+    short = [p[len("hvd/"):] if p.startswith("hvd/") else p for p in phases]
+
+    def label(r):
+        return ("launcher" if r.get("role") == "launcher"
+                else f"{r.get('role', 'rank')} {r.get('rank', 0)}")
+
+    def table(title, cell):
+        out = ["", title, f"{'process':<10} {'pid':>7}  " + "  ".join(
+            f"{n:>8}" for n in short)]
+        for r in rows:
+            out.append(f"{label(r):<10} {r.get('pid', 0):>7}  " + "  ".join(
+                f"{_fmt(cell(r, p)):>{max(8, len(n))}}"
+                for p, n in zip(phases, short)))
+        return out
+
+    def seconds(r, phase):
+        found = [s["seconds"] for s in r["spans"] if s["name"] == phase]
+        return round(sum(found), 3) if found else None
+
+    def ended(r, phase):
+        found = [s["t0"] + s["seconds"] for s in r["spans"]
+                 if s["name"] == phase]
+        return round(max(found) - began, 3) if found else None
+
+    lines = [f"start-up of {len(rows)} process(es) on "
+             f"{rows[0].get('host', '?')} ({rows[-1].get('platform') or '-'})"
+             f", begun {time.strftime('%Y-%m-%d %H:%M:%S', time.gmtime(began))}"
+             f" UTC" + (f"   [{source}]" if source else "")]
+    lines += table("seconds by phase (hvd/...):", seconds)
+    lines += table("phase ended, seconds after the first process began "
+                   "(one clock):", ended)
+    past = [f"{label(r)}: {r['spans_dropped']}" for r in rows
+            if r.get("spans_dropped")]
+    if past:
+        lines += ["", "intervals past a name's bound, counted and not "
+                  "kept (a phase's seconds above are its first calls'): "
+                  + ", ".join(past)]
+    # the compile ledgers: stages a process, then the slowest programs
+    ledgers = [(label(r), r["ledger"]) for r in rows if r.get("ledger")]
+    if ledgers:
+        keys = ("count", "trace_s", "lower_s", "backend_s", "retrieval_s",
+                "asked_cache", "hits", "cache_writes")
+        lines += ["", "compiles a process (backend_s leaves a hit's "
+                  "retrieval out; cache_writes is jax's cache_misses "
+                  "event):",
+                  f"{'process':<10}  " + "  ".join(f"{k:>11}" for k in keys)]
+        for name, led in ledgers:
+            lines.append(f"{name:<10}  " + "  ".join(
+                f"{_fmt(round(led['totals'].get(k, 0), 3)):>11}"
+                for k in keys))
+        worst: dict = {}
+        for _, led in ledgers:
+            for prog, e in led["programs"].items():
+                worst[prog] = max(worst.get(prog, 0.0), e["backend_s"])
+        lines += ["", "programs by backend_s (the slowest process's), and "
+                  "count / asked_cache / hits a process:"]
+        for prog in sorted(worst, key=worst.get, reverse=True)[:10]:
+            cells = []
+            for name, led in ledgers:
+                e = led["programs"].get(prog)
+                cells.append(f"{name}: " + (
+                    f"{e['count']}/{e['asked_cache']}/{e['hits']}"
+                    if e else "-"))
+            lines.append(f"  {prog[:36]:<36} {worst[prog]:>9.3f} s   "
+                         + "   ".join(cells))
+    return "\n".join(lines)
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m horovod_tpu.monitor",
@@ -147,7 +258,19 @@ def main(argv: Optional[List[str]] = None) -> int:
                    help="print the raw JSON instead of the table")
     p.add_argument("--watch", type=float, default=0.0, metavar="SECONDS",
                    help="refresh the live view every N seconds")
+    p.add_argument("--startup", nargs="?", const="", metavar="DIR",
+                   help="the newest launch's start-up records from "
+                        "DIR/_hvd_processes.jsonl (default: the compile "
+                        "cache's directory)")
     args = p.parse_args(argv)
+    if args.startup is not None:
+        from ..common import compile_cache
+        from ..trace import core
+        where = args.startup or compile_cache.cache_dir()
+        records = core.read_process_lines(where)
+        print(json.dumps(newest_launch(records), indent=2) if args.json
+              else render_startup(records, where))
+        return 0 if records else 1
     if bool(args.file) == bool(args.url):
         p.error("pass exactly one of: a snapshot file, or --url")
     if args.watch and not args.url:

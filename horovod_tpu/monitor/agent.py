@@ -35,8 +35,7 @@ from typing import Dict, List, Optional
 from .aggregator import RankAggregator
 from .registry import MetricRegistry
 from ..trace.core import PHASES as _TRACE_PHASES
-from ..trace.core import inner_update as _INNER_UPDATE
-from ..trace.core import stage_group as _STAGE_GROUP
+from ..trace.core import SERIES as _SERIES
 from ..utils.logging import get_logger
 
 log = get_logger()
@@ -145,27 +144,18 @@ class MonitorAgent:
             reg.counter("hvd_pipeline_dispatches_total",
                         "fused batches dispatched").set_total(
                 getattr(engine, "pipeline_dispatches", 0))
-            # The wrapped optimizer's eager update (jax/optimizer.py):
-            # calls through a wrapper's compiled callable, and traces of
-            # it — traces rising with calls is a retrace every step.
-            reg.counter("hvd_inner_update_compiled_total",
-                        "eager inner updates run as one compiled program"
-                        ).set_total(_INNER_UPDATE["compiled"])
-            reg.counter("hvd_inner_update_traces_total",
-                        "traces of the compiled inner update").set_total(
-                _INNER_UPDATE["traces"])
-            # A group's staging (ops/eager.py): members that went through
-            # the one program over their group, traces of it, and the
-            # members it packed into one flat buffer a dtype.
-            reg.counter("hvd_stage_group_compiled_total",
-                        "group members staged by one compiled program"
-                        ).set_total(_STAGE_GROUP["compiled"])
-            reg.counter("hvd_stage_group_traces_total",
-                        "traces of the staging program").set_total(
-                _STAGE_GROUP["traces"])
-            reg.counter("hvd_stage_group_packed_total",
-                        "group members staged inside one flat buffer a "
-                        "dtype").set_total(_STAGE_GROUP["packed"])
+            # The process-wide series (trace/core.py ``SERIES``): the
+            # compiled inner update, a group's staging, the start-up
+            # phases, the compile ledger — whatever registered itself.
+            for name, (kind, text, read, label) in list(_SERIES.items()):
+                make = reg.counter if kind == "counter" else reg.gauge
+                values = read()
+                for key, value in (values.items() if label
+                                   else ((None, values),)):
+                    metric = make(name, text,
+                                  labels={label: key} if label else None)
+                    (metric.set_total if kind == "counter"
+                     else metric.set)(value)
             # FSDP prefetch lane (ISSUE 18): dispatches count allgather
             # batches routed through the PREFETCH lane; overlapped counts
             # the ones issued while an earlier bucket was still unsettled

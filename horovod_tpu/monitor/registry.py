@@ -47,14 +47,22 @@ def _sanitize(name: str) -> str:
     return s or "_"
 
 
+def _label_body(labels: Dict[str, str]) -> str:
+    """``phase="hvd/init",rank="0"``: a series' labels, rendered here and
+    nowhere else."""
+    return ",".join(f'{k}="{v}"' for k, v in labels.items())
+
+
 class Counter:
     """Monotonically increasing value."""
 
     kind = "counter"
 
-    def __init__(self, name: str, help: str = ""):
+    def __init__(self, name: str, help: str = "",
+                 labels: Optional[Dict[str, str]] = None):
         self.name = name
         self.help = help
+        self.labels = dict(labels or {})
         self._lock = threading.Lock()
         self._value: float = 0
 
@@ -85,9 +93,11 @@ class Gauge:
 
     kind = "gauge"
 
-    def __init__(self, name: str, help: str = ""):
+    def __init__(self, name: str, help: str = "",
+                 labels: Optional[Dict[str, str]] = None):
         self.name = name
         self.help = help
+        self.labels = dict(labels or {})
         self._lock = threading.Lock()
         self._value: Number = 0
 
@@ -213,20 +223,28 @@ class MetricRegistry:
 
     # -------------------------------------------------------- registration
     def _get_or_create(self, cls, name: str, help: str, **kw):
+        # a labelled series is a metric of its own under its name: the
+        # table (and the snapshot) keys it by both
+        labels = kw.get("labels")
+        key = f"{name}{{{_label_body(labels)}}}" if labels else name
         with self._lock:
-            m = self._metrics.get(name)
+            m = self._metrics.get(key)
             if m is None:
-                m = self._metrics[name] = cls(name, help, **kw)
+                m = self._metrics[key] = cls(name, help, **kw)
             elif not isinstance(m, cls):
                 raise TypeError(
-                    f"metric {name!r} already registered as {m.kind}")
+                    f"metric {key!r} already registered as {m.kind}")
             return m
 
-    def counter(self, name: str, help: str = "") -> Counter:
-        return self._get_or_create(Counter, name, help)
+    def counter(self, name: str, help: str = "",
+                labels: Optional[Dict[str, str]] = None) -> Counter:
+        """``labels`` (``{"stage": "lower"}``) makes one series of several
+        under ``name``, described once in the rendering."""
+        return self._get_or_create(Counter, name, help, labels=labels)
 
-    def gauge(self, name: str, help: str = "") -> Gauge:
-        return self._get_or_create(Gauge, name, help)
+    def gauge(self, name: str, help: str = "",
+              labels: Optional[Dict[str, str]] = None) -> Gauge:
+        return self._get_or_create(Gauge, name, help, labels=labels)
 
     def histogram(self, name: str, help: str = "",
                   buckets: Sequence[float] = DEFAULT_BUCKETS) -> Histogram:
@@ -259,8 +277,8 @@ class MetricRegistry:
         the payload the controller side-channel ships to rank 0."""
         self._run_collectors()
         with self._lock:
-            metrics = list(self._metrics.values())
-        return {m.name: m.snapshot_value() for m in metrics}
+            metrics = list(self._metrics.items())
+        return {key: m.snapshot_value() for key, m in metrics}
 
     def to_prometheus(self, extra_label: str = "") -> str:
         """Prometheus text exposition format (served at ``/metrics``).
@@ -269,24 +287,30 @@ class MetricRegistry:
         ``rank="0"``) applied to every series."""
         self._run_collectors()
         with self._lock:
-            metrics = sorted(self._metrics.values(), key=lambda m: m.name)
+            metrics = [m for _, m in sorted(self._metrics.items())]
         lines: List[str] = []
-        lab = "{" + extra_label + "}" if extra_label else ""
+        described = set()
         for m in metrics:
+            # the series of one name (the start-up phases, the compile
+            # stages) are described once
             name = _sanitize(m.name)
-            if m.help:
-                lines.append(f"# HELP {name} {m.help}")
-            lines.append(f"# TYPE {name} {m.kind}")
+            labels = ",".join(
+                part for part in (_label_body(getattr(m, "labels", {})),
+                                  extra_label) if part)
+            lab = "{" + labels + "}" if labels else ""
+            if name not in described:
+                described.add(name)
+                if m.help:
+                    lines.append(f"# HELP {name} {m.help}")
+                lines.append(f"# TYPE {name} {m.kind}")
             if isinstance(m, Histogram):
                 snap = m.snapshot_value()
                 for le, c in snap["buckets"].items():
                     le_lab = f'le="{le:g}"'
-                    body = (extra_label + "," + le_lab) if extra_label \
-                        else le_lab
+                    body = (labels + "," + le_lab) if labels else le_lab
                     lines.append(f"{name}_bucket{{{body}}} {c}")
                 inf_lab = 'le="+Inf"'
-                body = (extra_label + "," + inf_lab) if extra_label \
-                    else inf_lab
+                body = (labels + "," + inf_lab) if labels else inf_lab
                 lines.append(f"{name}_bucket{{{body}}} {snap['count']}")
                 lines.append(f"{name}_sum{lab} {snap['sum']:g}")
                 lines.append(f"{name}_count{lab} {snap['count']}")

@@ -23,6 +23,7 @@ import shlex
 import socket
 import subprocess
 import sys
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..utils.timeline import per_rank_filename
@@ -524,7 +525,6 @@ def wait_and_reap(procs: List[subprocess.Popen],
     fire (the reference launcher's safe_shell_exec kills the process
     group the same way).
     """
-    import time
     rc = 0
     live = list(procs)
     try:
@@ -641,9 +641,10 @@ def ssh_command(host: str, env: Dict[str, str], command: List[str],
     return cmd
 
 
-def launch_workers(args, hosts: List[HostSpec],
-                   addrs: Optional[Dict[str, str]] = None) -> int:
-    """Spawn all workers, wait, propagate first failure (local + ssh).
+def plan_workers(args, hosts: List[HostSpec],
+                 addrs: Optional[Dict[str, str]] = None,
+                 tpu_chips: Optional[int] = None) -> List[Dict[str, str]]:
+    """The ports of the launch and every worker's environment.
 
     ``addrs`` (from the bootstrap probe phase) overrides the coordinator
     address with host 0's resolved control-plane address — this is what
@@ -669,7 +670,13 @@ def launch_workers(args, hosts: List[HostSpec],
         coord_host = (hosts[0].hostname if hosts[0].hostname != "localhost"
                       else "127.0.0.1")
     coord = (coord_host, ports[0], ports[1])
-    envs = worker_envs(args, hosts, coord, agent_ports=agent_ports)
+    return worker_envs(args, hosts, coord, agent_ports=agent_ports,
+                       tpu_chips=tpu_chips)
+
+
+def spawn_workers(args, envs: List[Dict[str, str]]
+                  ) -> List[subprocess.Popen]:
+    """One process a worker environment (local, or over ssh)."""
     procs: List[subprocess.Popen] = []
     for rank, env in enumerate(envs):
         host = env["HOROVOD_HOSTNAME"]
@@ -689,10 +696,11 @@ def launch_workers(args, hosts: List[HostSpec],
             proc = subprocess.Popen(cmd, env=os.environ.copy(),
                                     stdout=stdout, stderr=stderr)
         procs.append(proc)
-    return wait_and_reap(procs)
+    return procs
 
 
 def main(argv: Sequence[str]) -> int:
+    entered = time.time()
     args = parse_args(argv)
     if args.gke_jobset:
         from .tpu_vm import render_gke_jobset
@@ -705,25 +713,46 @@ def main(argv: Sequence[str]) -> int:
             or getattr(args, "tpu_metadata_discovery", False)):
         from ..elastic.driver import run_elastic
         return run_elastic(args)
-    hosts = placement(args)
-    if args.verbose:
-        print(f"[torovodrun] launching np={args.np} over "
-              f"{[(h.hostname, h.slots) for h in hosts]}", file=sys.stderr)
-    # Pre-launch bootstrap (reference P8): probe NICs + mutual connectivity
-    # whenever a host is remote or an explicit interface was requested —
-    # refuse fast with the exact broken pair instead of spawning workers
-    # that would hang in rendezvous.
-    addrs = None
-    from ..common.net import is_local_host
-    if args.nics or any(not is_local_host(h.hostname) for h in hosts):
-        from .bootstrap import bootstrap_hosts
-        try:
-            addrs = bootstrap_hosts(
-                hosts, nic=args.nics, ssh_port=args.ssh_port,
-                identity_file=args.ssh_identity_file,
-                timeout_s=min(args.start_timeout, 120),
-                verbose=args.verbose)
-        except RuntimeError as exc:
-            print(f"[torovodrun] {exc}", file=sys.stderr)
-            return 1
-    return launch_workers(args, hosts, addrs)
+    # The launcher's part of the start-up record (trace/core.py): from
+    # here to the last worker spawned, on the clock its workers share.
+    from ..common import compile_cache
+    from ..trace import core as trace
+    with trace.StartupSpan("hvd/launch", {"np": args.np}, entered) as whole:
+        with trace.StartupSpan("hvd/launch/placement", {}, entered) as sp:
+            hosts = placement(args)
+            whole.set(hosts=len(hosts))
+            if args.verbose:
+                print(f"[torovodrun] launching np={args.np} over "
+                      f"{[(h.hostname, h.slots) for h in hosts]}",
+                      file=sys.stderr)
+            # Pre-launch bootstrap (reference P8): probe NICs + mutual
+            # connectivity whenever a host is remote or an explicit
+            # interface was requested — refuse fast with the exact broken
+            # pair instead of spawning workers that would hang in
+            # rendezvous.
+            addrs = None
+            from ..common.net import is_local_host
+            if args.nics or any(not is_local_host(h.hostname)
+                                for h in hosts):
+                from .bootstrap import bootstrap_hosts
+                try:
+                    addrs = bootstrap_hosts(
+                        hosts, nic=args.nics, ssh_port=args.ssh_port,
+                        identity_file=args.ssh_identity_file,
+                        timeout_s=min(args.start_timeout, 120),
+                        verbose=args.verbose)
+                except RuntimeError as exc:
+                    print(f"[torovodrun] {exc}", file=sys.stderr)
+                    return 1
+            chips = local_tpu_chips()
+            sp.set(chips=chips)
+            envs = plan_workers(args, hosts, addrs, tpu_chips=chips)
+        with trace.startup_span("hvd/launch/spawn", n=len(envs)):
+            procs = spawn_workers(args, envs)
+    trace.startup_identity(role="launcher", world=len(envs))
+    compile_cache.place_process_file(
+        accelerator=bool(chips) and not os.environ.get(
+            "JAX_PLATFORMS", "").startswith("cpu"))
+    rc = wait_and_reap(procs)
+    trace.write_startup()       # its line, once the last worker has gone
+    return rc

@@ -6,7 +6,9 @@ negotiation cycle id, and merges the fleet into one perfetto view:
 
 - :mod:`.core`    — span ring + per-phase accumulators (the engine stamps),
   and the program spans (:func:`span`: one interval of one thread between
-  two of the program's layer boundaries, a TraceMe on the profiler's clock);
+  two of the program's layer boundaries, a TraceMe on the profiler's clock),
+  and the start-up record (:func:`startup`: the phases of a process's
+  start and its compile ledger, kept whether tracing is armed or not);
 - :mod:`.writer`  — per-rank JSONL trace files (``HOROVOD_TRACE``);
 - :mod:`.merge`   — cross-rank merge into a chrome/perfetto trace with
   per-rank lanes and cycle flow arrows (``python -m horovod_tpu.trace``);
@@ -22,14 +24,16 @@ import sys
 from . import core
 from .core import (DIGEST_MAX_CYCLES, DIGEST_MAX_OPEN, OFF, PHASE_BUCKETS_US,
                    PHASES, REDUCE_LEGS, CycleRecord, ProgramSpan, TensorSpan,
-                   TraceRecorder, inner_update, installed, span, stage_group)
+                   TraceRecorder, inner_update, installed, span, stage_group,
+                   startup, startup_span, write_startup)
 from .writer import TraceWriter
 
 __all__ = [
     "PHASES", "REDUCE_LEGS", "PHASE_BUCKETS_US", "DIGEST_MAX_CYCLES",
     "DIGEST_MAX_OPEN", "CycleRecord", "TensorSpan", "TraceRecorder",
     "TraceWriter", "maybe_install", "span", "installed", "OFF",
-    "ProgramSpan", "inner_update", "stage_group",
+    "ProgramSpan", "inner_update", "stage_group", "startup",
+    "startup_span", "write_startup",
 ]
 
 

@@ -42,13 +42,26 @@ Compact per-cycle digests (:meth:`TraceRecorder.digest`) ride the existing
 MON1 monitor side-channel inside the agent's JSON snapshot — interval-gated,
 size-capped (``DIGEST_*`` caps below), and version-safe (old peers ignore
 unknown snapshot keys).
+
+**The start-up record** (:func:`startup_span`, :func:`startup`) is the third
+kind, kept whether or not ``HOROVOD_TRACE`` is set: a dozen named intervals
+of one process between its start and the end of ``hvd.init()`` (the
+launcher's own three too), on ``time.time()`` — the clock a launcher and its
+ranks share on one host and jax's compile events carry — so set-up can be
+read from inside the program.  It costs a stamp a site at start-up and
+nothing on a step's path.  :func:`startup` returns it with the compile
+ledger (``common/compile_cache.py``); one JSON line a process goes beside
+the compile cache (:func:`write_startup`).
 """
 
 from __future__ import annotations
 
+import functools
+import os
+import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 # Phase names, in lifecycle order.  The wire/digest/JSON key order
 # everywhere else follows this tuple.
@@ -274,6 +287,37 @@ inner_update = {"compiled": 0, "traces": 0}
 # flat buffer a dtype (``_pack_leaves``: the eager gradient path) and not
 # as a member each (``_stack_leaves``).
 stage_group = {"compiled": 0, "traces": 0, "packed": 0}
+
+# The one table of the process-wide series: name -> (kind, help, read,
+# label).  ``read()`` gives a number, or with a ``label`` a dict from the
+# label's value to a number (``hvd_startup_seconds{phase="hvd/init"}``).
+# ``monitor/agent.py`` walks it at every snapshot; a module that keeps a
+# process-wide count registers it here and the exporter needs no edit.
+SERIES: Dict[str, tuple] = {}
+
+
+def register_series(name: str, kind: str, help: str, read: Callable,
+                    label: Optional[str] = None) -> None:
+    """``kind`` is ``counter`` or ``gauge``."""
+    SERIES[name] = (kind, help, read, label)
+
+
+def _register_counts(prefix: str, counts: dict, helps: dict) -> None:
+    for key, text in helps.items():
+        register_series(f"{prefix}_{key}_total", "counter", text,
+                        lambda key=key: counts[key])
+
+
+# traces rising with calls is a retrace every step
+_register_counts("hvd_inner_update", inner_update, {
+    "compiled": "eager inner updates run as one compiled program",
+    "traces": "traces of the compiled inner update"})
+# members that went through the one program over their group, traces of
+# it, and the members it packed into one flat buffer a dtype
+_register_counts("hvd_stage_group", stage_group, {
+    "compiled": "group members staged by one compiled program",
+    "traces": "traces of the staging program",
+    "packed": "group members staged inside one flat buffer a dtype"})
 
 
 def span(name: str, **ids):
@@ -556,3 +600,249 @@ class TraceRecorder:
         w, self._writer = self._writer, None
         if w is not None:
             w.close()
+
+
+# ---------------------------------------------------------------- start-up
+# One record a process, kept with tracing armed or not (module docstring).
+# The file its line goes to, inside the compile cache's directory: no cache
+# entry and no name a cache key can take (those end in ``-cache`` or
+# ``-atime``).  Cut to its newest lines when it outgrows its bound.
+PROCESS_FILE = "_hvd_processes.jsonl"
+PROCESS_FILE_MAX_BYTES = 1 << 20
+PROCESS_FILE_KEEP_LINES = 256
+# The record is of set-up, and bounded a name: ``hvd/broadcast_parameters``
+# is stamped at every call for the life of the process (a serving replica's
+# weight pushes), so a name keeps its first intervals and what came after
+# is counted.  No name can crowd out another: a re-``init()`` after the
+# thousandth broadcast still finds room for its ``hvd/init/*``.
+STARTUP_MAX_PER_NAME = 16
+
+
+@functools.lru_cache(maxsize=None)
+def process_started_at() -> Optional[float]:
+    """When the OS started this process, on ``time.time()``'s clock: its
+    start in clock ticks since boot (``/proc/self/stat``, field 22)
+    against the seconds since boot now, read once.  None where ``/proc``
+    has neither."""
+    try:
+        with open("/proc/self/stat") as fh:
+            # the fields after the command's closing parenthesis
+            fields = fh.read().rsplit(")", 1)[1].split()
+        with open("/proc/uptime") as fh:
+            up, now = float(fh.read().split()[0]), time.time()
+        age = up - int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        return now - max(0.0, age)
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class _Startup:
+    """This process's start-up record."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.spans: List[dict] = []
+        self.held: Dict[str, int] = {}      # intervals kept, by name
+        self.dropped = 0
+        self.identity = {"role": "single", "rank": 0, "world": 1,
+                         "platform": ""}
+        # what the compile ledger registers: () -> its totals and table
+        self.ledger: Optional[Callable[[], dict]] = None
+        # where the process's line goes: set where the compile cache was
+        # placed (``common/compile_cache.py``), None on a CPU run by itself
+        self.directory: Optional[str] = None
+        self.frozen: Optional[dict] = None
+        self.written = False
+
+
+_startup = _Startup()
+
+
+class StartupSpan:
+    """One interval of the start-up record (context manager), and the
+    ``jax.profiler.TraceAnnotation`` of the same name where the process
+    has jax, so a user who profiles their own start-up sees the phases on
+    the profiler's clock too.  :meth:`set` adds ids known only once the
+    span is open."""
+
+    __slots__ = ("name", "ids", "t0", "_ann")
+
+    def __init__(self, name: str, ids: dict, t0: Optional[float] = None):
+        self.name, self.ids, self.t0, self._ann = name, ids, t0, None
+
+    def set(self, **ids) -> None:
+        self.ids.update(ids)
+        if self._ann is not None:
+            self._ann.set_metadata(**ids)
+
+    def __enter__(self) -> "StartupSpan":
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name, **self.ids)
+            self._ann.__enter__()
+        if self.t0 is None:
+            self.t0 = time.time()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.time()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        startup_interval(self.name, self.t0, t1, **self.ids)
+        return False
+
+
+def startup_span(name: str, **ids) -> StartupSpan:
+    """``with trace.startup_span("hvd/init/backend") as sp: ...``: always
+    recorded, on ``time.time()``."""
+    return StartupSpan(name, ids)
+
+
+def startup_interval(name: str, t0: float, t1: float, **ids) -> None:
+    """An interval whose ends are known (``hvd/process`` began before any
+    line of Python ran)."""
+    st = _startup
+    with st.lock:
+        held = st.held.get(name, 0)
+        if held >= STARTUP_MAX_PER_NAME:
+            st.dropped += 1
+            return
+        st.held[name] = held + 1
+        st.spans.append({"name": name, "t0": t0,
+                         "seconds": max(0.0, t1 - t0), **ids})
+
+
+def begin_import(t0: float, jax_imported: bool) -> StartupSpan:
+    """From the first line of ``horovod_tpu/__init__.py``: closes
+    ``hvd/process`` (the OS's start of the process to that line) and opens
+    ``hvd/import``, which the package's last line closes."""
+    started = process_started_at()
+    if started is not None:
+        startup_interval("hvd/process", started, t0,
+                         jax_imported=int(jax_imported))
+    return StartupSpan("hvd/import", {}, t0).__enter__()
+
+
+def startup_identity(**fields) -> None:
+    """``role`` (``launcher``, ``rank``, ``single``), ``rank``, ``world``,
+    ``platform``: what ``hvd.init()`` and the launcher know of the
+    process."""
+    _startup.identity.update(fields)
+
+
+def startup_attach(ledger: Optional[Callable[[], dict]] = None,
+                   directory: Optional[str] = None) -> None:
+    """What ``common/compile_cache.py`` hands the record: its ledger's
+    reader, and the directory the process's line goes to."""
+    if ledger is not None:
+        _startup.ledger = ledger
+    if directory is not None:
+        _startup.directory = directory
+
+
+def startup_seconds() -> Dict[str, float]:
+    """Seconds by span name (two intervals of one name add up)."""
+    out: Dict[str, float] = {}
+    with _startup.lock:
+        for s in _startup.spans:
+            out[s["name"]] = out.get(s["name"], 0.0) + s["seconds"]
+    return out
+
+
+register_series("hvd_startup_seconds", "gauge",
+                "seconds of a start-up phase (the start-up record)",
+                startup_seconds, label="phase")
+
+
+def _compose() -> dict:
+    import socket
+    st = _startup
+    with st.lock:
+        spans, dropped = [dict(s) for s in st.spans], st.dropped
+    return {"v": 1, **st.identity, "pid": os.getpid(),
+            "ppid": os.getppid(), "host": socket.gethostname(),
+            "process_started_at": process_started_at(),
+            "written_at": time.time(), "spans": spans,
+            "spans_dropped": dropped,
+            "ledger": st.ledger() if st.ledger is not None else None}
+
+
+def startup() -> dict:
+    """This process's start-up record: ``role``, ``pid``, ``rank``,
+    ``world``, ``host``, ``platform``, ``process_started_at``, the spans
+    (``name``, ``t0``, ``seconds`` and their ids) and the compile ledger
+    (``totals`` and ``programs`` by name; None before ``hvd.init()``).
+    After ``hvd.shutdown()`` it is what it was at the shutdown."""
+    return _startup.frozen or _compose()
+
+
+def startup_freeze(on: bool) -> None:
+    """``hvd.shutdown()`` keeps the record as it is (what compiles after it
+    is no part of this runtime's set-up); the next ``hvd.init()`` goes on
+    with it."""
+    _startup.frozen = _compose() if on else None
+
+
+def append_process_line(directory: str, record: dict) -> str:
+    """One whole line appended to ``<directory>/_hvd_processes.jsonl`` under
+    a lock on the file, whoever else appends; past its bound the file is
+    cut, in place, to its newest lines."""
+    import fcntl
+    import json
+    os.makedirs(directory, exist_ok=True)
+    path = os.path.join(directory, PROCESS_FILE)
+    data = (json.dumps(record, separators=(",", ":")) + "\n").encode()
+    fd = os.open(path, os.O_RDWR | os.O_CREAT | os.O_APPEND, 0o644)
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        os.write(fd, data)
+        size = os.fstat(fd).st_size
+        if size > PROCESS_FILE_MAX_BYTES:
+            lines = os.pread(fd, size, 0).splitlines(keepends=True)
+            lines = lines[-PROCESS_FILE_KEEP_LINES:]
+            while len(lines) > 1 and \
+                    sum(map(len, lines)) > PROCESS_FILE_MAX_BYTES // 2:
+                lines = lines[len(lines) // 2:]
+            os.ftruncate(fd, 0)
+            os.write(fd, b"".join(lines))
+    finally:
+        os.close(fd)                # and with it the lock
+    return path
+
+
+def read_process_lines(directory: str) -> List[dict]:
+    """The records in the file, oldest first; a line that is no JSON object
+    (a foreign hand) is skipped, a missing file is no records."""
+    import json
+    try:
+        with open(os.path.join(directory, PROCESS_FILE)) as fh:
+            rows = fh.read().splitlines()
+    except OSError:
+        return []
+    out = []
+    for row in rows:
+        try:
+            rec = json.loads(row)
+        except ValueError:
+            continue
+        if isinstance(rec, dict):
+            out.append(rec)
+    return out
+
+
+def write_startup(directory: Optional[str] = None) -> Optional[str]:
+    """Append this process's record to the file in ``directory``.  Without
+    one: where the compile cache was placed, once a process (the first of
+    ``hvd.shutdown()``, interpreter exit and, in the launcher, its last
+    worker's exit), and nowhere on a CPU run by itself.  Returns the path
+    written, or None."""
+    st = _startup
+    if directory is None:
+        if st.written or st.directory is None:
+            return None
+        st.written = True
+        directory = st.directory
+    try:
+        return append_process_line(directory, startup())
+    except OSError:
+        return None                 # a record, never a failure

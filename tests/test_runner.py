@@ -267,26 +267,24 @@ def test_ssh_command_generation():
 
 def test_local_launch_end_to_end(tmp_path):
     """Actually spawn 2 local worker processes and check injected env."""
-    from horovod_tpu.runner.run import launch_workers
+    from horovod_tpu.runner.run import main
     out = tmp_path / "o"
     script = tmp_path / "w.py"
     script.write_text(
         "import os\n"
         "print(os.environ['HOROVOD_RANK'], os.environ['HOROVOD_SIZE'])\n")
-    args = parse_args(["-np", "2", "--output-filename", str(out),
-                       "python", str(script)])
-    rc = launch_workers(args, placement(args))
+    rc = main(["-np", "2", "--output-filename", str(out),
+               "python", str(script)])
     assert rc == 0
     assert (out / "rank.0" / "stdout").read_text().strip() == "0 2"
     assert (out / "rank.1" / "stdout").read_text().strip() == "1 2"
 
 
 def test_local_launch_propagates_failure(tmp_path):
-    from horovod_tpu.runner.run import launch_workers
+    from horovod_tpu.runner.run import main
     script = tmp_path / "bad.py"
     script.write_text("import sys; sys.exit(3)\n")
-    args = parse_args(["-np", "2", "python", str(script)])
-    rc = launch_workers(args, placement(args))
+    rc = main(["-np", "2", "python", str(script)])
     assert rc == 3
 
 
