@@ -31,6 +31,9 @@ import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from .. import trace
+from ..ops import causal_conv
+
 # the checkpoint name of a grouped delta rule's result (by_head_groups)
 RULE_OUTPUT = "gdn_rule_out"
 # added to a head's squared length before the root, so that a zero vector
@@ -191,8 +194,20 @@ def causal_conv_silu(x, kernel, bias=None):
     """``SiLU(conv(x) + bias)`` for x ``[B, T, channels]`` and a causal
     depthwise ``kernel [taps, channels]``: tap ``j`` weighs the input
     ``taps - 1 - j`` back.  Float32 inside, x's type out.  The recurrent
-    mixers' (this one's, without a bias, and ``mamba2``'s)."""
-    f32, taps, T = jnp.float32, kernel.shape[0], x.shape[1]
+    mixers' (this one's, without a bias, and ``mamba2``'s).
+
+    One algorithm, two implementations chosen at trace time from what can
+    be observed: on a TPU, where the shape fits its tiles
+    (``ops/causal_conv.py`` ``tiles``), the kernel pair that reads x once
+    a direction; everywhere else the plain formulation below.
+    ``trace.causal_conv`` counts the call sites of each."""
+    taps, T = kernel.shape[0], x.shape[1]
+    if causal_conv.kernel_enabled() and causal_conv.tiles(x.shape, taps,
+                                                          x.dtype):
+        trace.causal_conv["kernel"] += 1        # Python: once a trace
+        return causal_conv.causal_conv_silu(x, kernel, bias)
+    trace.causal_conv["plain"] += 1
+    f32 = jnp.float32
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
     conv = kernel.astype(f32)
     y = sum(conv[j] * padded[:, j:j + T] for j in range(taps))
@@ -222,10 +237,12 @@ def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
     hk, hv, dk, dv = dims.k_heads, dims.v_heads, dims.k_dim, dims.v_dim
     f32 = jnp.float32
     with jax.named_scope("gdn/proj"):
-        qkvz = x @ p["w_qkvz"]
+        # a product a consumer: the convolution's kernels read ``qkv`` as
+        # an array of its own, where a slice of ``[q|k|v|z]`` would be copied
+        qkv = x @ p["w_qkvz"][:, :dims.qkv_width]
+        z = x @ p["w_qkvz"][:, -hv * dv:]
         ba = jnp.einsum("btd,de->bte", x, p["w_ba"],
                         preferred_element_type=f32)
-        qkv, z = qkvz[..., :dims.qkv_width], qkvz[..., -hv * dv:]
     with jax.named_scope("gdn/conv"):
         qkv = causal_conv_silu(qkv, p["conv"])
     with jax.named_scope("gdn/scan"):

@@ -200,10 +200,12 @@ def mamba2(u, p, dims: Mamba2Dims, scan=None):
     H, P, G, N = dims.heads, dims.head_dim, dims.groups, dims.state
     d_inner, f32 = dims.d_inner, jnp.float32
     with jax.named_scope("ssm/proj"):
-        zx = u @ p["w_in"][:, :d_inner + dims.conv_width]
+        # a product a consumer: the convolution's kernels read ``xBC`` as
+        # an array of its own, where a slice of ``[z|xBC]`` would be copied
+        z = u @ p["w_in"][:, :d_inner]
+        xBC = u @ p["w_in"][:, d_inner:d_inner + dims.conv_width]
         dt = jnp.einsum("btd,dh->bth", u, p["w_in"][:, -H:],
                         preferred_element_type=f32)
-        z, xBC = zx[..., :d_inner], zx[..., d_inner:]
     with jax.named_scope("ssm/conv"):
         xBC = causal_conv_silu(xBC, p["conv"], p["conv_bias"])
     with jax.named_scope("ssm/scan"):

@@ -288,6 +288,14 @@ inner_update = {"compiled": 0, "traces": 0}
 # as a member each (``_stack_leaves``).
 stage_group = {"compiled": 0, "traces": 0, "packed": 0}
 
+# Call sites of the recurrent mixers' causal convolution
+# (``models/gated_delta.py`` ``causal_conv_silu``), counted in Python at
+# trace time: ``kernel`` those that took the Pallas kernel pair
+# (``ops/causal_conv.py``: a TPU, a shape that fits its tiles), ``plain``
+# those that kept XLA's code.  ``plain`` rising on a TPU is a shape the
+# tiles do not fit.
+causal_conv = {"kernel": 0, "plain": 0}
+
 # The one table of the process-wide series: name -> (kind, help, read,
 # label).  ``read()`` gives a number, or with a ``label`` a dict from the
 # label's value to a number (``hvd_startup_seconds{phase="hvd/init"}``).
@@ -318,6 +326,9 @@ _register_counts("hvd_stage_group", stage_group, {
     "compiled": "group members staged by one compiled program",
     "traces": "traces of the staging program",
     "packed": "group members staged inside one flat buffer a dtype"})
+_register_counts("hvd_causal_conv", causal_conv, {
+    "kernel": "causal convolution call sites traced as the Pallas kernels",
+    "plain": "causal convolution call sites traced as XLA's own code"})
 
 
 def span(name: str, **ids):

@@ -12,6 +12,8 @@ All of these tests stay in this one file and compile in the test's own
 process for the same reason.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -105,6 +107,47 @@ def test_flash_backward_compiles_for_v5e(one_chip, D, H, K, causal, window):
     # forward (for the residuals) + the dq and dk/dv backward kernels
     assert _kernels(compiled.as_text()) == [
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+# the recurrent mixers' convolution at the three hybrid cells' shapes
+# (four taps; ``mamba2``'s has a bias)
+CONV_CASES = [
+    pytest.param((2, 8192, 8192), False, id="qwen3next-b2-t8192-c8192"),
+    pytest.param((1, 16384, 11520), False, id="olmo-hybrid-t16384-c11520"),
+    pytest.param((1, 8192, 10240), True, id="nemotron3-t8192-c10240-bias"),
+]
+
+
+@pytest.mark.parametrize("shape, bias", CONV_CASES)
+def test_causal_conv_kernels_compile_for_v5e(one_chip, shape, bias):
+    """Forward under ``jax.checkpoint`` and its VJP at the tiles the shape
+    chooses: two kernels, and no temporary but the float32 partial sums of
+    ``dkernel`` / ``dbias`` (XLA's code for the plain formulation keeps
+    2.7 to 3.8 GB of float32 windows at these shapes)."""
+    from horovod_tpu.ops import causal_conv
+
+    def spec(s):
+        return jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+
+    C = shape[2]
+    assert causal_conv.tiles(shape, 4, jnp.bfloat16) is not None
+
+    def both(x, k, b, dy):
+        y, back = jax.vjp(jax.checkpoint(
+            lambda *a: causal_conv.causal_conv_silu(*a, interpret=False)),
+            x, k, b)
+        return y, back(dy)
+
+    compiled = jax.jit(both).lower(
+        spec(shape), spec((4, C)), spec((C,)) if bias else None,
+        spec(shape)).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"%(?:jvp_)?(causal_conv_(?:fwd|bwd))[\w.]* = "
+                             r"[^\n]*custom_call_target=\"tpu_custom_call\"",
+                             text))
+    assert kernels == {"causal_conv_fwd", "causal_conv_bwd"}
+    assert "pad_convert_fusion" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
 
 
 def test_engine_fused_allreduce_compiles_for_four_chips(hvd, mesh4):
