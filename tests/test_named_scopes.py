@@ -1,6 +1,7 @@
 """``jax.named_scope`` around the four parts of the step programs
-(``models/resnet.py``, ``models/llama.py``): names for a device trace, and
-nothing else.  Each step is lowered and compiled at test size with the
+(``models/resnet.py``, ``models/llama.py``, ``models/ouro.py``, whose
+looped step names its layers' parts and its passes' ends too): names for a
+device trace, and nothing else.  Each step is lowered and compiled at test size with the
 scopes and with ``jax.named_scope`` turned into a no-op; the two HLO texts
 must be equal once the metadata is taken out, and the scoped one must name
 every part."""
@@ -27,6 +28,17 @@ TABLES = re.compile(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n"
 
 def stripped(hlo_text):
     return METADATA.sub("", TABLES.sub("", hlo_text))
+
+
+def renumbered(hlo_text):
+    """Every instruction named by the order of its first appearance.  For
+    the looped step alone: the numbers XLA hands out count the lowerings of
+    cached inner functions (``jit_silu_.23`` against ``jit_silu_.5``), and
+    its second lowering in one process shifts them."""
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%{len(names)}"),
+                  hlo_text)
 
 
 def resnet_step():
@@ -59,13 +71,49 @@ def llama_step():
                       tokens, tokens)
 
 
-@pytest.mark.parametrize("lower", [resnet_step, llama_step])
-def test_named_scopes_change_metadata_only(lower, monkeypatch):
+def ouro_step():
+    from horovod_tpu.compat import shard_map
+    from horovod_tpu.models import ouro
+    cfg = ouro.tiny()
+    mesh = make_mesh({"hvd": 8})
+    params = ouro.init_params(cfg, jax.random.PRNGKey(0))
+    opt = optax.adam(1e-3)
+    tokens = jnp.zeros((8, 16), jnp.int32)
+    return jax.jit(shard_map(
+        ouro.make_train_step(cfg, opt), mesh=mesh,
+        in_specs=(P(), P(), P("hvd"), P("hvd")), out_specs=(P(), P(), P()),
+        check_vma=False)).lower(params, opt.init(params), tokens, tokens)
+
+
+# the looped step's own: a layer's two halves, and what ends a pass
+# (``benchmark/families/ouro.py`` ``SCOPES``)
+OURO_SCOPES = ("attn/full", "mlp", "head", "loop/exit", "loop/carry")
+
+
+same = lambda text: text
+
+
+@pytest.mark.parametrize("lower, scopes, names", [
+    (resnet_step, SCOPES, same), (llama_step, SCOPES, same),
+    (ouro_step, ("forward", "backward", "optimizer") + OURO_SCOPES,
+     renumbered)],
+    ids=["resnet", "llama", "ouro"])
+def test_named_scopes_change_metadata_only(lower, scopes, names, monkeypatch):
     scoped = lower().compile().as_text()
-    for scope in SCOPES:
+    for scope in scopes:
         assert re.search(r'op_name="[^"]*\b%s\b' % scope, scoped), scope
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare = lower().compile().as_text()
-    assert not re.search(r'op_name="[^"]*\b(%s)/' % "|".join(SCOPES), bare)
-    assert stripped(scoped) == stripped(bare)
+    assert not re.search(r'op_name="[^"]*\b(%s)/' % "|".join(scopes), bare)
+    assert names(stripped(scoped)) == names(stripped(bare))
+
+
+def test_the_looped_steps_scopes_are_the_ones_the_benchmark_reads():
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import ouro as family
+    assert family.SCOPES == OURO_SCOPES

@@ -373,3 +373,57 @@ def test_nemotron3super_step_compiles_and_fits_as_recorded(one_chip,
                    "sandbox_temp_bytes"]
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16.9e9
+
+
+def test_ouro2_6b_step_compiles_and_fits_as_recorded(one_chip, monkeypatch):
+    """The training step of ``ouro2_6b-16l-spmd-1c`` at the cell's sizes (16
+    weight-shared layers at the published widths run four times, a head
+    over 49152 rows after every pass, 8192 tokens; ``optax.adam`` in the
+    distributed optimizer's place): it compiles for the described v5e with
+    the flash kernels at 16 heads with keys of their own, one gradient
+    accumulator for the shared weights, and its arguments and temporaries
+    are what the configuration file records, inside the 16.9 GB the runtime
+    allows."""
+    import optax
+
+    from benchmark import cell as cells
+    from benchmark.families import ouro as family
+    from benchmark.reference import ouro as data
+    from horovod_tpu.models import ouro
+    from horovod_tpu.ops import flash_attention
+
+    # the default backend here is the CPU's: without this the Pallas kernels
+    # are interpreted, not compiled for the described chip
+    monkeypatch.setattr(flash_attention, "_interpret_default", lambda: False)
+    cell = cells.load_cell("ouro2_6b-16l-spmd-1c")
+    sizes = dict(cell.sizes, use_flash=True)
+    cfg = family.config_of(sizes)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: data.init_weights(k, sizes),
+                            jax.random.PRNGKey(0))
+    adam = data.ADAM
+    optimizer = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
+                           eps=adam["eps"])
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32,
+        sharding=one_chip)
+    compiled = jax.jit(
+        ouro.make_train_step(cfg, optimizer), donate_argnums=(0, 1)).lower(
+            at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+            tokens).compile()
+    assert _kernels(compiled.as_text()) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    memory = compiled.memory_analysis()
+    recorded = cell.config["memory_analysis"]
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        params)) == 1_023_545_345
+    # weights and two moments, 6 bytes a parameter, all donated
+    assert abs(memory.argument_size_in_bytes
+               - recorded["argument_bytes"]) < 1e6
+    assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
+    assert abs(memory.temp_size_in_bytes
+               - recorded["temp_bytes"]) < 0.02 * recorded["temp_bytes"]
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 16.9e9
