@@ -4,9 +4,11 @@ The hot op of the flagship Llama path (SURVEY.md §7 "pallas kernels for the
 hot ops"; no reference analogue — Horovod ships no model math).  Standard
 flash attention: the [Tq, Tk] score matrix is never materialized in HBM;
 each (batch·head, q-block) streams k/v blocks through VMEM with an
-online-softmax accumulator.  The backward pass recomputes probabilities
-blockwise from the saved logsumexp — two kernels (dq; dk/dv) so every
-accumulator lives in VMEM scratch across the inner grid dimension.
+online-softmax accumulator whose row statistics stay lane-replicated, in
+the layout the score block's reductions leave them.  The backward pass
+recomputes probabilities blockwise from the saved logsumexp — two kernels
+(dq; dk/dv) so every accumulator lives in VMEM scratch across the inner
+grid dimension.
 
 Layout: ``[B, T, H, D]`` (the llama layout).  GQA is native: pass kv with
 ``K = H / rep`` heads and each q-head group reads its shared kv head
@@ -59,8 +61,9 @@ def _env_int(name: str, dflt: int, valid=lambda v: True) -> int:
 
 def flash_min_seq(causal: bool = False) -> int:
     """Auto-mode crossover.  The defaults are unmeasured on the current
-    machine (they come from an earlier installation's in-model A/Bs;
-    ROADMAP queue 1 item 4 re-measures them):
+    machine (they come from an earlier installation's in-model A/Bs; the
+    sweep that closes ROADMAP queue 1 item 4a re-measures them, and every
+    benchmark cell runs at 4096 tokens or more, far above either):
 
     - **causal** (llama family): 512 — whole-block causal skipping halves
       the work, so flash is expected to win early.
@@ -68,8 +71,8 @@ def flash_min_seq(causal: bool = False) -> int:
       rescaling machinery is expected to be pure overhead against XLA's
       fused attention.
 
-    ``HVD_TPU_FLASH_MIN_SEQ`` overrides BOTH; tools/flash_sweep.py
-    measures the crossover per chip."""
+    ``HVD_TPU_FLASH_MIN_SEQ`` overrides BOTH; ``tools/flash_sweep.py
+    --xla --seqs ...`` measures the crossover per chip."""
     return _env_int("HVD_TPU_FLASH_MIN_SEQ", 512 if causal else 1024,
                     lambda v: v >= 0)
 
@@ -93,17 +96,39 @@ def flash_enabled(seq: Optional[int] = None,
 
 
 # ----------------------------------------------------------------- forward
+LANES = 128     # of a vector register: the row statistics' scratch width
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
                 n_k, tk_valid, window):
+    """Online softmax over the k-blocks of one (head, q-block).  The running
+    max ``m`` and sum ``l`` live as ``[block_q, LANES]`` scratch, a row's
+    value in every lane: that is the layout a reduction along the lanes of
+    the ``[block_q, block_k]`` scores leaves behind, so nothing between the
+    two products changes orientation (as ``(block_q,)`` vectors each block
+    paid four relayouts through VMEM, 2.9 us a live block where this form
+    takes 1.1: PERF.md section 6, PR 41)."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    head_dim = acc_ref.shape[1]
 
     @pl.when(ki == 0)
     def _():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        # Half of the mask's NEG_INF: a live block that masks a row whole
+        # then leaves it p = exp(NEG_INF - m) = 0, l = 0 (from NEG_INF
+        # itself p would be exp(0) = 1 and the row would average v).
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF / 2)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def across(stat, width):
+        """A ``[block_q, LANES]`` statistic against a block ``width`` wide."""
+        if width == LANES:
+            return stat
+        if width % LANES == 0:
+            return pltpu.repeat(stat, width // LANES, axis=1)
+        return stat[:, :1]
 
     q_start = qi * block_q
     k_start = ki * block_k
@@ -139,28 +164,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - across(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         # p is quantized to the value dtype for the second MXU pass (the
         # standard TPU flash formulation; exact when inputs are f32).
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = (acc_ref[:] * across(alpha, head_dim)
+                      + jax.lax.dot_general(
+                          p.astype(v_ref.dtype), v_ref[0],
+                          (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32))
         m_ref[:] = m_new
 
     @pl.when(ki == n_k - 1)
     def _():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[:] / across(safe_l, head_dim)
+                    ).astype(o_ref.dtype)
         # Empty rows (fully masked) store lse=0, NOT -inf: the backward
         # computes p = exp(s - lse) with s = NEG_INF on masked entries, and
         # exp(NEG_INF - 0) = 0 zeroes their contribution, while -inf would
         # turn it into exp(0) = 1 and poison dk/dv.
-        lse_ref[0, :, 0] = jnp.where(l == 0.0, 0.0,
-                                     m_ref[:] + jnp.log(safe_l))
+        lse = jnp.where(l == 0.0, 0.0, m_ref[:] + jnp.log(safe_l))
+        lse_ref[0] = lse[:, :1]
 
 
 # ---------------------------------------------------------------- backward
@@ -316,8 +344,8 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
+            pltpu.VMEM((bq, LANES), jnp.float32),
         ],
         compiler_params=tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -329,12 +357,14 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
 def _block_defaults() -> tuple:
     """Kernel tile defaults, env-overridable for per-chip tuning
     (``HVD_TPU_FLASH_BLOCK_Q`` / ``HVD_TPU_FLASH_BLOCK_K`` — read at
-    trace time; tools/flash_sweep.py measures the candidates).  The
-    512x512 default is unmeasured on the current machine (an earlier
-    installation's sweep chose it: bigger tiles amortize the grid/rescale
-    overhead and keep the MXU fed).  The sublane rule (multiples of 8) is
-    enforced here so a bad value keeps the default instead of dying in
-    Mosaic lowering."""
+    trace time; ``tools/flash_sweep.py --blocks`` measures candidates).
+    An earlier installation's sweep chose 512x512 (bigger tiles amortize
+    the grid/rescale overhead and keep the MXU fed); on this chip the
+    three kernels reach 46-78 % of the MXU's peak at it at the benchmark's
+    five geometries (PERF.md section 6, PR 41) and no other tile has been
+    measured (ROADMAP queue 1 item 4a).  The sublane rule (multiples of
+    8) is enforced here so a bad value keeps the default instead of dying
+    in Mosaic lowering."""
     ok = lambda v: v >= 8 and v % 8 == 0  # noqa: E731
     return (_env_int("HVD_TPU_FLASH_BLOCK_Q", 512, ok),
             _env_int("HVD_TPU_FLASH_BLOCK_K", 512, ok))

@@ -143,20 +143,117 @@ def test_flash_sliding_window_gqa():
         flash_attention(q, k, v, causal=False, window=12)
 
 
-def test_flash_tpu_lowering():
+def _reference_forward(q, k, v, scale, causal, rep, window):
+    """Plain float64 attention on the kernels' ``[BH, T, D]`` layout with
+    its logsumexp: ``(o, lse)``, both 0 in a row with no visible key."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    k, v = np.repeat(k, rep, axis=0), np.repeat(v, rep, axis=0)
+    s = np.einsum("bqd,bkd->bqk", q, k) * scale
+    rows = np.arange(s.shape[1])[:, None]
+    cols = np.arange(s.shape[2])[None]
+    visible = np.ones(s.shape[1:], bool)
+    if causal:
+        visible &= rows >= cols
+        if window:
+            visible &= rows - cols < window
+    s = np.where(visible, s, -np.inf)
+    empty = ~visible.any(axis=-1)
+    top = np.where(empty, 0.0, np.max(s, axis=-1))
+    p = np.exp(s - top[..., None])
+    total = np.where(empty, 1.0, p.sum(axis=-1))
+    o = np.einsum("bqk,bkd->bqd", p, v) / total[..., None]
+    return o, np.where(empty, 0.0, top + np.log(total)), empty
+
+
+@pytest.mark.parametrize("D,rep,causal,window,Tq,Tk,blocks", [
+    pytest.param(64, 1, True, 0, 64, 64, (32, 32), id="d64-rep1-causal"),
+    pytest.param(64, 4, False, 0, 64, 64, (32, 32), id="d64-rep4-full"),
+    pytest.param(128, 1, False, 0, 48, 48, (16, 16), id="d128-rep1-full"),
+    pytest.param(128, 4, True, 0, 70, 70, (32, 32),
+                 id="d128-rep4-causal-padded"),
+    pytest.param(128, 16, True, 0, 64, 64, (32, 32), id="d128-rep16-causal"),
+    pytest.param(256, 1, True, 0, 33, 33, (16, 16),
+                 id="d256-rep1-causal-padded"),
+    pytest.param(256, 16, False, 0, 32, 32, (16, 16), id="d256-rep16-full"),
+    pytest.param(128, 4, True, 8, 70, 70, (32, 32),
+                 id="d128-rep4-window8-padded"),
+    pytest.param(64, 1, True, 40, 64, 64, (16, 16), id="d64-rep1-window40"),
+    pytest.param(64, 4, False, 0, 17, 50, (16, 16), id="d64-rep4-tq17-tk50"),
+    pytest.param(128, 1, True, 0, 50, 17, (16, 16),
+                 id="d128-rep1-causal-tq50-tk17"),
+    pytest.param(64, 1, True, 8, 40, 16, (16, 16),
+                 id="d64-rep1-window8-rows-without-a-key"),
+    pytest.param(128, 4, True, 0, 256, 256, (128, 128),
+                 id="d128-rep4-causal-tile128"),
+])
+def test_flash_forward_output_and_logsumexp(D, rep, causal, window, Tq, Tk,
+                                            blocks):
+    """The forward kernel alone: ``o`` AND ``lse`` against a plain
+    float64 logsumexp.  The backward kernels and ring attention's merge of
+    shards read ``lse``; a row that sees no key stores ``lse`` 0 (not
+    -inf) and ``o`` 0."""
+    from horovod_tpu.ops.flash_attention import _fwd_impl
+
+    K = 2
+    rng = np.random.RandomState(D + 31 * rep + Tq + 7 * Tk + window)
+    q = jnp.asarray(rng.randn(K * rep, Tq, D), jnp.float32)
+    k = jnp.asarray(rng.randn(K, Tk, D), jnp.float32)
+    v = jnp.asarray(rng.randn(K, Tk, D), jnp.float32)
+    scale = D ** -0.5
+    o, lse = _fwd_impl(q, k, v, scale, causal, *blocks, True, rep, window)
+    ref_o, ref_lse, empty = _reference_forward(q, k, v, scale, causal, rep,
+                                               window)
+    assert o.shape == (K * rep, Tq, D) and lse.shape == (K * rep, Tq)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o), ref_o, atol=3e-5, rtol=3e-5)
+    np.testing.assert_allclose(np.asarray(lse), ref_lse, atol=3e-5,
+                               rtol=3e-5)
+    # causal with a window and more queries than keys: row r sees the keys
+    # in (r - window, r], none of them under Tk from r = Tk + window - 1 on
+    assert empty.sum() == (max(0, Tq - (Tk + window - 1)) if window else 0)
+    assert np.all(np.asarray(lse)[:, empty] == 0.0)
+    assert np.all(np.asarray(o)[:, empty] == 0.0)
+
+
+def _flash_sweep():
+    """tools/flash_sweep.py as a module (``tools`` is no package)."""
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "flash_sweep", os.path.join(os.path.dirname(__file__), os.pardir,
+                                    "tools", "flash_sweep.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# A llama layer and the five decoder cells' attention layers as a step sees
+# them, from the table tools/flash_sweep.py times on the chip.
+CELL_GEOMETRIES = [pytest.param(1, 1024, 8, 4, 128, None,
+                                id="llama-d128-h8k4-t1024")] + [
+    pytest.param(g["B"], g["T"], g["H"], g["K"], g["D"], g["window"] or None,
+                 id=cell) for cell, g in _flash_sweep().GEOMETRIES.items()]
+
+
+@pytest.mark.parametrize("B,T,H,K,D,window", CELL_GEOMETRIES)
+def test_flash_tpu_lowering(B, T, H, K, D, window):
     """Cross-platform lowering: the Mosaic/TPU pipeline runs client-side,
     so a CPU host can verify the kernels lower for TPU at real llama
-    shapes — the guard that keeps the driver's on-TPU compile check safe."""
+    shapes and at each benchmark cell's heads, head width, sequence and
+    window — the guard that keeps the driver's on-TPU compile check safe
+    (tests/test_tpu_compile.py compiles them for a described v5e)."""
     def f(q, k, v):
         return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            q, k, v, causal=True, window=window,
+            interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
-    spec_q = jax.ShapeDtypeStruct((1, 1024, 8, 128), jnp.bfloat16)
-    spec_kv = jax.ShapeDtypeStruct((1, 1024, 4, 128), jnp.bfloat16)  # GQA
+    spec_q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16)
+    spec_kv = jax.ShapeDtypeStruct((B, T, K, D), jnp.bfloat16)  # GQA
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
         spec_q, spec_kv, spec_kv)
-    assert len(exp.mlir_module_serialized) > 0
+    assert exp.mlir_module().count("tpu_custom_call") == 3
 
 
 def test_prefill_tpu_lowering(monkeypatch):
@@ -408,3 +505,21 @@ def test_gpt2_uses_flash_when_forced(monkeypatch):
     out = gpt2.forward(params, tokens, cfg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-4, rtol=2e-4)
+
+
+def test_flash_sweep_counts_blocks_and_names_kernels():
+    """tools/flash_sweep.py's arithmetic: the live and dead blocks a head
+    by the kernels' own ``live`` test (120 of 256 grid steps dead at 8192
+    tokens), and a device event's kernel by its instruction's name."""
+    sweep = _flash_sweep()
+    assert sweep.blocks_of(8192, 512, 512, 0) == (136, 120)
+    assert sweep.blocks_of(16384, 512, 512, 0) == (528, 496)
+    assert sweep.blocks_of(4096, 512, 512, 4096) == (36, 28)
+    assert sweep.blocks_of(4096, 512, 512, 1024) == (21, 43)
+    for event, kernel in [
+            ("%flash_fwd.13 = (bf16[16,8192,128]{2,1,0}) custom-call(...)",
+             "flash_fwd"),
+            ("%jvp_flash_bwd_dkv_.9 = (bf16[8,4096,128]) custom-call(...)",
+             "flash_bwd_dkv"),
+            ("flash_bwd_dq.1", "flash_bwd_dq")]:
+        assert sweep.EVENT_KERNEL.match(event).group(1) == kernel

@@ -69,8 +69,8 @@ def _kernels(hlo_text):
 
 # head_dim 64, 128 and 256 (qwen3_next's full-attention layer: 16 query and
 # 2 key-value heads), GQA but for olmo_hybrid's 30 heads with keys of their
-# own, up to nemotron_h's 16 query heads a key head; causal, one windowed,
-# one non-causal.
+# own, up to nemotron_h's 16 query heads a key head and ouro's 16 with keys
+# of their own; causal, one windowed, one non-causal.
 FLASH_CASES = [
     pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
     pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
@@ -79,6 +79,7 @@ FLASH_CASES = [
     pytest.param(256, 16, 2, True, None, id="d256-h16k2-causal"),
     pytest.param(128, 30, 30, True, None, id="d128-h30k30-causal"),
     pytest.param(128, 32, 2, True, None, id="d128-h32k2-causal"),
+    pytest.param(128, 16, 16, True, None, id="d128-h16k16-causal"),
 ]
 
 

@@ -1,18 +1,22 @@
-"""On-chip flash-vs-XLA attention sweep: find the crossover + best blocks.
+"""On-chip sweep of the flash attention kernels at the benchmark's five
+attention geometries: each kernel alone, forward + backward, the tiles.
 
-At short sequence the flash rescaling machinery can cost more than it
-saves while the [T,T] score tile still fits on-chip; flash exists for the
-memory wall at LONG sequence.  This sweep measures where that wall is on
-the chip and which block sizes the kernel wants there, so the auto routing
-(``flash_enabled`` / ``LlamaConfig.use_flash``) can pick the winner per
-shape instead of a blanket platform default.  Not yet run on the current
-machine (ROADMAP queue 1 item 4a).
+Per geometry (a decoder cell's heads, head width, sequence and window, one
+step's batch, bf16) it times, from one device trace, the forward kernel
+alone (``_fwd_impl``: ``flash_fwd``) and the two backward kernels alone
+(``_bwd_impl``: ``flash_bwd_dq``, ``flash_bwd_dkv``) as the median device
+time of the kernel's events, and on the host clock a jitted forward +
+backward of ``flash_attention`` (gradients of q, k and v: what a layer of a
+training step runs, without ``jax.checkpoint``'s second forward).  Beside
+each kernel: the time of a live block (0.35 us taken off for each dead grid
+step) and the share of the MXU's peak its products reach — the table of
+PERF.md section 6, PR 41, re-read in one chip call:
 
-Per (seq, impl) it times a jitted fwd+bwd (grads wrt q,k,v — the training
-shape of a decoder step) of causal GQA attention at fixed
-token count (B*T = const), bf16 inputs:
+    python tools/flash_sweep.py --out chiprun_out/FLASH_SWEEP.json
 
-    python tools/flash_sweep.py --out FLASH_SWEEP.json
+``--blocks 256x512,512x1024`` adds tile candidates (forward + backward
+each), ``--xla`` XLA's own attention where its ``[T, T]`` scores fit (the
+crossover of ``flash_min_seq``), ``--seqs`` other sequence lengths.
 """
 
 from __future__ import annotations
@@ -20,133 +24,204 @@ from __future__ import annotations
 import argparse
 import datetime
 import functools
+import glob
 import json
 import os
+import re
+import statistics
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-SEQS = [512, 1024, 2048, 4096, 8192]
-BLOCKS = [(128, 128), (256, 256), (512, 512), (128, 512), (256, 1024)]
-TOKENS = 64 * 1024          # B = TOKENS // T  (fixed work per measurement)
-H, K, D = 8, 4, 64          # toy heads; mistral7b-4l's are 32, 8, 128
-
-
-def _loss_fn(attn, iters):
-    """One jitted dispatch running ``iters`` fwd+bwd steps in a lax.scan.
-
-    The scan carry perturbs q every iteration from the previous step's
-    gradients, so no two executions see the same input and one dispatch
-    amortizes the host's launch cost over ``iters`` steps.  XLA would DCE
-    any grad the carry ignores — dk/dv come from a separate Pallas call
-    than dq — so the carry folds an element of all three."""
-    grad = jax.grad(lambda q, k, v: attn(q, k, v).astype(jnp.float32)
-                    .sum(), argnums=(0, 1, 2))
-
-    @jax.jit
-    def many(q, k, v, seed):
-        def body(t, i):
-            dq, dk, dv = grad(q + t.astype(q.dtype), k, v)
-            t_new = ((dq.ravel()[0] + dk.ravel()[0] + dv.ravel()[0])
-                     .astype(jnp.float32) * 1e-6 + i.astype(jnp.float32)
-                     * 1e-3)
-            return t_new, ()
-        t, _ = jax.lax.scan(body, seed, jnp.arange(iters))
-        return t
-    return many
+# A cell's attention layer as one step sees it: batch, sequence, query and
+# key-value heads, head width, window (benchmark/configs, benchmark/traffic).
+GEOMETRIES = {
+    "ouro2_6b-16l-spmd-1c": dict(B=1, T=8192, H=16, K=16, D=128, window=0),
+    "olmohybrid-4l-spmd-1c": dict(B=1, T=16384, H=30, K=30, D=128, window=0),
+    "mistral7b-4l-spmd-1c": dict(B=1, T=4096, H=32, K=8, D=128, window=4096),
+    "nemotron3super-11l-spmd-1c": dict(B=1, T=8192, H=32, K=2, D=128,
+                                       window=0),
+    "qwen3next-4l-spmd-1c": dict(B=2, T=8192, H=16, K=2, D=256, window=0),
+}
+KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
+DEAD_STEP_US = 0.35         # a grid step whose block is skipped
+# a device event's kernel: ``flash_fwd.3``, ``jvp_flash_fwd_.4`` under autodiff
+EVENT_KERNEL = re.compile(r"%?(?:jvp_)?(\w+?)_?(?:\.\d+)?(?: = |$)")
 
 
-def _time(fn, args, iters=10, warmup=1):
-    """The ``seed`` argument makes the warmup and timed calls differ in
-    their inputs.  float() fetches the result to host as a second sync
-    barrier."""
-    for w in range(warmup):
-        jax.block_until_ready(fn(*args, jnp.float32(w)))
+def blocks_of(T, bq, bk, window):
+    """(live, dead) blocks a head of a causal kernel's grid: what the
+    kernels' own ``live`` test keeps."""
+    n_q, n_k = -(-T // bq), -(-T // bk)
+    live = 0
+    for i in range(n_q):
+        for j in range(n_k):
+            ok = j * bk <= i * bq + bq - 1
+            if window:
+                ok = ok and j * bk + bk > i * bq - window
+            live += ok
+    return live, n_q * n_k - live
+
+
+def kernel_events(trace_dir):
+    """{kernel: [device ms of each event]} from the profiler's trace, read
+    as the benchmark reads it: the device operations whose instruction is
+    named after the ``pallas_call``."""
+    from benchmark import trace_reduce
+
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = {}
+    for ops in trace_reduce.read_planes(path)[0].values():
+        for name, start_s, end_s in ops:
+            m = EVENT_KERNEL.match(name)
+            if m and m.group(1) in KERNELS:
+                out.setdefault(m.group(1), []).append((end_s - start_s) * 1e3)
+    return out
+
+
+def traced_ms(calls, reps=3):
+    """Run every ``(fn, args)`` ``reps`` times under one device trace (each
+    compiled and run once before it) and return :func:`kernel_events`."""
+    for fn, args in calls:
+        jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for fn, args in calls:
+                for _ in range(reps):
+                    jax.block_until_ready(fn(*args))
+        return kernel_events(d)
+
+
+def host_ms(fn, args, reps=5):
+    """Wall ms a call of ``reps`` back-to-back calls, after one warm-up."""
+    jax.block_until_ready(fn(*args))
     t0 = time.perf_counter()
-    out = fn(*args, jnp.float32(warmup))
+    out = [fn(*args) for _ in range(reps)]
     jax.block_until_ready(out)
-    float(out)
-    return (time.perf_counter() - t0) / iters * 1e3  # ms per inner step
+    return (time.perf_counter() - t0) / reps * 1e3
 
 
-def sweep(seqs, iters, tokens=TOKENS, causal=True):
+def inputs(g, seed=0):
+    """q, do ``[B*H, T, D]`` and k, v ``[B*K, T, D]`` in bf16, made on the
+    device: the layout the kernels take."""
+    bh, bk = g["B"] * g["H"], g["B"] * g["K"]
+    return tuple(
+        jax.random.normal(key, (n, g["T"], g["D"]), jnp.bfloat16)
+        for key, n in zip(jax.random.split(jax.random.PRNGKey(seed), 4),
+                          (bh, bk, bk, bh)))
+
+
+def per_kernel(g, bq=512, bk=512, reps=3):
+    """Each kernel alone at geometry ``g``: {kernel: {ms, live_block_us,
+    mxu_pct}}."""
+    from benchmark import cell
+    from horovod_tpu.ops import flash_attention as fa
+
+    peak = cell.peaks_for(jax.devices()[0].device_kind)["bf16_flops_per_s"]
+    q, k, v, do = inputs(g)
+    rep, scale = g["H"] // g["K"], g["D"] ** -0.5
+    fwd = jax.jit(lambda q, k, v: fa._fwd_impl(
+        q, k, v, scale, True, bq, bk, False, rep, g["window"]))
+    o, lse = fwd(q, k, v)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    bwd = jax.jit(functools.partial(
+        fa._bwd_impl, scale=scale, causal=True, block_q=bq, block_k=bk,
+        interpret=False, rep=rep, window=g["window"]))
+    events = traced_ms([(fwd, (q, k, v)), (bwd, (q, k, v, do, lse, delta))],
+                       reps)
+    live, dead = blocks_of(g["T"], bq, bk, g["window"])
+    heads, out = g["B"] * g["H"], {}
+    for name in KERNELS:
+        ms = statistics.median(events[name])
+        block_us = (ms * 1e3 - DEAD_STEP_US * dead * heads) / (live * heads)
+        least_us = PRODUCTS[name] * 2 * bq * bk * g["D"] / peak * 1e6
+        out[name] = {"ms": round(ms, 4),
+                     "live_block_us": round(block_us, 4),
+                     "mxu_pct": round(
+                         100 * least_us * live * heads / (ms * 1e3), 2)}
+    return out
+
+
+def fwd_bwd_ms(g, attn, reps=5):
+    """Forward + backward of ``attn(q, k, v)`` on ``[B, T, H, D]`` inputs,
+    wall ms a call."""
+    q, k, v, _ = inputs(g)
+    to4 = lambda x, h: x.reshape(  # noqa: E731
+        g["B"], h, g["T"], g["D"]).transpose(0, 2, 1, 3)
+    grad = jax.jit(jax.grad(
+        lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))
+    return host_ms(grad, (to4(q, g["H"]), to4(k, g["K"]), to4(v, g["K"])),
+                   reps)
+
+
+def sweep(geometries, blocks, xla=False, reps=3):
     from horovod_tpu.ops.flash_attention import flash_attention
     from horovod_tpu.parallel.ring_attention import local_flash_attention
 
-    rng = np.random.RandomState(0)
     rows = []
-    for T in seqs:
-        B = max(tokens // T, 1)
-        q = jnp.asarray(rng.randn(B, T, H, D), jnp.bfloat16)
-        k = jnp.asarray(rng.randn(B, T, K, D), jnp.bfloat16)
-        v = jnp.asarray(rng.randn(B, T, K, D), jnp.bfloat16)
-        row = {"seq": T, "batch": B, "tokens": B * T,
-               "causal": causal, "ms": {}}
-
-        xla = _loss_fn(functools.partial(local_flash_attention,
-                                         causal=causal), iters)
+    for name, g in geometries.items():
+        row = {"cell": name, **g, "fwd_bwd_ms": {}, "errors": {}}
+        window = g["window"] or None
+        candidates = {f"flash_{bq}x{bk}": functools.partial(
+            flash_attention, causal=True, window=window, block_q=bq,
+            block_k=bk) for bq, bk in blocks if bq <= g["T"] and bk <= g["T"]}
+        if xla:
+            candidates["xla"] = functools.partial(
+                local_flash_attention, causal=True, window=window)
         try:
-            row["ms"]["xla"] = round(_time(xla, (q, k, v), iters), 3)
-        except Exception as exc:  # noqa: BLE001 — OOM at long T is the point
-            row["ms"]["xla"] = None
-            row.setdefault("errors", {})["xla"] = repr(exc)[:200]
-
-        for bq, bk in BLOCKS:
-            if bq > T or bk > T:
-                continue
-            fl = _loss_fn(functools.partial(
-                flash_attention, causal=causal, block_q=bq, block_k=bk),
-                iters)
-            key = f"flash_{bq}x{bk}"
+            row["kernels"] = per_kernel(g, *blocks[0], reps=reps)
+        except Exception as exc:  # noqa: BLE001 — a tile Mosaic refuses
+            row["errors"]["kernels"] = repr(exc)[:200]
+        for key, attn in candidates.items():
             try:
-                row["ms"][key] = round(_time(fl, (q, k, v), iters), 3)
-            except Exception as exc:  # noqa: BLE001
-                row["ms"][key] = None
-                row.setdefault("errors", {})[key] = repr(exc)[:200]
-
-        timed = [(v, k) for k, v in row["ms"].items() if v is not None]
-        best = min(timed) if timed else (None, None)
-        row["best"] = best[1]
-        row["flash_best_vs_xla"] = (
-            round(row["ms"]["xla"] / best[0], 3)
-            if row["ms"].get("xla") and best[1]
-            and not best[1].startswith("xla") else None)
+                row["fwd_bwd_ms"][key] = round(fwd_bwd_ms(g, attn), 3)
+            except Exception as exc:  # noqa: BLE001 — OOM at long T
+                row["errors"][key] = repr(exc)[:200]
         rows.append(row)
-        print(json.dumps(row))
+        print(json.dumps(row), flush=True)
     return rows
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="FLASH_SWEEP.json")
-    ap.add_argument("--iters", type=int, default=10)
-    ap.add_argument("--no-causal", action="store_true",
-                    help="sweep NON-causal attention (the bert-family "
-                         "routing default's evidence)")
-    ap.add_argument("--seqs", default=",".join(map(str, SEQS)))
-    ap.add_argument("--tokens", type=int, default=TOKENS,
-                    help="tokens per measurement (smoke tests shrink this)")
+    ap.add_argument("--cells", default=",".join(GEOMETRIES))
+    ap.add_argument("--seqs", default="",
+                    help="sequence lengths in place of each cell's own")
+    ap.add_argument("--blocks", default="512x512",
+                    help="tile candidates, the first also for the kernels "
+                         "alone")
+    ap.add_argument("--xla", action="store_true",
+                    help="XLA's attention beside the kernels")
+    ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args()
-    seqs = [int(s) for s in args.seqs.split(",")]
+    blocks = [tuple(int(n) for n in b.split("x"))
+              for b in args.blocks.split(",")]
+    geometries = {c: GEOMETRIES[c] for c in args.cells.split(",")}
+    if args.seqs:
+        geometries = {f"{c}@{T}": dict(g, T=int(T))
+                      for c, g in geometries.items()
+                      for T in args.seqs.split(",")}
 
     dev = jax.devices()[0]
-    rows = sweep(seqs, args.iters, args.tokens,
-                 causal=not args.no_causal)
     out = {
-        "provenance": "tools/flash_sweep.py — jitted fwd+bwd "
-                      f"{'causal' if not args.no_causal else 'non-causal'} GQA "
-                      f"attention, bf16, H={H} K={K} D={D}, fixed "
-                      f"{args.tokens} tokens per shape",
+        "provenance": "tools/flash_sweep.py: causal attention, bf16; "
+                      "kernels alone by device trace (median event), "
+                      "forward + backward on the host clock",
         "captured_utc": datetime.datetime.now(
             datetime.timezone.utc).isoformat(),
         "device": {"kind": dev.device_kind, "platform": dev.platform},
-        "rows": rows,
+        "rows": sweep(geometries, blocks, args.xla, args.reps),
     }
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as fh:
         json.dump(out, fh, indent=2)
     print(f"wrote {args.out}")
