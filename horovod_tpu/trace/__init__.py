@@ -24,16 +24,17 @@ import sys
 from . import core
 from .core import (DIGEST_MAX_CYCLES, DIGEST_MAX_OPEN, OFF, PHASE_BUCKETS_US,
                    PHASES, REDUCE_LEGS, CycleRecord, ProgramSpan, TensorSpan,
-                   TraceRecorder, causal_conv, inner_update, installed, span,
-                   stage_group, startup, startup_span, write_startup)
+                   TraceRecorder, causal_conv, inner_update, installed,
+                   selective_scan, span, stage_group, startup, startup_span,
+                   write_startup)
 from .writer import TraceWriter
 
 __all__ = [
     "PHASES", "REDUCE_LEGS", "PHASE_BUCKETS_US", "DIGEST_MAX_CYCLES",
     "DIGEST_MAX_OPEN", "CycleRecord", "TensorSpan", "TraceRecorder",
     "TraceWriter", "maybe_install", "span", "installed", "OFF",
-    "ProgramSpan", "inner_update", "stage_group", "causal_conv", "startup",
-    "startup_span", "write_startup",
+    "ProgramSpan", "inner_update", "stage_group", "causal_conv",
+    "selective_scan", "startup", "startup_span", "write_startup",
 ]
 
 

@@ -296,6 +296,12 @@ stage_group = {"compiled": 0, "traces": 0, "packed": 0}
 # tiles do not fit.
 causal_conv = {"kernel": 0, "plain": 0}
 
+# The same pair for the Mamba-1 mixers' selective scan
+# (``ops/selective_scan.py`` ``selective_scan``): ``kernel`` call sites took
+# the Pallas kernel pair, ``plain`` the ``lax.scan`` over chunks of tokens.
+# ``plain`` rising on a TPU is a shape the tiles do not fit.
+selective_scan = {"kernel": 0, "plain": 0}
+
 # The one table of the process-wide series: name -> (kind, help, read,
 # label).  ``read()`` gives a number, or with a ``label`` a dict from the
 # label's value to a number (``hvd_startup_seconds{phase="hvd/init"}``).
@@ -329,6 +335,9 @@ _register_counts("hvd_stage_group", stage_group, {
 _register_counts("hvd_causal_conv", causal_conv, {
     "kernel": "causal convolution call sites traced as the Pallas kernels",
     "plain": "causal convolution call sites traced as XLA's own code"})
+_register_counts("hvd_selective_scan", selective_scan, {
+    "kernel": "selective scan call sites traced as the Pallas kernels",
+    "plain": "selective scan call sites traced as XLA's own code"})
 
 
 def span(name: str, **ids):

@@ -82,6 +82,36 @@ def test_flash_gqa_matches_repeated(causal):
                                    atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("T, blocks", [(64, (32, 32)), (70, (16, 32))],
+                         ids=["t64", "t70-padded"])
+def test_flash_twenty_query_heads_on_one_key_head(T, blocks):
+    """20 query heads on ONE key-value head (``jamba``'s attention layer;
+    the other families' kernels' tests go up to 16 a key head): values and
+    all three gradients against the materialized repeat; ``flash_bwd_dkv``
+    sums ``dk`` and ``dv`` over the 20 heads of the one group."""
+    B, H, K, D = 2, 20, 1, 128
+    rng = np.random.RandomState(43)
+    q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
+    k = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
+    v = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
+    w = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1])
+    plain = lambda q, k, v: local_flash_attention(
+        q, jnp.repeat(k, H, axis=2), jnp.repeat(v, H, axis=2), causal=True)
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               atol=3e-5, rtol=3e-5)
+    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(plain(*a) * w), argnums=(0, 1, 2))(
+        q, k, v)
+    assert gf[1].shape == (B, T, K, D)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
 def test_flash_cross_attention_shapes():
     """Tq != Tk (cross attention / KV cache shapes)."""
     rng = np.random.RandomState(3)
@@ -172,6 +202,7 @@ def _reference_forward(q, k, v, scale, causal, rep, window):
     pytest.param(128, 4, True, 0, 70, 70, (32, 32),
                  id="d128-rep4-causal-padded"),
     pytest.param(128, 16, True, 0, 64, 64, (32, 32), id="d128-rep16-causal"),
+    pytest.param(128, 20, True, 0, 64, 64, (32, 32), id="d128-rep20-causal"),
     pytest.param(256, 1, True, 0, 33, 33, (16, 16),
                  id="d256-rep1-causal-padded"),
     pytest.param(256, 16, False, 0, 32, 32, (16, 16), id="d256-rep16-full"),

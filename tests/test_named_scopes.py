@@ -85,6 +85,26 @@ def ouro_step():
         check_vma=False)).lower(params, opt.init(params), tokens, tokens)
 
 
+def jamba_step():
+    from horovod_tpu.compat import shard_map
+    from horovod_tpu.models import jamba
+    jax.clear_caches()      # a checkpointed region traced before is kept
+    cfg = jamba.tiny()
+    mesh = make_mesh({"hvd": 8})
+    params = jamba.init_params(cfg, jax.random.PRNGKey(0))
+    opt = optax.adam(1e-3)
+    tokens = jnp.zeros((8, 16), jnp.int32)
+    return jax.jit(shard_map(
+        jamba.make_train_step(cfg, opt), mesh=mesh,
+        in_specs=(P(), P(), P("hvd"), P("hvd")), out_specs=(P(), P(), P()),
+        check_vma=False)).lower(params, opt.init(params), tokens, tokens)
+
+
+# the Mamba-1 hybrid's own: the mixer's four parts, the attention layer, a
+# layer's MLP and the tied head (``benchmark/families/jamba.py`` ``SCOPES``)
+JAMBA_SCOPES = ("ssm/proj", "ssm/conv", "ssm/scan", "ssm/out", "attn/full",
+                "mlp", "head")
+
 # the looped step's own: a layer's two halves, and what ends a pass
 # (``benchmark/families/ouro.py`` ``SCOPES``)
 OURO_SCOPES = ("attn/full", "mlp", "head", "loop/exit", "loop/carry")
@@ -96,8 +116,10 @@ same = lambda text: text
 @pytest.mark.parametrize("lower, scopes, names", [
     (resnet_step, SCOPES, same), (llama_step, SCOPES, same),
     (ouro_step, ("forward", "backward", "optimizer") + OURO_SCOPES,
+     renumbered),
+    (jamba_step, ("forward", "backward", "optimizer") + JAMBA_SCOPES,
      renumbered)],
-    ids=["resnet", "llama", "ouro"])
+    ids=["resnet", "llama", "ouro", "jamba"])
 def test_named_scopes_change_metadata_only(lower, scopes, names, monkeypatch):
     scoped = lower().compile().as_text()
     for scope in scopes:
@@ -117,3 +139,13 @@ def test_the_looped_steps_scopes_are_the_ones_the_benchmark_reads():
         sys.path.insert(0, root)
     from benchmark.families import ouro as family
     assert family.SCOPES == OURO_SCOPES
+
+
+def test_the_jamba_steps_scopes_are_the_ones_the_benchmark_reads():
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.families import jamba as family
+    assert family.SCOPES == JAMBA_SCOPES

@@ -70,7 +70,8 @@ def _kernels(hlo_text):
 # head_dim 64, 128 and 256 (qwen3_next's full-attention layer: 16 query and
 # 2 key-value heads), GQA but for olmo_hybrid's 30 heads with keys of their
 # own, up to nemotron_h's 16 query heads a key head and ouro's 16 with keys
-# of their own; causal, one windowed, one non-causal.
+# of their own, and jamba's 20 query heads on one key head; causal, one
+# windowed, one non-causal.
 FLASH_CASES = [
     pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
     pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
@@ -80,6 +81,7 @@ FLASH_CASES = [
     pytest.param(128, 30, 30, True, None, id="d128-h30k30-causal"),
     pytest.param(128, 32, 2, True, None, id="d128-h32k2-causal"),
     pytest.param(128, 16, 16, True, None, id="d128-h16k16-causal"),
+    pytest.param(128, 20, 1, True, None, id="d128-h20k1-causal"),
 ]
 
 
@@ -149,6 +151,54 @@ def test_causal_conv_kernels_compile_for_v5e(one_chip, shape, bias):
     assert kernels == {"causal_conv_fwd", "causal_conv_bwd"}
     assert "pad_convert_fusion" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+
+
+# the Mamba-1 mixers' selective scan at the jamba cell's shape and at two
+# that choose narrower tiles
+SCAN_CASES = [
+    pytest.param((1, 8192, 5120), 16, 512, id="jamba2-3b-t8192-d5120-n16"),
+    pytest.param((2, 1024, 640), 16, 128, id="b2-d640-tiles-of-128"),
+    pytest.param((1, 1024, 1024), 32, 256, id="d1024-n32-tiles-of-256"),
+]
+
+
+@pytest.mark.parametrize("shape, state, tile_c", SCAN_CASES)
+def test_selective_scan_kernels_compile_for_v5e(one_chip, shape, state,
+                                                tile_c):
+    """Forward under ``jax.checkpoint`` and its VJP at the tile the shape
+    chooses: two kernels (the forward twice), and no temporary but the
+    state a block of 128 tokens starts from, the two projections repeated
+    over 128 lanes and the float32 partial sums (XLA's code for the plain
+    path keeps float32 copies of x, delta and y and their cotangents)."""
+    from horovod_tpu.ops import selective_scan as ss
+
+    def spec(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    b, T, d = shape
+    assert ss.tiles(shape, state) == tile_c
+
+    def both(x, delta, A, B, C, D, dy):
+        y, back = jax.vjp(jax.checkpoint(
+            lambda *a: ss.kernel_selective_scan(*a, interpret=False)),
+            x, delta, A, B, C, D)
+        return y, back(dy)
+
+    compiled = jax.jit(both).lower(
+        spec(shape), spec(shape, jnp.float32), spec((d, state), jnp.float32),
+        spec((b, T, state)), spec((b, T, state)), spec((d,)),
+        spec(shape)).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(
+        r"%(?:jvp_)?(selective_scan_(?:fwd|bwd))[\w.]* = "
+        r"[^\n]*custom_call_target=\"tpu_custom_call\"", text))
+    assert kernels == {"selective_scan_fwd", "selective_scan_bwd"}
+    assert " while(" not in text
+    # saved states, B and C over the lanes (twice), dB and dC a tile
+    n_t, n_c = T // ss.TILE_T, d // tile_c
+    expected = (4 * b * n_t * state * d + 2 * 2 * 2 * b * T * state * 128
+                + 2 * 4 * b * n_c * state * T + 4 * b * (state + 8) * d)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * expected
 
 
 def test_engine_fused_allreduce_compiles_for_four_chips(hvd, mesh4):
@@ -426,5 +476,66 @@ def test_ouro2_6b_step_compiles_and_fits_as_recorded(one_chip, monkeypatch):
     assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
     assert abs(memory.temp_size_in_bytes
                - recorded["temp_bytes"]) < 0.02 * recorded["temp_bytes"]
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 16.9e9
+
+
+def test_jamba2_3b_step_compiles_and_fits_as_recorded(one_chip, monkeypatch):
+    """The training step of ``jamba2_3b-14l-spmd-1c`` at the cell's sizes (14
+    layers at the published widths, the tied 65536-row matrix whole, 8192
+    tokens; ``optax.adam`` in the distributed optimizer's place): it
+    compiles for the described v5e with the flash kernels at 20 query heads
+    on one key head, the convolution's kernels at 5120 channels and the
+    selective scan's, and its arguments and temporaries are what the
+    configuration file records, inside the 16.9 GB the runtime allows."""
+    import optax
+
+    from benchmark import cell as cells
+    from benchmark.families import jamba as family
+    from benchmark.reference import jamba as data
+    from horovod_tpu.models import jamba
+    from horovod_tpu.ops import causal_conv, flash_attention, selective_scan
+
+    # the default backend here is the CPU's: without these the Pallas
+    # kernels are interpreted or left out, not compiled for the described chip
+    for module in (flash_attention, causal_conv, selective_scan):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+    for module in (causal_conv, selective_scan):
+        monkeypatch.setattr(module, "kernel_enabled", lambda: True)
+    cell = cells.load_cell("jamba2_3b-14l-spmd-1c")
+    sizes = dict(cell.sizes, use_flash=True)
+    cfg = family.config_of(sizes)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: data.init_weights(k, sizes),
+                            jax.random.PRNGKey(0))
+    adam = data.ADAM
+    optimizer = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
+                           eps=adam["eps"])
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32,
+        sharding=one_chip)
+    compiled = jax.jit(
+        jamba.make_train_step(cfg, optimizer), donate_argnums=(0, 1)).lower(
+            at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+            tokens).compile()
+    kernels = set(re.findall(
+        r"(flash_fwd|flash_bwd_dq|flash_bwd_dkv|causal_conv_fwd|"
+        r"causal_conv_bwd|selective_scan_fwd|selective_scan_bwd)[\w.]* = "
+        r"[^\n]*custom_call_target=\"tpu_custom_call\"",
+        compiled.as_text()))
+    assert len(kernels) == 7
+    memory = compiled.memory_analysis()
+    recorded = cell.config["memory_analysis"]
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        params)) == 1_598_556_096
+    # weights and two moments, 6 bytes a parameter, all donated
+    assert abs(memory.argument_size_in_bytes
+               - recorded["argument_bytes"]) < 1e6
+    assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
+    assert abs(memory.temp_size_in_bytes
+               - recorded["sandbox_temp_bytes"]) < 0.02 * recorded[
+                   "sandbox_temp_bytes"]
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16.9e9

@@ -47,6 +47,7 @@ GEOMETRIES = {
     "nemotron3super-11l-spmd-1c": dict(B=1, T=8192, H=32, K=2, D=128,
                                        window=0),
     "qwen3next-4l-spmd-1c": dict(B=2, T=8192, H=16, K=2, D=256, window=0),
+    "jamba2_3b-14l-spmd-1c": dict(B=1, T=8192, H=20, K=1, D=128, window=0),
 }
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
