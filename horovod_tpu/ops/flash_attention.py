@@ -8,7 +8,10 @@ online-softmax accumulator whose row statistics stay lane-replicated, in
 the layout the score block's reductions leave them.  The backward pass
 recomputes probabilities blockwise from the saved logsumexp — two kernels
 (dq; dk/dv) so every accumulator lives in VMEM scratch across the inner
-grid dimension.
+grid dimension.  Where the mask takes whole blocks out (causal, a window)
+that inner dimension is no dense row of blocks but the steps of a schedule
+made at trace time (:func:`block_schedule`): the blocks in which the mask
+keeps an element, so a block that computes nothing costs no grid step.
 
 Layout: ``[B, T, H, D]`` (the llama layout).  GQA is native: pass kv with
 ``K = H / rep`` heads and each q-head group reads its shared kv head
@@ -26,9 +29,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import trace
 from ..compat import tpu_compiler_params
 
 NEG_INF = -1e30
@@ -95,26 +100,199 @@ def flash_enabled(seq: Optional[int] = None,
     return seq is None or seq >= flash_min_seq(causal)
 
 
+# ---------------------------------------------------------------- schedule
+# What a step of the schedule is: the FIRST and the LAST step of its row
+# (where the accumulators start and where the row is written) and a LIVE
+# block to compute.  A row with no live block keeps one step that is FIRST
+# and LAST alone and writes zeros.
+FIRST, LAST, LIVE = 1, 2, 4
+
+# The steps of a list that scalar memory is asked to hold: a word a step,
+# three quarters of a v5e's 1 MiB (Mosaic refuses a kernel past 1 MiB with
+# RESOURCE_EXHAUSTED).  A longer list is not made: see :class:`_Walk`.
+MAX_LIST = 3 * 2 ** 16
+
+
+def _live(q_start, k_start, *, Tq, Tk, block_q, block_k, causal, window):
+    """Whether the mask keeps an element of the block that starts at
+    ``(q_start, k_start)``: not whole above the diagonal (causal), not
+    whole below the window's band, the padded tail left out.  Plain
+    operators, so NumPy arrays (the schedule) and a kernel's scalars (the
+    dense grid) both pass through."""
+    if not causal:
+        return True
+    live = (k_start <= q_start + block_q - 1) & (k_start <= Tq - 1)
+    if window:
+        live &= ((k_start + block_k - 1 > q_start - window)
+                 & (Tk - 1 > q_start - window))
+    return live
+
+
+def block_schedule(Tq, Tk, block_q, block_k, causal, window=0, rep=1,
+                   by_k=False):
+    """The steps a kernel walks, made at trace time from what the call
+    sees: ``(rows, cols, flags)``, three ``int32`` arrays a step.
+
+    Only live blocks (:func:`_live`) are steps, so a block that computes
+    nothing is no grid step and fetches nothing.  (The rule is exact: the
+    dense grid's test kept a few blocks more, all of them masked whole —
+    one whose last column is the first the window drops, one whose columns
+    inside the band are padding.  Such a block added zeros.)
+    ``flash_fwd`` and ``flash_bwd_dq`` walk a q block's k blocks ascending
+    (``rows`` = ``qi``, ``cols`` = ``ki``); ``flash_bwd_dkv`` (``by_k``) a
+    k block's ``t = r * n_q + qi`` ascending over the ``rep`` q-heads of
+    its group (``rows`` = ``ki``, ``cols`` = ``t``): the order in which a
+    dense grid reached them, so every accumulator sums in that order."""
+    n_q, n_k = -(-Tq // block_q), -(-Tk // block_k)
+    live = np.broadcast_to(_live(
+        np.arange(n_q)[:, None] * block_q, np.arange(n_k)[None, :] * block_k,
+        Tq=Tq, Tk=Tk, block_q=block_q, block_k=block_k, causal=causal,
+        window=window), (n_q, n_k))
+    if by_k:
+        live = np.tile(live.T, (1, rep))
+    steps = live.copy()
+    steps[~live.any(axis=1), 0] = True
+    rows, cols = np.nonzero(steps)
+    flags = np.where(live[rows, cols], LIVE, 0)
+    turn = np.flatnonzero(np.diff(rows))
+    flags[np.r_[0, turn + 1]] |= FIRST
+    flags[np.r_[turn, -1]] |= LAST
+    return tuple(x.astype(np.int32) for x in (rows, cols, flags))
+
+
+class _Walk:
+    """How a kernel call's index maps and bodies find the blocks of a step.
+
+    The grid after the head's dimension is ``grid``; ``at`` is what follows
+    the head in an index map's arguments (the step's place in ``grid``, then
+    the refs of ``operands``), and ``blocks(*at)`` / ``flags(*at)`` read it.
+
+    * Where the mask takes blocks out, ``grid`` is the steps of
+      :func:`block_schedule` and ``operands`` its list in scalar memory, a
+      word a step: flags, column, row (``flash_bwd_dkv``'s column ``t`` as
+      ``r`` and ``qi`` apart, so no map divides).
+    * Where every block is live (a non-causal call; one block) the list
+      would be the whole grid: ``grid`` is ``(rows, columns)``, a step's
+      blocks are its place, and nothing is read.  (A step that reads its
+      entry costs the forward 0.05 us, ``dq`` and ``dkv`` 0.10 more than
+      one that does not: PERF.md section 6, PR 44.)  The same walk,
+      with :func:`_live` as a step's test, takes a list of more than
+      ``MAX_LIST`` steps: such a call runs its dead steps, as every call
+      did before the schedule."""
+
+    def __init__(self, Tq, Tk, block_q, block_k, causal, window, rep=1,
+                 by_k=False):
+        n_q, n_k = -(-Tq // block_q), -(-Tk // block_k)
+        rows, cols, flags = block_schedule(Tq, Tk, block_q, block_k, causal,
+                                           window, rep, by_k)
+        self.n_q, self.by_k = n_q, by_k
+        self.block_q, self.block_k = block_q, block_k
+        self.q_bits = int(n_q - 1).bit_length()
+        if by_k:
+            cols = (cols // n_q) << self.q_bits | cols % n_q
+        self.col_bits = int(cols.max()).bit_length()
+        self.listed = (len(flags) < rep * n_q * n_k
+                       and len(flags) <= MAX_LIST and 3 + self.col_bits
+                       + int(rows.max()).bit_length() <= 31)
+        if self.listed:
+            self.grid = (len(flags),)
+            self.operands = (jnp.asarray(
+                flags | cols << 3 | rows << (3 + self.col_bits)),)
+        else:
+            self.grid = (n_k, rep * n_q) if by_k else (n_q, n_k)
+            self.operands = ()
+            self.live = functools.partial(
+                _live, Tq=Tq, Tk=Tk, block_q=block_q, block_k=block_k,
+                causal=causal, window=window)
+        trace.flash_blocks["grid"] += rep * n_q * n_k
+        trace.flash_blocks["steps"] += int(np.prod(self.grid))
+
+    @property
+    def semantics(self):
+        return tpu_compiler_params(dimension_semantics=(
+            "parallel",) * len(self.grid) + ("arbitrary",))
+
+    def split(self, refs):
+        """A kernel's refs as (``at`` of this step, the refs after the
+        walk's own)."""
+        n = len(self.operands)
+        here = tuple(pl.program_id(1 + d) for d in range(len(self.grid)))
+        return here + refs[:n], refs[n:]
+
+    def blocks(self, *at):
+        """``(qi, ki, r)`` of the step at ``at``: its q block, its k block,
+        and (``by_k``) its q-head within the kv head's group."""
+        if self.listed:
+            step, words = at
+            row = words[step] >> (3 + self.col_bits)
+            col = (words[step] >> 3) & ((1 << self.col_bits) - 1)
+            if not self.by_k:
+                return row, col, 0
+            return col & ((1 << self.q_bits) - 1), row, col >> self.q_bits
+        row, col = at
+        if not self.by_k:
+            return row, col, 0
+        return col % self.n_q, row, col // self.n_q
+
+    def flags(self, *at):
+        """``(first, live, last)`` of the step at ``at``."""
+        if self.listed:
+            step, words = at
+            word = words[step]
+            return word & FIRST != 0, word & LIVE != 0, word & LAST != 0
+        qi, ki, _ = self.blocks(*at)
+        return (at[1] == 0, self.live(qi * self.block_q, ki * self.block_k),
+                at[1] == self.grid[1] - 1)
+
+
+def _mask(s, q_start, k_start, *, causal, window, tq_valid, tk_valid):
+    """A block's scores with ``NEG_INF`` where the pair is out: a padded
+    row or column, above the diagonal, below the window.  (In most blocks
+    of a schedule the mask is all true; building it there reads no slower
+    on the chip than a second body without it: PERF.md section 6, PR 44.)"""
+    block_q, block_k = s.shape
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    terms = []
+    if tk_valid % block_k:
+        terms.append(cols < tk_valid)
+    if tq_valid % block_q:
+        terms.append(rows < tq_valid)
+    if causal:
+        terms.append(rows >= cols)
+        if window:
+            terms.append(rows - cols < window)
+    if not terms:       # non-causal and no padded tail: nothing to mask
+        return s
+    return jnp.where(functools.reduce(jnp.logical_and, terms), s, NEG_INF)
+
+
+def _step(walk, at, block, *, first, last):
+    """One step of a kernel: ``first()`` where its row starts, ``block()``
+    for a live block, ``last()`` where the row ends."""
+    is_first, is_live, is_last = walk.flags(*at)
+    pl.when(is_first)(first)
+    pl.when(is_live)(block)
+    pl.when(is_last)(last)
+
+
 # ----------------------------------------------------------------- forward
 LANES = 128     # of a vector register: the row statistics' scratch width
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
-                n_k, tk_valid, window):
-    """Online softmax over the k-blocks of one (head, q-block).  The running
-    max ``m`` and sum ``l`` live as ``[block_q, LANES]`` scratch, a row's
-    value in every lane: that is the layout a reduction along the lanes of
-    the ``[block_q, block_k]`` scores leaves behind, so nothing between the
-    two products changes orientation (as ``(block_q,)`` vectors each block
-    paid four relayouts through VMEM, 2.9 us a live block where this form
-    takes 1.1: PERF.md section 6, PR 41)."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _fwd_kernel(*refs, walk, scale, block_q, block_k, **edges):
+    """Online softmax over the live k-blocks of one (head, q-block).  The
+    running max ``m`` and sum ``l`` live as ``[block_q, LANES]`` scratch, a
+    row's value in every lane: that is the layout a reduction along the
+    lanes of the ``[block_q, block_k]`` scores leaves behind, so nothing
+    between the two products changes orientation (as ``(block_q,)`` vectors
+    each block paid four relayouts through VMEM, 2.9 us a live block where
+    this form takes 1.1: PERF.md section 6, PR 41)."""
+    at, (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+         l_ref) = walk.split(refs)
     head_dim = acc_ref.shape[1]
 
-    @pl.when(ki == 0)
-    def _():
+    def first():
         # Half of the mask's NEG_INF: a live block that masks a row whole
         # then leaves it p = exp(NEG_INF - m) = 0, l = 0 (from NEG_INF
         # itself p would be exp(0) = 1 and the row would average v).
@@ -130,19 +308,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             return pltpu.repeat(stat, width // LANES, axis=1)
         return stat[:, :1]
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    # Causal: skip k-blocks strictly above the diagonal band; a sliding
-    # window additionally skips blocks entirely BELOW the band (the
-    # Mistral-style O(T·W) compute shape — whole blocks outside
-    # [r-window+1, r] never touch the MXU).
-    live = (not causal) or (k_start <= q_start + block_q - 1)
-    if window:
-        live = jnp.logical_and(live,
-                               k_start + block_k > q_start - window)
-
-    @pl.when(live)
-    def _():
+    def block():
         # Dots take the RAW input dtype (bf16 in training) with an f32
         # accumulator: bf16×bf16 products are exact in f32 accumulation,
         # so this matches the old cast-to-f32-first numerics while running
@@ -152,16 +318,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [bq, bk]
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = cols < tk_valid
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, rows >= cols)
-            if window:
-                mask = jnp.logical_and(mask, rows - cols < window)
-        s = jnp.where(mask, s, NEG_INF)
+        qi, ki, _ = walk.blocks(*at)
+        s = _mask(s, qi * block_q, ki * block_k, **edges)
 
         m_prev = m_ref[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -177,8 +335,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                           preferred_element_type=jnp.float32))
         m_ref[:] = m_new
 
-    @pl.when(ki == n_k - 1)
-    def _():
+    def last():
         l = l_ref[:]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[:] / across(safe_l, head_dim)
@@ -190,27 +347,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse = jnp.where(l == 0.0, 0.0, m_ref[:] + jnp.log(safe_l))
         lse_ref[0] = lse[:, :1]
 
+    _step(walk, at, block, first=first, last=last)
+
 
 # ---------------------------------------------------------------- backward
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, causal, block_q, block_k, n_k,
-               tq_valid, tk_valid, window):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+def _dq_kernel(*refs, walk, scale, block_q, block_k, **edges):
+    at, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+         acc_ref) = walk.split(refs)
 
-    @pl.when(ki == 0)
-    def _():
+    def first():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    live = (not causal) or (k_start <= q_start + block_q - 1)
-    if window:
-        live = jnp.logical_and(live,
-                               k_start + block_k > q_start - window)
-
-    @pl.when(live)
-    def _():
+    def block():
         # Raw-dtype MXU operands + f32 accumulators (see _fwd_kernel): the
         # f32 intermediates p/ds are quantized back to the operand dtype
         # for their second matmuls.
@@ -220,16 +368,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         do = do_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = jnp.logical_and(cols < tk_valid, rows < tq_valid)
-        if causal:
-            mask = jnp.logical_and(mask, rows >= cols)
-            if window:
-                mask = jnp.logical_and(mask, rows - cols < window)
-        s = jnp.where(mask, s, NEG_INF)
+        qi, ki, _ = walk.blocks(*at)
+        s = _mask(s, qi * block_q, ki * block_k, **edges)
         p = jnp.exp(s - lse_ref[0, :, :1])        # [bq, bk]
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -238,32 +378,21 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == n_k - 1)
-    def _():
+    def last():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
+    _step(walk, at, block, first=first, last=last)
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                block_q, block_k, n_q, n_t, tq_valid, tk_valid, window):
-    ki = pl.program_id(1)
-    t = pl.program_id(2)      # = r * n_q + qi over the rep q-heads (GQA)
-    qi = t % n_q
 
-    @pl.when(t == 0)
-    def _():
+def _dkv_kernel(*refs, walk, scale, block_q, block_k, **edges):
+    at, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc, dv_acc) = walk.split(refs)
+
+    def first():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    live = (not causal) or (k_start <= q_start + block_q - 1)
-    if window:
-        live = jnp.logical_and(live,
-                               k_start + block_k > q_start - window)
-
-    @pl.when(live)
-    def _():
+    def block():
         # Raw-dtype MXU operands + f32 accumulators (see _fwd_kernel).
         q = q_ref[0]
         k = k_ref[0]
@@ -271,16 +400,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        cols = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        rows = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        mask = jnp.logical_and(cols < tk_valid, rows < tq_valid)
-        if causal:
-            mask = jnp.logical_and(mask, rows >= cols)
-            if window:
-                mask = jnp.logical_and(mask, rows - cols < window)
-        s = jnp.where(mask, s, NEG_INF)
+        qi, ki, _ = walk.blocks(*at)
+        s = _mask(s, qi * block_q, ki * block_k, **edges)
         p = jnp.exp(s - lse_ref[0, :, :1])        # [bq, bk]
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -292,13 +413,25 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)      # [bk, D]
 
-    @pl.when(t == n_t - 1)
-    def _():
+    def last():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    _step(walk, at, block, first=first, last=last)
+
 
 # -------------------------------------------------------------- dispatcher
+def _by_q(walk):
+    """Index map of a q-side block at a step of a q block's walk."""
+    return lambda b, *at: (b, walk.blocks(*at)[0], 0)
+
+
+def _kv_of_q(walk, rep):
+    """Index map of the k or v block at a step of a q block's walk:
+    ``rep`` consecutive q-heads read one kv head."""
+    return lambda b, *at: (b // rep, walk.blocks(*at)[1], 0)
+
+
 def _pad_t(x, block):
     t = x.shape[1]
     pad = (-t) % block
@@ -316,41 +449,42 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
     Tk = k.shape[1]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     qp, kp, vp = _pad_t(q, bq), _pad_t(k, bk), _pad_t(v, bk)
-    Tqp, Tkp = qp.shape[1], kp.shape[1]
-    n_q, n_k = Tqp // bq, Tkp // bk
+    Tqp = qp.shape[1]
 
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             block_q=bq, block_k=bk, n_k=n_k, tk_valid=Tk,
-                             window=window)
+    walk = _Walk(Tq, Tk, bq, bk, causal, window)
+    by_q, kv_of_q = _by_q(walk), _kv_of_q(walk, rep)
     o, lse = pl.pallas_call(
-        kern,
+        functools.partial(_fwd_kernel, walk=walk, scale=scale, block_q=bq,
+                          block_k=bk, causal=causal, window=window,
+                          tq_valid=Tq, tk_valid=Tk),
         name="flash_fwd",
-        grid=(BH, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // rep, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // rep, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            # 3D (1, bq, 1): TPU block rules need the trailing dims
-            # divisible by (8, 128) or equal to the array's — a [BH, T]
-            # row vector can't satisfy that, [BH, T, 1] can.
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.operands),
+            grid=(BH,) + walk.grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, D), by_q),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bq, D), by_q),
+                # 3D (1, bq, 1): TPU block rules need the trailing dims
+                # divisible by (8, 128) or equal to the array's — a [BH, T]
+                # row vector can't satisfy that, [BH, T, 1] can.
+                pl.BlockSpec((1, bq, 1), by_q),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+                pltpu.VMEM((bq, LANES), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
             jax.ShapeDtypeStruct((BH, Tqp, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, D), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-            pltpu.VMEM((bq, LANES), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=walk.semantics,
         interpret=interpret,
-    )(qp, kp, vp)
+    )(*walk.operands, qp, kp, vp)
     return o[:, :Tq], lse[:, :Tq, 0]
 
 
@@ -477,64 +611,73 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
     lsep = jnp.pad(lse, ((0, 0), (0, pad_q)))[..., None]
     deltap = jnp.pad(delta, ((0, 0), (0, pad_q)))[..., None]
     Tqp, Tkp = qp.shape[1], kp.shape[1]
-    n_q, n_k = Tqp // bq, Tkp // bk
 
+    edges = dict(causal=causal, window=window, tq_valid=Tq, tk_valid=Tk)
+
+    walk = _Walk(Tq, Tk, bq, bk, causal, window)
+    by_q, kv_of_q = _by_q(walk), _kv_of_q(walk, rep)
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_k=n_k,
-                          tq_valid=Tq, tk_valid=Tk, window=window),
+        functools.partial(_dq_kernel, walk=walk, scale=scale, block_q=bq,
+                          block_k=bk, **edges),
         name="flash_bwd_dq",
-        grid=(BH, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // rep, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, i, j: (b // rep, j, 0)),
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.operands),
+            grid=(BH,) + walk.grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, D), by_q),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, bq, D), by_q),
+                pl.BlockSpec((1, bq, 1), by_q),
+                pl.BlockSpec((1, bq, 1), by_q),
+            ],
+            out_specs=pl.BlockSpec((1, bq, D), by_q),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=walk.semantics,
         interpret=interpret,
-    )(qp, kp, vp, dop, lsep, deltap)[:, :Tq]
+    )(*walk.operands, qp, kp, vp, dop, lsep, deltap)[:, :Tq]
 
-    # dk/dv accumulate over the rep q-heads sharing each kv head: grid is
-    # (B*K, n_k, rep*n_q) and the q-side index map walks head r = t // n_q,
-    # block qi = t % n_q of the kv head's group.
-    def _qix(b, j, t):
-        return (b * rep + t // n_q, t % n_q, 0)
+    # dk/dv accumulate over the rep q-heads sharing each kv head: a k block's
+    # steps walk t = r * n_q + qi, and the q-side index map reads block qi
+    # of head r of the kv head's group.
+    walk = _Walk(Tq, Tk, bq, bk, causal, window, rep, by_k=True)
+
+    def q_of_k(b, *at):
+        qi, _, r = walk.blocks(*at)
+        return (b * rep + r, qi, 0)
+
+    def by_k(b, *at):
+        return (b, walk.blocks(*at)[1], 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_q=n_q, n_t=rep * n_q,
-                          tq_valid=Tq, tk_valid=Tk, window=window),
+        functools.partial(_dkv_kernel, walk=walk, scale=scale, block_q=bq,
+                          block_k=bk, **edges),
         name="flash_bwd_dkv",
-        grid=(BK, n_k, rep * n_q),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), _qix),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0)),
-            pl.BlockSpec((1, bq, D), _qix),
-            pl.BlockSpec((1, bq, 1), _qix),
-            pl.BlockSpec((1, bq, 1), _qix),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(walk.operands),
+            grid=(BK,) + walk.grid,
+            in_specs=[
+                pl.BlockSpec((1, bq, D), q_of_k),
+                pl.BlockSpec((1, bk, D), by_k),
+                pl.BlockSpec((1, bk, D), by_k),
+                pl.BlockSpec((1, bq, D), q_of_k),
+                pl.BlockSpec((1, bq, 1), q_of_k),
+                pl.BlockSpec((1, bq, 1), q_of_k),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bk, D), by_k),
+                pl.BlockSpec((1, bk, D), by_k),
+            ],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((BK, Tkp, D), k.dtype),
             jax.ShapeDtypeStruct((BK, Tkp, D), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=walk.semantics,
         interpret=interpret,
-    )(qp, kp, vp, dop, lsep, deltap)
+    )(*walk.operands, qp, kp, vp, dop, lsep, deltap)
     return dq, dk[:, :Tk], dv[:, :Tk]
 
 

@@ -302,6 +302,12 @@ causal_conv = {"kernel": 0, "plain": 0}
 # ``plain`` rising on a TPU is a shape the tiles do not fit.
 selective_scan = {"kernel": 0, "plain": 0}
 
+# The flash attention kernels' block schedules (``ops/flash_attention.py``
+# ``_schedule``), added up in Python once a traced kernel call, a head:
+# ``grid`` the blocks of the dense grid, ``steps`` the steps the schedule
+# keeps of it (a causal call a little over half).
+flash_blocks = {"grid": 0, "steps": 0}
+
 # The one table of the process-wide series: name -> (kind, help, read,
 # label).  ``read()`` gives a number, or with a ``label`` a dict from the
 # label's value to a number (``hvd_startup_seconds{phase="hvd/init"}``).
@@ -338,6 +344,9 @@ _register_counts("hvd_causal_conv", causal_conv, {
 _register_counts("hvd_selective_scan", selective_scan, {
     "kernel": "selective scan call sites traced as the Pallas kernels",
     "plain": "selective scan call sites traced as XLA's own code"})
+_register_counts("hvd_flash_blocks", flash_blocks, {
+    "grid": "blocks a head of the dense grids of traced flash kernel calls",
+    "steps": "steps a head the flash kernels' schedules keep of those grids"})
 
 
 def span(name: str, **ids):

@@ -246,6 +246,205 @@ def test_flash_forward_output_and_logsumexp(D, rep, causal, window, Tq, Tk,
     assert np.all(np.asarray(o)[:, empty] == 0.0)
 
 
+# (Tq, Tk, tiles, causal, window, rep): the six cells' attention layers, then
+# what the small shapes reach — a padded tail, a window's edge inside a
+# block and across blocks, odd windows (the band's last column the first of
+# a block), more queries than keys under a window (q rows with no live
+# block), more keys than queries (k rows with none), cross attention, a
+# grid with no mask at all.
+SCHEDULES = [
+    pytest.param(8192, 8192, (512, 512), True, 0, 1, id="ouro2_6b-16l"),
+    pytest.param(16384, 16384, (512, 512), True, 0, 1,
+                 id="olmo-hybrid-7b-4l"),
+    pytest.param(4096, 4096, (512, 512), True, 4096, 4, id="mistral7b-4l"),
+    pytest.param(8192, 8192, (512, 512), True, 0, 16,
+                 id="nemotron3-super-11l"),
+    pytest.param(8192, 8192, (512, 512), True, 0, 8, id="qwen3next-4l"),
+    pytest.param(8192, 8192, (512, 512), True, 0, 20, id="jamba2-3b-14l"),
+    pytest.param(4096, 4096, (512, 512), True, 1024, 4, id="window1024"),
+    pytest.param(3000, 3000, (512, 512), True, 0, 20, id="t3000-padded"),
+    pytest.param(70, 70, (32, 32), True, 0, 4, id="t70-padded"),
+    pytest.param(70, 70, (16, 32), True, 8, 1, id="t70-window8-tiles16x32"),
+    pytest.param(64, 64, (16, 16), True, 40, 1, id="t64-window40"),
+    pytest.param(64, 64, (32, 16), True, 16, 2, id="t64-window16-tiles32x16"),
+    pytest.param(64, 64, (16, 16), True, 1, 2, id="t64-window1"),
+    pytest.param(64, 64, (16, 16), True, 17, 1, id="t64-window17"),
+    pytest.param(70, 70, (16, 32), True, 33, 4,
+                 id="t70-window33-tiles16x32"),
+    pytest.param(4096, 4096, (512, 512), True, 1025, 1, id="window1025"),
+    pytest.param(40, 16, (16, 16), True, 8, 1,
+                 id="window8-rows-without-a-key"),
+    pytest.param(45, 17, (16, 16), True, 9, 2,
+                 id="window9-padding-inside-the-band"),
+    pytest.param(50, 17, (16, 16), True, 0, 1, id="causal-tq50-tk17"),
+    pytest.param(17, 50, (16, 16), True, 0, 2, id="causal-tq17-tk50"),
+    pytest.param(17, 50, (16, 16), False, 0, 4, id="cross-tq17-tk50"),
+    pytest.param(64, 64, (32, 32), False, 0, 1, id="full-t64-no-mask"),
+    pytest.param(33, 33, (16, 16), False, 0, 1, id="full-t33-padded"),
+]
+
+
+def _block_masks(Tq, Tk, bq, bk, causal, window):
+    """The kernels' mask by brute force, a block at a time:
+    ``[n_q, n_k, bq, bk]``."""
+    n_q, n_k = -(-Tq // bq), -(-Tk // bk)
+    rows = np.arange(n_q * bq)[:, None]
+    cols = np.arange(n_k * bk)[None]
+    mask = (cols < Tk) & (rows < Tq)
+    if causal:
+        mask = mask & (rows >= cols)
+        if window:
+            mask = mask & (rows - cols < window)
+    return mask.reshape(n_q, bq, n_k, bk).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("by_k", [False, True], ids=["by_q", "by_k"])
+@pytest.mark.parametrize("Tq,Tk,blocks,causal,window,rep", SCHEDULES)
+def test_block_schedule(Tq, Tk, blocks, causal, window, rep, by_k):
+    """The schedule alone: its live blocks are exactly those in which a
+    brute-force mask keeps an element (every one a block the dense grid's
+    ``live`` rule kept), each once, rows contiguous and in the dense
+    grid's order, first / last flags on a row's ends, and one step that
+    computes nothing for an output row with no live block."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    bq, bk = blocks
+    n_q, n_k = -(-Tq // bq), -(-Tk // bk)
+    kept = _block_masks(Tq, Tk, bq, bk, causal, window).any(axis=(2, 3))
+
+    def dense_rule(qi, ki):             # the dense kernels' own test
+        ok = (not causal) or ki * bk <= qi * bq + bq - 1
+        if window:
+            ok = ok and ki * bk + bk > qi * bq - window
+        return ok
+
+    assert all(dense_rule(qi, ki) for qi, ki in zip(*np.nonzero(kept)))
+
+    rows, cols, flags = fa.block_schedule(Tq, Tk, bq, bk, causal, window,
+                                          rep if by_k else 1, by_k=by_k)
+    assert rows.dtype == cols.dtype == flags.dtype == np.int32
+    n_rows, n_cols = (n_k, rep * n_q) if by_k else (n_q, n_k)
+    pair = (lambda r, c: (c % n_q, r)) if by_k else (lambda r, c: (r, c))
+    at = 0
+    for r in range(n_rows):
+        live_cols = [c for c in range(n_cols) if kept[pair(r, c)]]
+        steps = live_cols or [0]        # a row with no live block: one step
+        here = slice(at, at + len(steps))
+        assert list(rows[here]) == [r] * len(steps)
+        assert list(cols[here]) == steps
+        want = np.full(len(steps), fa.LIVE if live_cols else 0)
+        want[0] |= fa.FIRST
+        want[-1] |= fa.LAST
+        assert list(flags[here]) == list(want)
+        at += len(steps)
+    assert at == len(flags)
+
+
+@pytest.mark.parametrize("Tq,Tk,blocks,causal,window,rep,dtype", [
+    pytest.param(70, 70, (16, 32), True, 0, 1, jnp.float32,
+                 id="rep1-causal-padded"),
+    pytest.param(64, 64, (16, 16), True, 0, 4, jnp.bfloat16,
+                 id="rep4-causal-bf16"),
+    pytest.param(64, 64, (16, 16), True, 0, 20, jnp.float32,
+                 id="rep20-causal"),
+    pytest.param(70, 70, (16, 16), True, 40, 4, jnp.float32,
+                 id="rep4-window40-padded"),
+    pytest.param(64, 64, (16, 16), True, 17, 1, jnp.bfloat16,
+                 id="rep1-window17-bf16"),
+    pytest.param(64, 64, (16, 16), True, 1, 20, jnp.float32,
+                 id="rep20-window1"),
+    pytest.param(40, 16, (16, 16), True, 8, 1, jnp.float32,
+                 id="rep1-window8-rows-without-a-key"),
+    pytest.param(45, 17, (16, 16), True, 9, 2, jnp.float32,
+                 id="rep2-window9-padding-inside-the-band"),
+    pytest.param(50, 17, (16, 16), True, 0, 4, jnp.float32,
+                 id="rep4-causal-tq50-tk17"),
+    pytest.param(17, 50, (16, 16), True, 0, 4, jnp.float32,
+                 id="rep4-causal-tq17-tk50"),
+])
+def test_flash_dense_grid_gives_the_same_bits(
+        monkeypatch, Tq, Tk, blocks, causal, window, rep, dtype):
+    """A list too long for scalar memory is not made: the kernels then walk
+    the dense grid with ``_live`` as each step's test (what every call did
+    before the schedule), and ``o``, ``lse``, ``dq``, ``dk`` and ``dv``
+    equal the list's bit for bit — same blocks, same order."""
+    from horovod_tpu import trace
+    from horovod_tpu.ops import flash_attention as fa
+
+    K, D = 2, 64
+    rng = np.random.RandomState(Tq + 3 * Tk + 5 * window + 7 * rep)
+    q, do = (jnp.asarray(rng.randn(K * rep, Tq, D), dtype) for _ in "ab")
+    k, v = (jnp.asarray(rng.randn(K, Tk, D), dtype) for _ in "ab")
+    scale = D ** -0.5
+
+    def five():
+        before = dict(trace.flash_blocks)
+        o, lse = fa._fwd_impl(q, k, v, scale, causal, *blocks, True, rep,
+                              window)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), -1)
+        out = (o, lse) + tuple(fa._bwd_impl(
+            q, k, v, do, lse, delta, scale=scale, causal=causal,
+            block_q=blocks[0], block_k=blocks[1], interpret=True, rep=rep,
+            window=window))
+        return out, {key: trace.flash_blocks[key] - n
+                     for key, n in before.items()}
+
+    want, listed = five()
+    monkeypatch.setattr(fa, "MAX_LIST", 0)
+    got, dense = five()
+    assert listed["steps"] < listed["grid"] == dense["grid"] == dense["steps"]
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and np.array_equal(
+            np.asarray(a.astype(jnp.float32)),
+            np.asarray(b.astype(jnp.float32))), name
+
+
+def test_flash_blocks_counts_grid_and_steps():
+    """``trace.flash_blocks``: a traced causal call at 16 x 16 blocks adds
+    a head's ``grid`` 256 and ``steps`` 136 a kernel; its gradient the
+    forward's and the two backward kernels', ``rep`` times that for
+    ``flash_bwd_dkv``; a non-causal call keeps its whole grid; and
+    ``/metrics`` carries the two series."""
+    from horovod_tpu import trace
+    from horovod_tpu.monitor.agent import MonitorAgent
+
+    class Engine:
+        monitor = None
+
+    def moved(fn, *shapes):
+        before = dict(trace.flash_blocks)
+        jax.eval_shape(fn, *(jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                             for s in shapes))
+        return {key: trace.flash_blocks[key] - n for key, n in before.items()}
+
+    attn = lambda causal: lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=causal, block_q=16, block_k=16)
+    one = (1, 256, 4, 64)
+    agent = MonitorAgent(engine=Engine())
+    try:
+        first = agent.registry.snapshot()
+        assert moved(attn(True), one, one, one) == {"grid": 256, "steps": 136}
+        assert moved(attn(False), one, one, one) == {
+            "grid": 256, "steps": 256}
+        grad = jax.grad(lambda q, k, v: attn(True)(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+        assert moved(grad, one, (1, 256, 2, 64), (1, 256, 2, 64)) == {
+            "grid": 4 * 256, "steps": 4 * 136}
+        second = agent.registry.snapshot()
+        text = agent.registry.to_prometheus('rank="0"')
+    finally:
+        agent.close()
+
+    def value(snap, name):
+        return snap[name]["value"] if isinstance(snap[name], dict) \
+            else snap[name]
+
+    for key, n in {"grid": 6 * 256, "steps": 5 * 136 + 256}.items():
+        name = f"hvd_flash_blocks_{key}_total"
+        assert value(second, name) - value(first, name) == n
+        assert name in text
+
+
 def _flash_sweep():
     """tools/flash_sweep.py as a module (``tools`` is no package)."""
     import importlib.util
@@ -259,16 +458,24 @@ def _flash_sweep():
     return module
 
 
-# A llama layer and the five decoder cells' attention layers as a step sees
-# them, from the table tools/flash_sweep.py times on the chip.
-CELL_GEOMETRIES = [pytest.param(1, 1024, 8, 4, 128, None,
+# A llama layer and the six decoder cells' attention layers as a step sees
+# them, from the table tools/flash_sweep.py times on the chip; then 20 query
+# heads on one key head with a padded tail, and a cross-attention shape.
+CELL_GEOMETRIES = [pytest.param(1, 1024, 1024, 8, 4, 128, True, None,
                                 id="llama-d128-h8k4-t1024")] + [
-    pytest.param(g["B"], g["T"], g["H"], g["K"], g["D"], g["window"] or None,
-                 id=cell) for cell, g in _flash_sweep().GEOMETRIES.items()]
+    pytest.param(g["B"], g["T"], g["T"], g["H"], g["K"], g["D"], True,
+                 g["window"] or None, id=cell)
+    for cell, g in _flash_sweep().GEOMETRIES.items()] + [
+    pytest.param(1, 3000, 3000, 20, 1, 128, True, None,
+                 id="h20k1-t3000-padded"),
+    pytest.param(2, 1024, 4096, 8, 2, 128, False, None,
+                 id="cross-tq1024-tk4096"),
+    pytest.param(1, 700, 1500, 12, 12, 64, False, None,
+                 id="cross-d64-tq700-tk1500-padded")]
 
 
-@pytest.mark.parametrize("B,T,H,K,D,window", CELL_GEOMETRIES)
-def test_flash_tpu_lowering(B, T, H, K, D, window):
+@pytest.mark.parametrize("B,Tq,Tk,H,K,D,causal,window", CELL_GEOMETRIES)
+def test_flash_tpu_lowering(B, Tq, Tk, H, K, D, causal, window):
     """Cross-platform lowering: the Mosaic/TPU pipeline runs client-side,
     so a CPU host can verify the kernels lower for TPU at real llama
     shapes and at each benchmark cell's heads, head width, sequence and
@@ -276,12 +483,12 @@ def test_flash_tpu_lowering(B, T, H, K, D, window):
     (tests/test_tpu_compile.py compiles them for a described v5e)."""
     def f(q, k, v):
         return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-            q, k, v, causal=True, window=window,
+            q, k, v, causal=causal, window=window,
             interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
-    spec_q = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16)
-    spec_kv = jax.ShapeDtypeStruct((B, T, K, D), jnp.bfloat16)  # GQA
+    spec_q = jax.ShapeDtypeStruct((B, Tq, H, D), jnp.bfloat16)
+    spec_kv = jax.ShapeDtypeStruct((B, Tk, K, D), jnp.bfloat16)  # GQA
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
         spec_q, spec_kv, spec_kv)
     assert exp.mlir_module().count("tpu_custom_call") == 3
@@ -539,14 +746,16 @@ def test_gpt2_uses_flash_when_forced(monkeypatch):
 
 
 def test_flash_sweep_counts_blocks_and_names_kernels():
-    """tools/flash_sweep.py's arithmetic: the live and dead blocks a head
-    by the kernels' own ``live`` test (120 of 256 grid steps dead at 8192
-    tokens), and a device event's kernel by its instruction's name."""
+    """tools/flash_sweep.py's arithmetic: a head's blocks of the dense
+    grid and the steps of the kernels' schedule (136 of 256 at 8192
+    tokens); and a device event's kernel by its instruction's name."""
     sweep = _flash_sweep()
-    assert sweep.blocks_of(8192, 512, 512, 0) == (136, 120)
-    assert sweep.blocks_of(16384, 512, 512, 0) == (528, 496)
-    assert sweep.blocks_of(4096, 512, 512, 4096) == (36, 28)
-    assert sweep.blocks_of(4096, 512, 512, 1024) == (21, 43)
+    assert sweep.blocks_of(8192, 512, 512, 0) == (256, 136)
+    assert sweep.blocks_of(16384, 512, 512, 0) == (1024, 528)
+    assert sweep.blocks_of(4096, 512, 512, 4096) == (64, 36)
+    assert sweep.blocks_of(4096, 512, 512, 1024) == (64, 21)
+    assert sweep.blocks_of(3000, 512, 512, 0) == (36, 21)
+    assert sweep.blocks_of(8192, 512, 512, 0, causal=False) == (256, 256)
     for event, kernel in [
             ("%flash_fwd.13 = (bf16[16,8192,128]{2,1,0}) custom-call(...)",
              "flash_fwd"),

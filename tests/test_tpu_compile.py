@@ -112,6 +112,38 @@ def test_flash_backward_compiles_for_v5e(one_chip, D, H, K, causal, window):
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
 
 
+@pytest.mark.parametrize("T,H,listed", [
+    pytest.param(65536, 20, True, id="t65536-h20k1-165120-steps"),
+    pytest.param(131072, 3, True, id="t131072-h3k1-98688-steps"),
+    pytest.param(65536, 32, False, id="t65536-h32k1-dense-grid"),
+])
+def test_flash_backward_compiles_whatever_its_steps(one_chip, T, H, listed):
+    """Scalar memory holds a word a step of a kernel's list, and Mosaic
+    refuses a kernel past its 1 MiB: ``flash_bwd_dkv``'s list (``H`` times
+    the forward's) compiles up to ``MAX_LIST`` steps, and a longer one is
+    not made — the kernel walks the dense grid, as it did before it had a
+    schedule, so no length fails to compile."""
+    from horovod_tpu import trace
+    from horovod_tpu.ops import flash_attention as fa
+
+    def grads(q, k, v, do, lse, delta):
+        return fa._bwd_impl(q, k, v, do, lse, delta, scale=0.088, causal=True,
+                            block_q=512, block_k=512, interpret=False, rep=H)
+
+    q = jax.ShapeDtypeStruct((H, T, 128), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, T, 128), jnp.bfloat16, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((H, T), jnp.float32, sharding=one_chip)
+    before = dict(trace.flash_blocks)
+    compiled = jax.jit(grads).lower(q, kv, kv, q, row, row).compile()
+    assert _kernels(compiled.as_text()) == ["flash_bwd_dkv", "flash_bwd_dq"]
+    n = T // 512
+    steps = trace.flash_blocks["steps"] - before["steps"]
+    assert trace.flash_blocks["grid"] - before["grid"] == (1 + H) * n * n
+    assert steps == (1 + (H if listed else 0)) * n * (n + 1) // 2 + (
+        0 if listed else H * n * n)
+    assert (H * n * (n + 1) // 2 <= fa.MAX_LIST) == listed
+
+
 # the recurrent mixers' convolution at the three hybrid cells' shapes
 # (four taps; ``mamba2``'s has a bias)
 CONV_CASES = [

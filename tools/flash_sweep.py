@@ -1,4 +1,4 @@
-"""On-chip sweep of the flash attention kernels at the benchmark's five
+"""On-chip sweep of the flash attention kernels at the benchmark's six
 attention geometries: each kernel alone, forward + backward, the tiles.
 
 Per geometry (a decoder cell's heads, head width, sequence and window, one
@@ -8,9 +8,14 @@ alone (``_fwd_impl``: ``flash_fwd``) and the two backward kernels alone
 time of the kernel's events, and on the host clock a jitted forward +
 backward of ``flash_attention`` (gradients of q, k and v: what a layer of a
 training step runs, without ``jax.checkpoint``'s second forward).  Beside
-each kernel: the time of a live block (0.35 us taken off for each dead grid
-step) and the share of the MXU's peak its products reach — the table of
-PERF.md section 6, PR 41, re-read in one chip call:
+each kernel: the steps of its schedule a head, the time of a step, the
+share of the MXU's peak its products reach, and the same shape non-causal
+(every block of the grid a step, a mask on the padded tail only: what a
+block costs without the causal mask, and on a kernel that walks the dense
+grid what splits a live block from a dead step).  A row
+also holds the SHA-256 of ``o``, ``lse``, ``dq``, ``dk`` and ``dv``: two
+commits whose digests agree computed the same bits.  The tables of PERF.md
+section 6, PRs 41 and 44, re-read in one chip call:
 
     python tools/flash_sweep.py --out chiprun_out/FLASH_SWEEP.json
 
@@ -25,6 +30,7 @@ import argparse
 import datetime
 import functools
 import glob
+import hashlib
 import json
 import os
 import re
@@ -37,6 +43,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # A cell's attention layer as one step sees it: batch, sequence, query and
 # key-value heads, head width, window (benchmark/configs, benchmark/traffic).
@@ -51,23 +58,17 @@ GEOMETRIES = {
 }
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
-DEAD_STEP_US = 0.35         # a grid step whose block is skipped
 # a device event's kernel: ``flash_fwd.3``, ``jvp_flash_fwd_.4`` under autodiff
 EVENT_KERNEL = re.compile(r"%?(?:jvp_)?(\w+?)_?(?:\.\d+)?(?: = |$)")
 
 
-def blocks_of(T, bq, bk, window):
-    """(live, dead) blocks a head of a causal kernel's grid: what the
-    kernels' own ``live`` test keeps."""
-    n_q, n_k = -(-T // bq), -(-T // bk)
-    live = 0
-    for i in range(n_q):
-        for j in range(n_k):
-            ok = j * bk <= i * bq + bq - 1
-            if window:
-                ok = ok and j * bk + bk > i * bq - window
-            live += ok
-    return live, n_q * n_k - live
+def blocks_of(T, bq, bk, window, causal=True):
+    """(grid, steps) a head: the blocks of the dense grid and the steps of
+    the kernels' schedule."""
+    from horovod_tpu.ops import flash_attention as fa
+
+    flags = fa.block_schedule(T, T, bq, bk, causal, window)[2]
+    return -(-T // bq) * -(-T // bk), len(flags)
 
 
 def kernel_events(trace_dir):
@@ -120,34 +121,47 @@ def inputs(g, seed=0):
 
 
 def per_kernel(g, bq=512, bk=512, reps=3):
-    """Each kernel alone at geometry ``g``: {kernel: {ms, live_block_us,
-    mxu_pct}}."""
+    """Each kernel alone at geometry ``g``, causal (with the cell's window)
+    and non-causal: ({kernel: {steps, ms, step_us, mxu_pct, full_ms,
+    full_step_us}}, {output: sha256 of the causal call's bytes})."""
     from benchmark import cell
     from horovod_tpu.ops import flash_attention as fa
 
     peak = cell.peaks_for(jax.devices()[0].device_kind)["bf16_flops_per_s"]
     q, k, v, do = inputs(g)
     rep, scale = g["H"] // g["K"], g["D"] ** -0.5
-    fwd = jax.jit(lambda q, k, v: fa._fwd_impl(
-        q, k, v, scale, True, bq, bk, False, rep, g["window"]))
-    o, lse = fwd(q, k, v)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    bwd = jax.jit(functools.partial(
-        fa._bwd_impl, scale=scale, causal=True, block_q=bq, block_k=bk,
-        interpret=False, rep=rep, window=g["window"]))
-    events = traced_ms([(fwd, (q, k, v)), (bwd, (q, k, v, do, lse, delta))],
-                       reps)
-    live, dead = blocks_of(g["T"], bq, bk, g["window"])
-    heads, out = g["B"] * g["H"], {}
+    heads = g["B"] * g["H"]
+
+    def alone(causal):
+        window = g["window"] if causal else 0
+        fwd = jax.jit(lambda q, k, v: fa._fwd_impl(
+            q, k, v, scale, causal, bq, bk, False, rep, window))
+        o, lse = fwd(q, k, v)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+        bwd = jax.jit(functools.partial(
+            fa._bwd_impl, scale=scale, causal=causal, block_q=bq, block_k=bk,
+            interpret=False, rep=rep, window=window))
+        events = traced_ms(
+            [(fwd, (q, k, v)), (bwd, (q, k, v, do, lse, delta))], reps)
+        return ({name: statistics.median(events[name]) for name in KERNELS},
+                (o, lse) + tuple(bwd(q, k, v, do, lse, delta)))
+
+    ms, outputs = alone(True)
+    full_ms, _ = alone(False)
+    grid, steps = blocks_of(g["T"], bq, bk, g["window"])
+    out = {}
     for name in KERNELS:
-        ms = statistics.median(events[name])
-        block_us = (ms * 1e3 - DEAD_STEP_US * dead * heads) / (live * heads)
         least_us = PRODUCTS[name] * 2 * bq * bk * g["D"] / peak * 1e6
-        out[name] = {"ms": round(ms, 4),
-                     "live_block_us": round(block_us, 4),
-                     "mxu_pct": round(
-                         100 * least_us * live * heads / (ms * 1e3), 2)}
-    return out
+        out[name] = {
+            "steps": steps, "ms": round(ms[name], 4),
+            "step_us": round(ms[name] * 1e3 / (steps * heads), 4),
+            "mxu_pct": round(
+                100 * least_us * steps * heads / (ms[name] * 1e3), 2),
+            "full_ms": round(full_ms[name], 4),
+            "full_step_us": round(full_ms[name] * 1e3 / (grid * heads), 4)}
+    return out, {name: hashlib.sha256(np.asarray(x).tobytes()).hexdigest()
+                 for name, x in zip(("o", "lse", "dq", "dk", "dv"), outputs)}
 
 
 def fwd_bwd_ms(g, attn, reps=5):
@@ -178,7 +192,8 @@ def sweep(geometries, blocks, xla=False, reps=3):
             candidates["xla"] = functools.partial(
                 local_flash_attention, causal=True, window=window)
         try:
-            row["kernels"] = per_kernel(g, *blocks[0], reps=reps)
+            row["kernels"], row["outputs_sha256"] = per_kernel(
+                g, *blocks[0], reps=reps)
         except Exception as exc:  # noqa: BLE001 — a tile Mosaic refuses
             row["errors"]["kernels"] = repr(exc)[:200]
         for key, attn in candidates.items():
@@ -215,8 +230,9 @@ def main():
     dev = jax.devices()[0]
     out = {
         "provenance": "tools/flash_sweep.py: causal attention, bf16; "
-                      "kernels alone by device trace (median event), "
-                      "forward + backward on the host clock",
+                      "kernels alone by device trace (median event; full_*: "
+                      "the same shape non-causal), forward + backward on "
+                      "the host clock",
         "captured_utc": datetime.datetime.now(
             datetime.timezone.utc).isoformat(),
         "device": {"kind": dev.device_kind, "platform": dev.platform},
