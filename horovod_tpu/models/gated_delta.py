@@ -9,7 +9,8 @@ and L2-normalised a head, q times ``k_dim ** -0.5``; ``beta = beta_scale *
 sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``; per head ``S <-
 exp(g_t) S + k_t (beta_t (v_t - S^T k_t))^T``, ``o_t = S^T q_t`` with ``S``
 ``k_dim x v_dim``, computed in the **chunked** form
-(:func:`chunked_gated_delta_rule`); ``o <- RMSNorm(o; w_out[v_dim]) *
+(:func:`chunked_gated_delta_rule`, or on a TPU where the widths fit the
+kernel pair of ``ops/delta_rule.py``); ``o <- RMSNorm(o; w_out[v_dim]) *
 SiLU(z)``; ``W_o``.
 
 ``beta_scale`` 1 keeps beta in (0, 1): ``I - beta k k^T`` then only shrinks
@@ -32,7 +33,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from .. import trace
-from ..ops import causal_conv
+from ..ops import causal_conv, delta_rule
 
 # the checkpoint name of a grouped delta rule's result (by_head_groups)
 RULE_OUTPUT = "gdn_rule_out"
@@ -105,7 +106,16 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
     solve and the state are float32; the matrix products take their
     operands in the inputs' type and accumulate in float32.  Plain JAX
     operations: the backward pass is autodiff's.  ``T`` need not be a
-    multiple of ``chunk``."""
+    multiple of ``chunk``.
+
+    This is the **plain branch** of the rule: :func:`gated_delta_net` takes
+    it wherever the kernel pair of ``ops/delta_rule.py`` does not engage —
+    any backend but a TPU (the CPU tests and rehearsals), and on a TPU a
+    key or value width that is no multiple of the 128 lanes (96 and 192,
+    say) or a chunk its tiles do not take — and it is the yardstick the
+    kernels' tests hold them to.  Every float32
+    intermediate here is an array of all chunks at once that goes to HBM
+    and comes back; the kernels keep a chunk's in VMEM."""
     B, T, H, dk = q.shape
     dv, dt, C = v.shape[-1], v.dtype, chunk
     pad = (-T) % C
@@ -169,7 +179,12 @@ def by_head_groups(rule, token_heads):
     :data:`RULE_OUTPUT`: a caller that recomputes the whole mixer in the
     backward pass saves it by that name, and the rule then runs forward
     twice a step (once to recompute a group for its backward pass), as it
-    does ungrouped, not three times."""
+    does ungrouped, not three times.
+
+    Part of the **plain branch**: the split exists because
+    :func:`chunked_gated_delta_rule`'s working set is all chunks' at once.
+    Where :func:`gated_delta_net` takes the kernel pair the working set is
+    a chunk's, and the ``rule`` a caller built with this is not called."""
     def grouped(q, k, v, g, beta, chunk):
         B, T, H = q.shape[:3]
         fit = max(1, token_heads // (B * T))
@@ -229,9 +244,15 @@ def gate_inputs(ba, p, dims: GatedDeltaDims):
 
 
 def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
-    """The mixer: x ``[B, T, d_model]`` -> ``[B, T, d_model]``.  ``rule`` is
-    the delta rule's implementation, :func:`chunked_gated_delta_rule` by
-    default (the same signature: a caller may hand in its own)."""
+    """The mixer: x ``[B, T, d_model]`` -> ``[B, T, d_model]``.  The delta
+    rule is one algorithm with two implementations, chosen here at trace
+    time from what can be observed: on a TPU, where the shape fits
+    (``ops/delta_rule.py`` ``tiles``: key and value widths in 128s), the
+    Pallas kernel pair, which holds a chunk's algebra in VMEM and needs no
+    split by heads; everywhere else ``rule``, the plain branch
+    (:func:`chunked_gated_delta_rule` by default; a caller whose shape
+    wants it hands in :func:`by_head_groups` of it).  ``trace.delta_rule``
+    counts the call sites of each."""
     rule = rule or chunked_gated_delta_rule
     B, T, _ = x.shape
     hk, hv, dk, dv = dims.k_heads, dims.v_heads, dims.k_dim, dims.v_dim
@@ -246,19 +267,28 @@ def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
     with jax.named_scope("gdn/conv"):
         qkv = causal_conv_silu(qkv, p["conv"])
     with jax.named_scope("gdn/scan"):
+        # one algorithm, two implementations chosen at trace time from the
+        # backend and the shape: the kernel pair reads q and k at the key
+        # heads, the plain branch a copy a value head
+        kernel = delta_rule.kernel_enabled() and delta_rule.tiles(
+            (B, T, hk, dk), (B, T, hv, dv), dims.chunk, x.dtype)
+        trace.delta_rule["kernel" if kernel else "plain"] += 1   # a trace
+
         def heads(y, n, dim, repeat=1):
             y = y.reshape(B, T, n, dim).astype(f32)
             y = y * lax.rsqrt(jnp.sum(jnp.square(y), axis=-1,
                                       keepdims=True) + L2_NORM_EPS)
             return jnp.repeat(y, repeat, axis=2)
 
-        q = (heads(qkv[..., :hk * dk], hk, dk, hv // hk)
+        copies = 1 if kernel else hv // hk
+        q = (heads(qkv[..., :hk * dk], hk, dk, copies)
              / np.sqrt(dk)).astype(x.dtype)
         k = heads(qkv[..., hk * dk:2 * hk * dk], hk, dk,
-                  hv // hk).astype(x.dtype)
+                  copies).astype(x.dtype)
         v = qkv[..., 2 * hk * dk:].reshape(B, T, hv, dv)
         beta, g = gate_inputs(ba, p, dims)
-        o = rule(q, k, v, g, beta, dims.chunk)
+        o = (delta_rule.gated_delta_rule if kernel else rule)(
+            q, k, v, g, beta, dims.chunk)
     with jax.named_scope("gdn/out"):
         of = o.astype(f32)
         var = jnp.mean(jnp.square(of), axis=-1, keepdims=True)

@@ -302,6 +302,13 @@ causal_conv = {"kernel": 0, "plain": 0}
 # ``plain`` rising on a TPU is a shape the tiles do not fit.
 selective_scan = {"kernel": 0, "plain": 0}
 
+# The same pair for the Gated DeltaNet mixers' chunked delta rule
+# (``models/gated_delta.py`` ``gated_delta_net``): ``kernel`` call sites
+# took the Pallas kernel pair (``ops/delta_rule.py``: a TPU, key and value
+# widths in 128s), ``plain`` XLA's code for all chunks at once.  ``plain``
+# rising on a TPU is a shape the kernels do not take.
+delta_rule = {"kernel": 0, "plain": 0}
+
 # The flash attention kernels' block schedules (``ops/flash_attention.py``
 # ``_schedule``), added up in Python once a traced kernel call, a head:
 # ``grid`` the blocks of the dense grid, ``steps`` the steps the schedule
@@ -344,6 +351,9 @@ _register_counts("hvd_causal_conv", causal_conv, {
 _register_counts("hvd_selective_scan", selective_scan, {
     "kernel": "selective scan call sites traced as the Pallas kernels",
     "plain": "selective scan call sites traced as XLA's own code"})
+_register_counts("hvd_delta_rule", delta_rule, {
+    "kernel": "chunked delta rule call sites traced as the Pallas kernels",
+    "plain": "chunked delta rule call sites traced as XLA's own code"})
 _register_counts("hvd_flash_blocks", flash_blocks, {
     "grid": "blocks a head of the dense grids of traced flash kernel calls",
     "steps": "steps a head the flash kernels' schedules keep of those grids"})

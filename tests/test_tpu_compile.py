@@ -343,6 +343,89 @@ def test_chunked_delta_rule_compiles_for_v5e(one_chip, heads, dk, dv,
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
+DELTA_KERNELS = (r"%(?:jvp_)?(delta_rule_(?:fwd|bwd))[\w.]* = "
+                 r"[^\n]*custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("shape, block", [
+    pytest.param((2, 8192, 16, 32, 128, 128), 8, id="qwen3next-b2-t8192"),
+    pytest.param((1, 1100, 2, 2, 128, 256), 8, id="t1100-dv256-padded"),
+    pytest.param((1, 256, 1, 1, 128, 128), 4, id="one-block-of-four"),
+])
+def test_delta_rule_kernels_compile_for_v5e(one_chip, shape, block):
+    """The delta rule's kernel pair (``ops/delta_rule.py``) at
+    ``qwen3next-80b-a3b-4l``'s geometry (16 key heads shared by 32 value
+    heads of 128 x 128, two sequences of 8192) and at two that pad and
+    stack differently: forward under ``jax.checkpoint`` and its VJP.  Two
+    kernels, no loop of XLA's, and no temporary but the state every group
+    of chunks starts from, the gates a chunk a row and the padding (XLA's
+    code for the plain formulation keeps 2.9 GB at the first shape)."""
+    from horovod_tpu.ops import delta_rule
+
+    def spec(s, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    B, T, hk, hv, dk, dv = shape
+    q, v = (B, T, hk, dk), (B, T, hv, dv)
+    assert delta_rule.tiles(q, v, 64, jnp.bfloat16) == block
+
+    def both(q, k, v, g, beta, do):
+        o, back = jax.vjp(jax.checkpoint(
+            lambda *a: delta_rule.gated_delta_rule(*a, 64, interpret=False)),
+            q, k, v, g, beta)
+        return o, back(do)
+
+    gate = spec((B, T, hv), jnp.float32)
+    compiled = jax.jit(both).lower(spec(q), spec(q), spec(v), gate, gate,
+                                   spec(v)).compile()
+    text = compiled.as_text()
+    assert set(re.findall(DELTA_KERNELS, text)) == {"delta_rule_fwd",
+                                                    "delta_rule_bwd"}
+    assert " while(" not in text
+    padded = -(-T // (64 * block)) * 64 * block
+    states = 4 * B * hv * (padded // (64 * delta_rule.GROUP)) * dk * dv
+    moved = B * padded * (2 * (2 * hk * dk + 2 * hv * dv) + 4 * 4 * hv)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.1 * states + 3 * moved)
+
+
+def test_qwen3next_step_names_the_delta_rules_kernels(one_chip, monkeypatch):
+    """A ``qwen3_next`` training step with the published head geometry
+    (key and value heads of 128 x 128, two value heads a key head; the
+    rest at test size) compiles for the described v5e with the delta
+    rule's kernel pair at its three Gated DeltaNet layers — forward, the
+    recomputed forward and the backward a layer — beside the convolution's
+    kernels, and without the plain path's solve.  (The cell's own step:
+    nine such calls and 3.87 GB of temporaries where the plain path's has
+    9.72, PERF.md section 6, PR 45; at 90 s it is not compiled here.)"""
+    import optax
+
+    from horovod_tpu.models import qwen3_next
+    from horovod_tpu.ops import causal_conv, delta_rule
+
+    # the default backend here is the CPU's: without these the Pallas
+    # kernels are interpreted or left out, not compiled for the described chip
+    for module in (causal_conv, delta_rule):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+        monkeypatch.setattr(module, "kernel_enabled", lambda: True)
+    cfg = qwen3_next.tiny(lin_k_dim=128, lin_v_dim=128, dtype=jnp.bfloat16)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: qwen3_next.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    optimizer = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(qwen3_next.make_train_step(cfg, optimizer)).lower(
+        at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+        tokens).compile()
+    text = compiled.as_text()
+    calls = re.findall(DELTA_KERNELS.replace("%(?:jvp_)?", ""), text)
+    assert sorted(calls) == ["delta_rule_bwd"] * 3 + ["delta_rule_fwd"] * 6
+    assert "causal_conv_fwd" in text
+    assert "InvertDiagBlocks" not in text       # the plain path's solve
+
+
 @pytest.mark.parametrize("at_once", [1, 8], ids=["a-group-at-a-time",
                                                  "all-groups"])
 def test_chunked_ssd_compiles_for_v5e(one_chip, at_once):
