@@ -56,8 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import blocks as _blocks
 from . import mamba as _ssm
-from ..parallel.ring_attention import local_flash_attention
 
 # tokens of a sequence whose logits over the whole vocabulary are held
 # together, in the forward pass and again in the backward pass
@@ -162,34 +162,14 @@ def init_params(cfg: JambaConfig, key) -> Dict:
 
 
 # ------------------------------------------------------------------ forward
-def _rmsnorm(x, w, eps):
-    """``x / rms(x) * w`` over the last axis, in float32."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _attention(x, p, cfg: JambaConfig):
-    from ..ops.flash_attention import flash_attention, resolve_flash
-    B, T, _ = x.shape
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("attn/full"):
-        q = (x @ p["wq"]).reshape(B, T, h, hd)
-        k = (x @ p["wk"]).reshape(B, T, kv, hd)
-        v = (x @ p["wv"]).reshape(B, T, kv, hd)
-        attend = (flash_attention if resolve_flash(cfg.use_flash, seq=T,
-                                                   causal=True)
-                  else local_flash_attention)
-        # no rotary: the heads see no position but the causal mask
-        o = attend(q, k, v, causal=True)
-        return o.reshape(B, T, h * hd) @ p["wo"]
+_rmsnorm = _blocks.rmsnorm
 
 
 def _mixer_block(p, x, cfg: JambaConfig):
     if "attn" in p:
         with jax.named_scope("attn/full"):
             u = _rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
-        return x + _attention(u, p["attn"], cfg)
+        return x + _blocks.grouped_attention(u, p["attn"], cfg)
     with jax.named_scope("ssm/proj"):
         u = _rmsnorm(x, p["mixer_norm"], cfg.norm_eps)
     return x + _ssm.mamba(u, p["ssm"], cfg.ssm_dims())
@@ -281,21 +261,8 @@ def decay_stats(params, tokens, cfg: JambaConfig):
 
 # --------------------------------------------------------------- train step
 def make_train_step(cfg: JambaConfig, optimizer):
-    """``step(params, opt_state, tokens, targets) -> (params, opt_state,
-    loss)`` for use inside ``shard_map``; ``optimizer`` is an in-graph
-    ``hvd.DistributedOptimizer`` (or plain optax), which exchanges the
-    gradients."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        with jax.named_scope("forward"):
-            loss, backward = jax.vjp(
-                lambda p: loss_fn(p, tokens, targets, cfg), params)
-        with jax.named_scope("backward"):
-            grads, = backward(jnp.ones_like(loss))
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """:func:`blocks.train_step` of this module's ``loss_fn``, looked up
+    when the step runs."""
+    return _blocks.train_step(
+        lambda p, tokens, targets: loss_fn(p, tokens, targets, cfg),
+        optimizer)

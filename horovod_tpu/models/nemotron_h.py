@@ -57,11 +57,10 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
+from . import blocks as _blocks
 from . import mamba2 as _ssm
 from . import moe as _moe
-from ..parallel.ring_attention import local_flash_attention
 
 PUBLISHED_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
                      "EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
@@ -180,27 +179,7 @@ def init_params(cfg: NemotronHConfig, key) -> Dict:
 
 
 # ------------------------------------------------------------------ forward
-def _rmsnorm(x, w, eps):
-    """``x / rms(x) * w`` over the last axis, in float32."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
-
-
-def _attention(x, p, cfg: NemotronHConfig):
-    from ..ops.flash_attention import flash_attention, resolve_flash
-    B, T, _ = x.shape
-    h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("attn/full"):
-        q = (x @ p["wq"]).reshape(B, T, h, hd)
-        k = (x @ p["wk"]).reshape(B, T, kv, hd)
-        v = (x @ p["wv"]).reshape(B, T, kv, hd)
-        attend = (flash_attention if resolve_flash(cfg.use_flash, seq=T,
-                                                   causal=True)
-                  else local_flash_attention)
-        # no rotary: the heads see no position but the causal mask
-        o = attend(q, k, v, causal=True)
-        return o.reshape(B, T, h * hd) @ p["wo"]
+_rmsnorm = _blocks.rmsnorm
 
 
 def _mamba(x, p, cfg: NemotronHConfig):
@@ -218,7 +197,7 @@ def _layer(p, x, cfg: NemotronHConfig):
                                           cfg.moe_cfg())
         return x + y.reshape(B, T, D), counts
     return x + (_mamba(h, p["ssm"], cfg) if "ssm" in p
-                else _attention(h, p["attn"], cfg)), None
+                else _blocks.grouped_attention(h, p["attn"], cfg)), None
 
 
 def _forward(params, tokens, cfg: NemotronHConfig):
@@ -282,31 +261,13 @@ def decay_stats(params, tokens, cfg: NemotronHConfig):
 
 def loss_fn(params, tokens, targets, cfg: NemotronHConfig):
     """Mean next-token cross-entropy over this rank's tokens."""
-    logits = forward(params, tokens, cfg)
-    with jax.named_scope("head"):
-        logp = logits - jax.scipy.special.logsumexp(logits, axis=-1,
-                                                    keepdims=True)
-        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None],
-                                             axis=-1))
+    return _blocks.next_token_loss(forward(params, tokens, cfg), targets)
 
 
 # --------------------------------------------------------------- train step
 def make_train_step(cfg: NemotronHConfig, optimizer):
-    """``step(params, opt_state, tokens, targets) -> (params, opt_state,
-    loss)`` for use inside ``shard_map``; ``optimizer`` is an in-graph
-    ``hvd.DistributedOptimizer`` (or plain optax), which exchanges the
-    gradients."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        with jax.named_scope("forward"):
-            loss, backward = jax.vjp(
-                lambda p: loss_fn(p, tokens, targets, cfg), params)
-        with jax.named_scope("backward"):
-            grads, = backward(jnp.ones_like(loss))
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """:func:`blocks.train_step` of this module's ``loss_fn``, looked up
+    when the step runs."""
+    return _blocks.train_step(
+        lambda p, tokens, targets: loss_fn(p, tokens, targets, cfg),
+        optimizer)

@@ -65,8 +65,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
+from . import blocks as _blocks
 from . import gated_delta as _gdn
 from ..parallel.ring_attention import local_flash_attention
 
@@ -166,11 +166,7 @@ def init_params(cfg: OlmoHybridConfig, key) -> Dict:
 
 
 # ------------------------------------------------------------------ forward
-def _rmsnorm(x, w, eps):
-    """``x / rms(x) * w`` over the last axis, in float32."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
+_rmsnorm = _blocks.rmsnorm
 
 
 def _full_attention(x, p, cfg: OlmoHybridConfig):
@@ -248,31 +244,13 @@ def beta_stats(params, tokens, cfg: OlmoHybridConfig):
 
 def loss_fn(params, tokens, targets, cfg: OlmoHybridConfig):
     """Mean next-token cross-entropy over this rank's tokens."""
-    logits = forward(params, tokens, cfg)
-    with jax.named_scope("head"):
-        logp = logits - jax.scipy.special.logsumexp(logits, axis=-1,
-                                                    keepdims=True)
-        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None],
-                                             axis=-1))
+    return _blocks.next_token_loss(forward(params, tokens, cfg), targets)
 
 
 # --------------------------------------------------------------- train step
 def make_train_step(cfg: OlmoHybridConfig, optimizer):
-    """``step(params, opt_state, tokens, targets) -> (params, opt_state,
-    loss)`` for use inside ``shard_map``; ``optimizer`` is an in-graph
-    ``hvd.DistributedOptimizer`` (or plain optax), which exchanges the
-    gradients."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        with jax.named_scope("forward"):
-            loss, backward = jax.vjp(
-                lambda p: loss_fn(p, tokens, targets, cfg), params)
-        with jax.named_scope("backward"):
-            grads, = backward(jnp.ones_like(loss))
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """:func:`blocks.train_step` of this module's ``loss_fn``, looked up
+    when the step runs."""
+    return _blocks.train_step(
+        lambda p, tokens, targets: loss_fn(p, tokens, targets, cfg),
+        optimizer)

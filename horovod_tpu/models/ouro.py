@@ -81,6 +81,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import blocks as _blocks
 from .llama import _rope
 from ..parallel.ring_attention import local_flash_attention
 
@@ -144,11 +145,7 @@ def init_params(cfg: OuroConfig, key) -> Dict:
 
 
 # ------------------------------------------------------------------ forward
-def _rmsnorm(x, w, eps):
-    """``x / rms(x) * w`` over the last axis, in float32."""
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    return (xf * lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
+_rmsnorm = _blocks.rmsnorm
 
 
 def _layer(p, x, cfg: OuroConfig):
@@ -348,21 +345,8 @@ def loss_fn(params, tokens, targets, cfg: OuroConfig):
 
 # --------------------------------------------------------------- train step
 def make_train_step(cfg: OuroConfig, optimizer):
-    """``step(params, opt_state, tokens, targets) -> (params, opt_state,
-    loss)`` for use inside ``shard_map``; ``optimizer`` is an in-graph
-    ``hvd.DistributedOptimizer`` (or plain optax), which exchanges the
-    gradients."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        with jax.named_scope("forward"):
-            loss, backward = jax.vjp(
-                lambda p: loss_fn(p, tokens, targets, cfg), params)
-        with jax.named_scope("backward"):
-            grads, = backward(jnp.ones_like(loss))
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """:func:`blocks.train_step` of this module's ``loss_fn``, looked up
+    when the step runs."""
+    return _blocks.train_step(
+        lambda p, tokens, targets: loss_fn(p, tokens, targets, cfg),
+        optimizer)

@@ -49,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import blocks as _blocks
 from . import gated_delta as _gdn
 from . import moe as _moe
 from .gated_delta import chunked_gated_delta_rule  # noqa: F401  (this
@@ -256,31 +257,13 @@ def expert_load(params, tokens, cfg: Qwen3NextConfig):
 
 def loss_fn(params, tokens, targets, cfg: Qwen3NextConfig):
     """Mean next-token cross-entropy over this rank's tokens."""
-    logits = forward(params, tokens, cfg)
-    with jax.named_scope("head"):
-        logp = logits - jax.scipy.special.logsumexp(logits, axis=-1,
-                                                    keepdims=True)
-        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None],
-                                             axis=-1))
+    return _blocks.next_token_loss(forward(params, tokens, cfg), targets)
 
 
 # --------------------------------------------------------------- train step
 def make_train_step(cfg: Qwen3NextConfig, optimizer):
-    """``step(params, opt_state, tokens, targets) -> (params, opt_state,
-    loss)`` for use inside ``shard_map``; ``optimizer`` is an in-graph
-    ``hvd.DistributedOptimizer`` (or plain optax), which exchanges the
-    gradients."""
-    import optax
-
-    def step(params, opt_state, tokens, targets):
-        with jax.named_scope("forward"):
-            loss, backward = jax.vjp(
-                lambda p: loss_fn(p, tokens, targets, cfg), params)
-        with jax.named_scope("backward"):
-            grads, = backward(jnp.ones_like(loss))
-        with jax.named_scope("optimizer"):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, loss
-
-    return step
+    """:func:`blocks.train_step` of this module's ``loss_fn``, looked up
+    when the step runs."""
+    return _blocks.train_step(
+        lambda p, tokens, targets: loss_fn(p, tokens, targets, cfg),
+        optimizer)

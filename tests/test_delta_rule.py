@@ -12,6 +12,8 @@ in-kernel inverse against numpy's; which shapes take which branch of
 two ``/metrics`` series).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,6 +45,7 @@ def draw(B, T, hk, hv, dk, dv, dtype=jnp.float32, decay=0.999, beta=None,
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), g, b), w
 
 
+@functools.lru_cache(maxsize=None)     # one rule a tiling: see ``_both``
 def kernel_rule(block=None, group=None, chunk=64):
     return lambda q, k, v, g, beta: delta_rule.gated_delta_rule(
         q, k, v, g, beta, chunk, block=block, group=group, interpret=True)
@@ -58,14 +61,25 @@ def at_value_heads(rule, chunk=64):
     return fn
 
 
-def with_gradients(rule, args, w):
-    """``(o, dq, dk, dv, dg, dbeta)`` of ``sum(o * w)``, in float32 (one
-    jitted program: op by op the plain branch alone takes seconds)."""
+@functools.lru_cache(maxsize=None)
+def _both(rule):
+    """One jitted program a rule, kept: cases that differ in their data
+    and not in their shapes share its compilation."""
     @jax.jit
     def both(w, *args):
         o, pull = jax.vjp(rule, *args)
         return (o,) + pull(w.astype(o.dtype))
-    return tuple(np.asarray(x, np.float32) for x in both(w, *args))
+    return both
+
+
+PLAIN = at_value_heads(plain_rule)
+RECURRENCE = at_value_heads(lambda *a: ref.recurrence(*a[:5]))
+
+
+def with_gradients(rule, args, w):
+    """``(o, dq, dk, dv, dg, dbeta)`` of ``sum(o * w)``, in float32 (one
+    jitted program: op by op the plain branch alone takes seconds)."""
+    return tuple(np.asarray(x, np.float32) for x in _both(rule)(w, *args))
 
 
 def gaps(got, want):
@@ -103,7 +117,7 @@ def test_values_and_gradients_are_the_plain_branchs(shape, tile,
                                                     dtype=jnp.float32):
     args, w = draw(*shape, dtype)
     got = with_gradients(kernel_rule(*tile), args, w)
-    want = with_gradients(at_value_heads(plain_rule), args, w)
+    want = with_gradients(PLAIN, args, w)
     assert got[0].shape == want[0].shape
     assert max(gaps(got, want)) <= TOLERANCE[dtype], gaps(got, want)
 
@@ -123,8 +137,7 @@ def test_values_and_gradients_are_the_recurrences(decay, beta):
     lose."""
     args, w = draw(2, 150, 1, 2, 128, 128, decay=decay, beta=beta)
     with jax.default_matmul_precision("highest"):
-        want = with_gradients(
-            at_value_heads(lambda *a: ref.recurrence(*a[:5])), args, w)
+        want = with_gradients(RECURRENCE, args, w)
     got = with_gradients(kernel_rule(2, 2), args, w)
     assert max(gaps(got, want)) <= 1e-4, gaps(got, want)
 
@@ -264,8 +277,10 @@ def test_the_branch_follows_backend_and_shape(monkeypatch, k_dim, v_dim,
     interpreted here); on the CPU every shape is plain.  A caller's own
     ``rule`` is the plain branch's and never the kernel's."""
     x, p, dims = mixer(k_dim, v_dim, chunk)
+    net = lambda **kw: jax.jit(lambda x, p: gated_delta.gated_delta_net(
+        x, p, dims, **kw))(x, p)       # traced once: the counts hold
     before = counts()
-    want = gated_delta.gated_delta_net(x, p, dims)
+    want = net()
     assert moved(before) == {"kernel": 0, "plain": 1}
     monkeypatch.setattr(delta_rule, "kernel_enabled", lambda: True)
     called = []
@@ -275,7 +290,7 @@ def test_the_branch_follows_backend_and_shape(monkeypatch, k_dim, v_dim,
         return plain_rule(*a)
 
     before = counts()
-    got = gated_delta.gated_delta_net(x, p, dims, rule=rule)
+    got = net(rule=rule)
     assert moved(before) == {"kernel": int(branch == "kernel"),
                              "plain": int(branch == "plain")}
     assert len(called) == int(branch == "plain")
@@ -346,6 +361,9 @@ def lowered_step(family):
     """The family's training step at test size, lowered for this backend
     (the CPU) from shapes alone."""
     from horovod_tpu.models import llama, nemotron_h, olmo_hybrid, qwen3_next
+    # A checkpointed region another file's test traced on this worker is
+    # kept, and the counts below move when a region is traced.
+    jax.clear_caches()
     module = {"llama": llama, "qwen3_next": qwen3_next,
               "olmo_hybrid": olmo_hybrid, "nemotron_h": nemotron_h}[family]
     cfg = (llama.tiny(dp_axis=None, tp_axis=None, sp_axis=None,

@@ -14,6 +14,17 @@ from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import local_flash_attention
 
 
+def _value_and_grads(attend, q, k, v, w=None):
+    """``attend(q, k, v)`` and the three gradients of the sum of its
+    squares (of its products with ``w``, where given), as one program (op
+    by op each is some twenty)."""
+    def loss(*a):
+        o = attend(*a)
+        return jnp.sum(o ** 2 if w is None else o * w)
+    return jax.jit(lambda q, k, v: (attend(q, k, v), jax.grad(
+        loss, argnums=(0, 1, 2))(q, k, v)))(q, k, v)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape,blocks", [
     ((2, 70, 3, 16), (32, 32)),   # padded: 70 % 32 != 0
@@ -28,20 +39,13 @@ def test_flash_matches_reference(shape, blocks, causal):
     k = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
 
-    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-    ref = local_flash_attention(q, k, v, causal=causal)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk)
+    plain = lambda q, k, v: local_flash_attention(q, k, v, causal=causal)
+    (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v)
+                            for f in (flash, plain))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
-
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       block_q=bq, block_k=bk) ** 2)
-
-    def loss_ref(q, k, v):
-        return jnp.sum(local_flash_attention(q, k, v, causal=causal) ** 2)
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)
@@ -59,24 +63,15 @@ def test_flash_gqa_matches_repeated(causal):
     k = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
 
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       block_q=16, block_k=16) ** 2)
-
-    def loss_ref(q, k, v):
-        kr = jnp.repeat(k, rep, axis=2)
-        vr = jnp.repeat(v, rep, axis=2)
-        return jnp.sum(local_flash_attention(q, kr, vr, causal=causal) ** 2)
-
-    np.testing.assert_allclose(
-        np.asarray(flash_attention(q, k, v, causal=causal,
-                                   block_q=16, block_k=16)),
-        np.asarray(local_flash_attention(
-            q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
-            causal=causal)),
-        atol=3e-5, rtol=3e-5)
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                            block_q=16, block_k=16)
+    repeated = lambda q, k, v: local_flash_attention(
+        q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),
+        causal=causal)
+    (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v)
+                            for f in (flash, repeated))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=3e-5, rtol=3e-5)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)
@@ -99,13 +94,10 @@ def test_flash_twenty_query_heads_on_one_key_head(T, blocks):
         q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1])
     plain = lambda q, k, v: local_flash_attention(
         q, jnp.repeat(k, H, axis=2), jnp.repeat(v, H, axis=2), causal=True)
-    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
-                               np.asarray(plain(q, k, v)),
+    (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v, w)
+                            for f in (flash, plain))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
-    gf = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
-    gr = jax.grad(lambda *a: jnp.sum(plain(*a) * w), argnums=(0, 1, 2))(
-        q, k, v)
     assert gf[1].shape == (B, T, K, D)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -140,18 +132,14 @@ def test_flash_sliding_window_matches_reference(window, shape, blocks):
     k = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
     v = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
 
-    out = flash_attention(q, k, v, causal=True, window=window,
-                          block_q=bq, block_k=bk)
-    ref = local_flash_attention(q, k, v, causal=True, window=window)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=bq, block_k=bk)
+    plain = lambda q, k, v: local_flash_attention(q, k, v, causal=True,
+                                                  window=window)
+    (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v)
+                            for f in (flash, plain))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
-
-    gf = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
-        q, k, v, causal=True, window=window, block_q=bq, block_k=bk) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(lambda q, k, v: jnp.sum(local_flash_attention(
-        q, k, v, causal=True, window=window) ** 2),
-        argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-4, rtol=1e-4)

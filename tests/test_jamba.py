@@ -23,8 +23,9 @@ if ROOT not in sys.path:
 
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import jamba as ref                # noqa: E402
+from family import Seeded, planted, worst_rel               # noqa: E402
 from horovod_tpu.compat import shard_map                    # noqa: E402
-from horovod_tpu.models import jamba, mamba                 # noqa: E402
+from horovod_tpu.models import blocks, jamba, mamba         # noqa: E402
 
 # one period of four (MM*M); the configuration file's ``tiny`` preset
 SIZES = dict(hidden_size=64, intermediate_size=96, num_hidden_layers=4,
@@ -41,21 +42,17 @@ KEY = jax.random.PRNGKey(43)
 LOGITS_TOL, LOSS_TOL, GRAD_TOL = 2e-4, 1e-5, 5e-4
 
 
-def config(**kw):
+def config(sizes=SIZES, **kw):
     from benchmark.families import jamba as family
-    return family.config_of({**SIZES, "use_flash": False, **kw})
+    return family.config_of({**sizes, "use_flash": False, **kw})
 
 
-def worst_rel(a, b):
-    return max(float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12))
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
-
-
-def seeded(sizes=SIZES):
-    params = ref.init_weights(KEY, sizes)
-    toks, tgts = ref.make_batch(KEY, sizes, 0)
-    return params, toks, tgts
+SEEDED = Seeded(ref, SIZES, KEY)
+# Where depth is not what a test asserts (Adam's wiring leaf by leaf, the
+# gradient exchange, the mean over ranks), a Mamba layer and an attention
+# layer (M*): a step's compile time follows its layers.
+SHALLOW = dict(SIZES, num_hidden_layers=2, attn_layer_period=2,
+               attn_layer_offset=1)
 
 
 # ------------------------------------------------------------------ the mixer
@@ -65,13 +62,15 @@ def test_the_mixer_is_the_references_layer():
     the three inner norms, the rank-8 step with its bias, a decay a
     (channel, state) pair, the skip and the gate."""
     from benchmark.reference.common import quantizer
-    params, _, _ = seeded()
+    params, _, _ = SEEDED
     p = params["layers"][0]["ssm"]
     u = jax.random.normal(KEY, (2, 72, 64))
     q = quantizer("float32")
     with jax.default_matmul_precision("highest"):
-        want = ref.mamba(p, u, SIZES, ref._matmul(q), q)
-        got = mamba.mamba(u, p, config().ssm_dims())
+        want = jax.jit(lambda p, u: ref.mamba(
+            p, u, SIZES, ref._matmul(q), q))(p, u)
+        got = jax.jit(lambda u, p: mamba.mamba(u, p, config().ssm_dims()))(
+            u, p)
     assert got.shape == (2, 72, 64)
     assert float(jnp.max(jnp.abs(got - want))) <= 1e-5 * float(
         jnp.max(jnp.abs(want)))
@@ -169,13 +168,11 @@ def test_logits_loss_and_gradients_are_the_references(use_flash):
     norm weights away from one, the published decays): logits, the loss and
     every leaf's gradient; with the Pallas flash kernel interpreted at 4
     query heads on one key head."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
+    want, (l1, g1) = SEEDED.logits, SEEDED.loss_and_grads
     cfg = config(use_flash=use_flash)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, toks, SIZES))(params)
         got = jax.jit(lambda p: jamba.forward(p, toks, cfg))(params)
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda p: jamba.loss_fn(p, toks, tgts, cfg)))(params)
     assert got.shape == (2, 72, 256) and got.dtype == jnp.float32
@@ -192,10 +189,9 @@ def test_the_references_gradient_a_layer_a_call_is_its_whole_gradient():
     """``follow`` takes the gradient by ``gradient`` (a layer a jitted
     call, the tied matrix's two parts summed in float32): the same numbers
     as ``jax.grad`` of the loss in one traced function."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
+    want_l, want = SEEDED.loss_and_grads
     with jax.default_matmul_precision("highest"):
-        want_l, want = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         loss, got = ref.gradient(ref._pieces(ref.scalars(SIZES), "float32"),
                                  params, toks, tgts)
     assert abs(loss - float(want_l)) <= 1e-6 * float(want_l)
@@ -206,7 +202,7 @@ def test_the_tied_gradient_is_the_sum_of_an_untied_pairs():
     """With the head given a matrix of its own (the same numbers), the
     embedding's gradient is the lookup's scatter and the head's the
     product; tied, the one leaf's gradient is their sum."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config()
 
     def untied(embed, head):
@@ -216,10 +212,10 @@ def test_the_tied_gradient_is_the_sum_of_an_untied_pairs():
             jax.nn.log_softmax(logits), tgts[..., None], axis=-1))
 
     with jax.default_matmul_precision("highest"):
-        of_lookup, of_head = jax.grad(untied, argnums=(0, 1))(
+        of_lookup, of_head = jax.jit(jax.grad(untied, argnums=(0, 1)))(
             params["embed"], params["embed"])
-        tied = jax.grad(lambda p: jamba.loss_fn(p, toks, tgts, cfg))(
-            params)["embed"]
+        tied = jax.jit(jax.grad(
+            lambda p: jamba.loss_fn(p, toks, tgts, cfg)))(params)["embed"]
     # the lookup touches the rows of the tokens that occur, the head all
     assert (np.abs(np.asarray(of_lookup)).sum(axis=1) > 0).sum() <= 144
     assert (np.abs(np.asarray(of_head)).sum(axis=1) > 0).all()
@@ -229,7 +225,7 @@ def test_the_tied_gradient_is_the_sum_of_an_untied_pairs():
 
 @pytest.mark.parametrize("block", [16, 64, 72, 1024])
 def test_the_head_in_blocks_is_the_head(monkeypatch, block):
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config()
     with jax.default_matmul_precision("highest"):
         logits = jamba.forward(params, toks, cfg)
@@ -266,18 +262,16 @@ def one_decay_a_channel(mixer):
     (mamba, "_rmsnorm", no_inner_norms),
     (mamba, "mamba", no_step_bias),
     (mamba, "mamba", one_decay_a_channel),
-    (jamba, "local_flash_attention", None),
+    (blocks, "local_flash_attention", None),
 ], ids=["no-skip", "no-inner-norms", "no-step-bias", "one-decay-a-channel",
         "a-rotary"])
-def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
-                                                     name, broken):
+def test_the_layers_wiring_is_what_the_reference_has(module, name, broken):
     """The skip ``D x`` left out, the norms on ``dt_r``, ``B`` and ``C``
     left out, ``b_dt`` left out, ``A`` taken as one decay a channel, a
     rotary applied: each moves the logits far beyond the tolerance that
     the sound model keeps."""
     from horovod_tpu.models import qwen3_next
-    jax.clear_caches()      # a region traced by an earlier test is kept
-    params, toks, _ = seeded()
+    params, toks, _ = SEEDED
     if broken is None:
         attend = module.local_flash_attention
         turn = lambda y: qwen3_next._partial_rope(y, y.shape[-1], 1e4)
@@ -285,11 +279,10 @@ def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
             turn(q), turn(k), v, causal=causal))
     program = lambda: jax.jit(lambda p: jamba.forward(
         p, toks, config()))(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, toks, SIZES))(params)
-        sound = program()
-        monkeypatch.setattr(module, name, broken(getattr(module, name)))
-        jax.clear_caches()
+    want = SEEDED.logits
+    sound = SEEDED.kept("the sound program's logits", lambda *_: program())
+    with planted(module, name, broken(getattr(module, name))), \
+            jax.default_matmul_precision("highest"):
         got = program()
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(sound - want))) <= LOGITS_TOL * scale
@@ -298,7 +291,7 @@ def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
 
 # ---------------------------------------------------------------- the counter
 def test_the_counter_reads_the_decays():
-    params, toks, _ = seeded()
+    params, toks, _ = SEEDED
     cfg = config()
     share, least, most = jax.jit(
         lambda p: jamba.decay_stats(p, toks, cfg))(params)
@@ -330,14 +323,15 @@ def test_three_optimizer_steps_are_the_references():
     tied matrix is one leaf on both sides."""
     from benchmark import compare
     from benchmark.reference.common import leaf_norms
-    sizes = dict(SIZES, batch_per_chip=1)
-    reference = ref.follow(sizes, KEY, 1, 3)
-    params = ref.init_weights(KEY, sizes)
-    toks, tgts = ref.make_batch(KEY, sizes, 0)
+    # SHALLOW as it is (two sequences a rank): ``follow`` keeps its compiled
+    # pieces by the sizes, and the test below reads the same ones
+    reference = ref.follow(SHALLOW, KEY, 1, 3)
+    params = ref.init_weights(KEY, SHALLOW)
+    toks, tgts = ref.make_batch(KEY, SHALLOW, 0)
     adam = ref.ADAM
     opt = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
                      eps=adam["eps"])
-    step = jax.jit(jamba.make_train_step(config(), opt))
+    step = jax.jit(jamba.make_train_step(config(SHALLOW), opt))
     state, p, losses = opt.init(params), params, []
     with jax.default_matmul_precision("highest"):
         for i in range(3):
@@ -360,14 +354,14 @@ def test_two_ranks_and_two_sequences_are_averaged_by_the_reference():
     """``follow`` at world 2 with two sequences a rank: the first gradient
     is the mean over the four sequences' gradients."""
     from benchmark.reference.common import leaf_norms
-    reference = ref.follow(SIZES, KEY, 2, 1)
+    reference = ref.follow(SHALLOW, KEY, 2, 1)
     assert len(reference["losses"]) == 2
-    params = ref.init_weights(KEY, SIZES)
-    batches = [ref.make_batch(KEY, SIZES, r) for r in range(2)]
+    params = ref.init_weights(KEY, SHALLOW)
+    batches = [ref.make_batch(KEY, SHALLOW, r) for r in range(2)]
     toks, tgts = (jnp.concatenate(x) for x in zip(*batches))
     with jax.default_matmul_precision("highest"):
-        want = leaf_norms(jax.grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES))(params))
+        want = leaf_norms(jax.jit(jax.grad(
+            lambda p: ref.loss_fn(p, toks, tgts, SHALLOW)))(params))
     for leaf, norm in want.items():
         assert abs(reference["grad_norms"][leaf] - norm) <= 1e-4 * max(
             norm, 1e-6), leaf
@@ -380,8 +374,8 @@ def test_the_train_step_under_shard_map_is_the_unsharded_step():
     whole batch."""
     hvd.init()
     mesh = hvd.mesh()
-    sizes = dict(SIZES, batch_per_chip=1, seq_len=64)
-    cfg = config()
+    sizes = dict(SHALLOW, batch_per_chip=1, seq_len=64)
+    cfg = config(SHALLOW)
     params = ref.init_weights(KEY, sizes)
     toks, tgts = (jnp.concatenate(x) for x in zip(*(
         ref.make_batch(KEY, sizes, r) for r in range(mesh.size))))
@@ -412,12 +406,11 @@ def test_bfloat16_in_float32s_place_fails_the_tolerances():
     """The tolerances above are tight enough to tell a precision: the
     program at bfloat16 weights and activations against the float32
     reference is far outside the loss's and the gradients'."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     low = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16), params)
     cfg = config(dtype="bfloat16")
+    l1, g1 = SEEDED.loss_and_grads
     with jax.default_matmul_precision("highest"):
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda p: jamba.loss_fn(p, toks, tgts, cfg)))(low)
     g2 = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), g2)

@@ -7,6 +7,8 @@ operators, ring attention, and gradient psums are right, a sharded step is
 bit-compatible (up to fp tolerance) with the unsharded one.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,10 +28,13 @@ def _data(cfg, batch=8, seq=16, seed=0):
     return jnp.asarray(tokens), jnp.asarray(targets)
 
 
-def _reference_run(steps=2, batch=8, seq=16, n_layers=2):
-    """Unsharded single-device ground truth (all axes disabled, f32)."""
-    cfg = llama.tiny(dtype=jnp.float32, n_layers=n_layers, dp_axis=None,
-                     tp_axis=None, sp_axis=None)
+@functools.lru_cache(maxsize=None)
+def _reference_run(steps=2, batch=8, seq=16, **kw):
+    """Unsharded single-device ground truth (all axes disabled, f32; ``kw``
+    to ``llama.tiny``): ``(losses, params)``, worked out once a process
+    for the cases that share it."""
+    cfg = llama.tiny(dtype=jnp.float32, dp_axis=None, tp_axis=None,
+                     sp_axis=None, **kw)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     opt = optax.sgd(0.1)
     opt_state = opt.init(params)
@@ -84,17 +89,7 @@ def test_ulysses_sp_matches_reference(sp, tp, heads, kv_heads):
     numerics-identical to the unsharded reference, like the ring path.
     Ulysses needs (kv_heads / tp) % sp == 0 — GQA kv travels un-repeated."""
     hkw = dict(n_heads=heads, n_kv_heads=kv_heads)
-    cfg_ref = llama.tiny(dtype=jnp.float32, dp_axis=None, tp_axis=None,
-                         sp_axis=None, **hkw)
-    params = llama.init_params(cfg_ref, jax.random.PRNGKey(0))
-    opt = optax.sgd(0.1)
-    opt_state = opt.init(params)
-    rstep = jax.jit(llama.make_train_step(cfg_ref, opt))
-    tokens, targets = _data(cfg_ref)
-    ref_losses = []
-    for _ in range(2):
-        params, opt_state, loss = rstep(params, opt_state, tokens, targets)
-        ref_losses.append(float(loss))
+    ref_losses, _ = _reference_run(**hkw)
 
     cfg = llama.tiny(dtype=jnp.float32, sp_impl="ulysses", **hkw)
     mesh = infer_mesh(8, tp=tp, sp=sp)
@@ -209,21 +204,11 @@ def test_llama_moe_matches_reference(ep, tp):
     shard mean differs from the global value — a modeling choice, not an
     implementation error); the exact-math contract covers everything
     else."""
-    kw = dict(dtype=jnp.float32, n_experts=4, capacity_factor=4.0,
-              aux_weight=0.0)
-    cfg_ref = llama.tiny(dp_axis=None, tp_axis=None, sp_axis=None, **kw)
-    params = llama.init_params(cfg_ref, jax.random.PRNGKey(0))
+    kw = dict(n_experts=4, capacity_factor=4.0, aux_weight=0.0)
+    ref_losses, ref_params = _reference_run(batch=16, **kw)
     opt = optax.sgd(0.1)
-    opt_state = opt.init(params)
-    step = jax.jit(llama.make_train_step(cfg_ref, opt))
-    tokens, targets = _data(cfg_ref, batch=16)
-    ref_losses = []
-    for _ in range(2):
-        params, opt_state, loss = step(params, opt_state, tokens, targets)
-        ref_losses.append(float(loss))
-    ref_params = params
 
-    cfg = llama.tiny(ep_axis="ep", **kw)
+    cfg = llama.tiny(dtype=jnp.float32, ep_axis="ep", **kw)
     mesh = infer_mesh(8, tp=tp, ep=ep)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     pspecs = llama.param_specs(cfg)
@@ -254,20 +239,12 @@ def test_llama_moe_pp_composes():
     (per-stage partials, psum'd over pp).  Exact-math check at
     aux_weight=0 vs the unsharded MoE run, plus an aux>0 run proving the
     composition trains (finite loss, params move)."""
-    kw = dict(dtype=jnp.float32, n_experts=4, capacity_factor=4.0,
-              aux_weight=0.0)
-    cfg_ref = llama.tiny(dp_axis=None, tp_axis=None, sp_axis=None, **kw)
-    params = llama.init_params(cfg_ref, jax.random.PRNGKey(0))
+    kw = dict(n_experts=4, capacity_factor=4.0, aux_weight=0.0)
+    ref_losses, _ = _reference_run(batch=16, **kw)
     opt = optax.sgd(0.1)
-    opt_state = opt.init(params)
-    step = jax.jit(llama.make_train_step(cfg_ref, opt))
-    tokens, targets = _data(cfg_ref, batch=16)
-    ref_losses = []
-    for _ in range(2):
-        params, opt_state, loss = step(params, opt_state, tokens, targets)
-        ref_losses.append(float(loss))
 
-    cfg = llama.tiny(ep_axis="ep", pp_axis="pp", n_microbatches=2, **kw)
+    cfg = llama.tiny(dtype=jnp.float32, ep_axis="ep", pp_axis="pp",
+                     n_microbatches=2, **kw)
     mesh = infer_mesh(8, pp=2, ep=2)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
     pspecs = llama.param_specs(cfg)
@@ -291,22 +268,21 @@ def test_llama_moe_pp_composes():
     # fails both if the carry plumbing returns 0 and if the per-microbatch
     # sum is not normalized (which would give ≈ n_microbatches × aux).
     w = 0.05
-    first_losses = {}
-    for aw in (0.0, w):
-        cfg_a = llama.tiny(ep_axis="ep", pp_axis="pp", n_microbatches=2,
-                           dtype=jnp.float32, n_experts=4,
-                           capacity_factor=4.0, aux_weight=aw)
-        params_a = llama.init_params(cfg_a, jax.random.PRNGKey(0))
-        opt_state_a = opt.init(params_a)
-        specs_a = llama.param_specs(cfg_a)
-        os_specs_a = spmd.infer_specs_like(opt_state_a, params_a, specs_a)
-        astep = spmd.make_sharded_train_step(
-            llama.make_train_step(cfg_a, opt), mesh, specs_a, os_specs_a,
-            P(("dp", "ep"), None))
-        params_a = spmd.shard_params(params_a, specs_a, mesh)
-        _, _, loss_a = astep(params_a, opt_state_a, tokens, targets)
-        first_losses[aw] = float(loss_a)
-    ratio = (first_losses[w] - first_losses[0.0]) / w
+    cfg_a = llama.tiny(ep_axis="ep", pp_axis="pp", n_microbatches=2,
+                       dtype=jnp.float32, n_experts=4,
+                       capacity_factor=4.0, aux_weight=w)
+    params_a = llama.init_params(cfg_a, jax.random.PRNGKey(0))
+    opt_state_a = opt.init(params_a)
+    specs_a = llama.param_specs(cfg_a)
+    os_specs_a = spmd.infer_specs_like(opt_state_a, params_a, specs_a)
+    astep = spmd.make_sharded_train_step(
+        llama.make_train_step(cfg_a, opt), mesh, specs_a, os_specs_a,
+        P(("dp", "ep"), None))
+    params_a = spmd.shard_params(params_a, specs_a, mesh)
+    _, _, loss_a = astep(params_a, opt_state_a, tokens, targets)
+    # at aux_weight 0 the first step above is this step: same weights, same
+    # tokens, same layout
+    ratio = (float(loss_a) - losses[0]) / w
     assert 1.0 - 1e-3 <= ratio <= 4.0 + 1e-3, ratio
 
 
@@ -327,9 +303,11 @@ def test_kv_cache_decode_matches_forward():
     # Reference: recompute the FULL forward over (prompt + generated so
     # far) with no cache; its last-position argmax must reproduce each
     # generated token.
+    # a length a program: op by op each length compiles some hundred
+    forward = jax.jit(lambda p, s: llama.forward(p, s, cfg))
     seq = prompt
     for i in range(N):
-        logits = llama.forward(params, seq, cfg)
+        logits = forward(params, seq)
         nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         np.testing.assert_array_equal(np.asarray(gen[:, i]), nxt,
                                       err_msg=f"token {i}")
@@ -424,19 +402,21 @@ def test_sampling_modes():
         np.random.RandomState(32).randint(0, cfg.vocab_size, (2, 5)),
         jnp.int32)
 
-    greedy = llama.generate(params, prompt, 4, cfg)
+    def generate(n, **kw):
+        """``llama.generate`` as one program; ``rng`` is its argument."""
+        rng = kw.pop("rng", None)
+        return jax.jit(lambda p, t, rng: llama.generate(
+            p, t, n, cfg, rng=rng, **kw))(params, prompt, rng)
+
+    greedy = generate(4)
     # Tiny temperature ≈ greedy (argmax dominates the categorical).
-    near_greedy = llama.generate(params, prompt, 4, cfg, temperature=1e-4,
-                                 rng=jax.random.PRNGKey(1))
+    near_greedy = generate(4, temperature=1e-4, rng=jax.random.PRNGKey(1))
     np.testing.assert_array_equal(np.asarray(greedy),
                                   np.asarray(near_greedy))
     # Same rng → same sample; different rng → (almost surely) different.
-    s1 = llama.generate(params, prompt, 8, cfg, temperature=5.0,
-                        rng=jax.random.PRNGKey(2))
-    s2 = llama.generate(params, prompt, 8, cfg, temperature=5.0,
-                        rng=jax.random.PRNGKey(2))
-    s3 = llama.generate(params, prompt, 8, cfg, temperature=5.0,
-                        rng=jax.random.PRNGKey(3))
+    s1 = generate(8, temperature=5.0, rng=jax.random.PRNGKey(2))
+    s2 = generate(8, temperature=5.0, rng=jax.random.PRNGKey(2))
+    s3 = generate(8, temperature=5.0, rng=jax.random.PRNGKey(3))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
     assert not np.array_equal(np.asarray(s1), np.asarray(s3))
     with pytest.raises(ValueError, match="rng"):
@@ -474,13 +454,17 @@ def test_decode_chunk_matches_step_loop():
     prompt = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, T0)), jnp.int32)
     chunk = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, Tq)), jnp.int32)
 
-    _, c0 = llama.prefill(params, llama.init_cache(cfg, B, 32), prompt, cfg)
-    cl, cc = llama.decode_chunk(params, c0, chunk, T0, cfg)
+    # a program each (the position is traced: the loop's steps share one)
+    _, c0 = jax.jit(lambda p, c, t: llama.prefill(p, c, t, cfg))(
+        params, llama.init_cache(cfg, B, 32), prompt)
+    cl, cc = jax.jit(lambda p, c, t, pos: llama.decode_chunk(
+        p, c, t, pos, cfg))(params, c0, chunk, T0)
+    step = jax.jit(lambda p, c, t, pos: llama.decode_step(p, c, t, pos, cfg))
 
     cs = c0
     step_logits = []
     for i in range(Tq):
-        li, cs = llama.decode_step(params, cs, chunk[:, i], T0 + i, cfg)
+        li, cs = step(params, cs, chunk[:, i], T0 + i)
         step_logits.append(np.asarray(li))
     np.testing.assert_allclose(np.asarray(cl),
                                np.stack(step_logits, axis=1),
@@ -523,22 +507,23 @@ def test_sliding_window_train_and_decode(monkeypatch):
     params = llama.init_params(cfg_jnp, jax.random.PRNGKey(51))
     tokens, targets = _data(cfg_jnp, batch=2, seq=24)
 
-    l_jnp = float(llama.loss_fn(params, tokens, targets, cfg_jnp))
-    l_flash = float(llama.loss_fn(params, tokens, targets, cfg_flash))
+    loss = lambda cfg: float(jax.jit(
+        lambda p: llama.loss_fn(p, tokens, targets, cfg))(params))
+    l_jnp, l_flash = loss(cfg_jnp), loss(cfg_flash)
     np.testing.assert_allclose(l_flash, l_jnp, rtol=2e-5)
     # The window changes the math (vs full causal attention).
     cfg_full = llama.tiny(use_flash=False, dtype=jnp.float32, max_seq=64,
                           dp_axis=None, tp_axis=None, sp_axis=None)
-    l_full = float(llama.loss_fn(params, tokens, targets, cfg_full))
-    assert abs(l_full - l_jnp) > 1e-6
+    assert abs(loss(cfg_full) - l_jnp) > 1e-6
 
     # Cached decode under the window == windowed full-context forward.
     prompt = tokens[:, :7]
     gen = jax.jit(lambda p, t: llama.generate(p, t, 5, cfg_jnp))(
         params, prompt)
+    forward = jax.jit(lambda p, s: llama.forward(p, s, cfg_jnp))
     seq = prompt
     for i in range(5):
-        logits = llama.forward(params, seq, cfg_jnp)
+        logits = forward(params, seq)
         nxt = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1))
         np.testing.assert_array_equal(np.asarray(gen[:, i]), nxt,
                                       err_msg=f"token {i}")
@@ -577,10 +562,12 @@ def test_rolling_cache_matches_full_cache():
     prompt = jnp.asarray(rng.randint(0, cfg_full.vocab_size, (2, 10)),
                          jnp.int32)
     N = 20                                   # ring R=12 wraps twice
-    ref = np.asarray(jax.jit(
-        lambda p, t: llama.generate(p, t, N, cfg_full))(params, prompt))
-    roll = np.asarray(jax.jit(
-        lambda p, t: llama.generate(p, t, N, cfg_roll))(params, prompt))
+
+    def generate(prompt, n, cfg):
+        return np.asarray(jax.jit(
+            lambda p, t: llama.generate(p, t, n, cfg))(params, prompt))
+
+    ref, roll = generate(prompt, N, cfg_full), generate(prompt, N, cfg_roll)
     np.testing.assert_array_equal(roll, ref)
     # Ring memory really is O(W + slack).
     c = llama.init_cache(cfg_roll, 2)
@@ -589,9 +576,8 @@ def test_rolling_cache_matches_full_cache():
     # Prompt longer than the ring.
     prompt2 = jnp.asarray(rng.randint(0, cfg_full.vocab_size, (1, 20)),
                           jnp.int32)
-    ref2 = np.asarray(llama.generate(params, prompt2, 6, cfg_full))
-    roll2 = np.asarray(llama.generate(params, prompt2, 6, cfg_roll))
-    np.testing.assert_array_equal(roll2, ref2)
+    np.testing.assert_array_equal(generate(prompt2, 6, cfg_roll),
+                                  generate(prompt2, 6, cfg_full))
 
     # Prompt SHORTER than the window: never-written ring slots derive
     # negative positions and must be masked — qpos-W is negative too in
@@ -599,14 +585,13 @@ def test_rolling_cache_matches_full_cache():
     # review-caught dilution bug).
     prompt3 = jnp.asarray(rng.randint(0, cfg_full.vocab_size, (2, 3)),
                           jnp.int32)
-    ref3 = np.asarray(llama.generate(params, prompt3, 8, cfg_full))
-    roll3 = np.asarray(llama.generate(params, prompt3, 8, cfg_roll))
-    np.testing.assert_array_equal(roll3, ref3)
+    np.testing.assert_array_equal(generate(prompt3, 8, cfg_roll),
+                                  generate(prompt3, 8, cfg_full))
 
     # Speculative decoding on the rolling cache (chunk 3 <= slack).
     draft = llama.init_params(cfg_full, jax.random.PRNGKey(63))
-    spec = np.asarray(llama.speculative_generate(
-        params, draft, prompt, N, cfg_roll, n_draft=2))
+    spec = np.asarray(jax.jit(lambda p, d, t: llama.speculative_generate(
+        p, d, t, N, cfg_roll, n_draft=2))(params, draft, prompt))
     np.testing.assert_array_equal(spec, ref)
 
     # Chunks beyond the slack are rejected (their earlier rows would
@@ -622,11 +607,9 @@ def test_rolling_cache_matches_full_cache():
                                 rolling_slack=slack, **base)
     with pytest.raises(ValueError, match="slots"):
         llama.generate(params, prompt, 30, cfg_small)
-    long_out = llama.generate(params, prompt, 30, cfg_small_roll)
+    long_out = generate(prompt, 30, cfg_small_roll)
     assert long_out.shape == (2, 30)
-    np.testing.assert_array_equal(
-        np.asarray(long_out[:, :N]),
-        np.asarray(llama.generate(params, prompt, N, cfg_full)))
+    np.testing.assert_array_equal(long_out[:, :N], ref)
 
 
 def test_kv_cache_budget_enforced():

@@ -65,7 +65,8 @@ def test_resnet18_trains_with_syncbn():
     cfg = resnet.ResNetConfig(depth=18, num_classes=10, width=16,
                               compute_dtype=jnp.float32)
     mesh = make_mesh({"hvd": 8})
-    params, stats = resnet.init_params(cfg, jax.random.PRNGKey(0))
+    params, stats = jax.jit(lambda k: resnet.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
     opt = optax.sgd(0.05, momentum=0.9)
     opt_state = opt.init(params)
     step = resnet.make_sharded_train_step(cfg, opt, mesh)
@@ -85,7 +86,8 @@ def test_resnet50_forward_shape():
     from horovod_tpu.models import resnet
     cfg = resnet.ResNetConfig(depth=50, num_classes=1000, width=8,
                               compute_dtype=jnp.float32, sync_bn_axis=None)
-    params, stats = resnet.init_params(cfg, jax.random.PRNGKey(0))
+    params, stats = jax.jit(lambda k: resnet.init_params(cfg, k))(
+        jax.random.PRNGKey(0))
     x, _ = resnet.synthetic_batch(2, image_size=64)
     logits, new_stats = jax.jit(
         lambda p, s, x: resnet.forward(p, s, x, cfg, train=False))(
@@ -187,16 +189,16 @@ def test_llama_remat_layers_matches():
     toks = jnp.asarray(np.random.RandomState(0).randint(0, 128, (2, 33)),
                        jnp.int32)
 
-    out = llama.forward(params, toks, cfg)
-    out_r = llama.forward(params, toks, cfg_r)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_r))
+    forward = lambda c: jax.jit(lambda p: llama.forward(p, toks, c))(params)
+    np.testing.assert_array_equal(np.asarray(forward(cfg)),
+                                  np.asarray(forward(cfg_r)))
 
     def loss(p, c):
         lg = llama.forward(p, toks, c)
         return jnp.mean((lg - 1.0) ** 2)
 
-    g = jax.grad(lambda p: loss(p, cfg))(params)
-    g_r = jax.grad(lambda p: loss(p, cfg_r))(params)
+    g = jax.jit(jax.grad(lambda p: loss(p, cfg)))(params)
+    g_r = jax.jit(jax.grad(lambda p: loss(p, cfg_r)))(params)
     for a, b in zip(jax.tree_util.tree_leaves(g),
                     jax.tree_util.tree_leaves(g_r)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -300,9 +302,11 @@ def test_gpt2_generate_matches_full_forward():
     prompt = jnp.asarray(np.random.RandomState(0).randint(0, 256, (2, 8)),
                          jnp.int32)
     out = gpt2.generate(params, prompt, 6, cfg)
+    # a length a program: op by op each length compiles some hundred
+    forward = jax.jit(lambda p, s: gpt2.forward(p, s, cfg))
     seq = prompt
     for _ in range(6):
-        lg = gpt2.forward(params, seq, cfg)
+        lg = forward(params, seq)
         nxt = jnp.argmax(lg[:, -1], axis=-1).astype(jnp.int32)
         seq = jnp.concatenate([seq, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(seq[:, 8:]))

@@ -24,8 +24,10 @@ if ROOT not in sys.path:
 
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import nemotron_h as ref           # noqa: E402
+from family import Seeded, planted, worst_rel               # noqa: E402
 from horovod_tpu.compat import shard_map                    # noqa: E402
-from horovod_tpu.models import gated_delta, mamba2, moe, nemotron_h  # noqa: E402
+from horovod_tpu.models import (blocks, gated_delta, mamba2, moe,  # noqa: E402
+                                nemotron_h)
 
 # one period (MEMEMEM*EME); the configuration file's ``tiny`` preset
 SIZES = dict(hidden_size=64, num_hidden_layers=11,
@@ -44,16 +46,22 @@ KEY = jax.random.PRNGKey(5)
 LOGITS_TOL, LOSS_TOL, GRAD_TOL = 2e-4, 1e-5, 5e-4
 
 
-def worst_rel(a, b):
-    return max(float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12))
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
+SEEDED = Seeded(ref, SIZES, KEY)
 
 
-def seeded(sizes=SIZES):
-    params = ref.init_weights(KEY, sizes)
-    toks, tgts = ref.make_batch(KEY, sizes, 0)
-    return params, toks, tgts
+def with_a_large_selection_bias(params):
+    """A selection bias large enough that weighing by it shows."""
+    for p in params["layers"]:
+        if "moe" in p:
+            p["moe"]["router_bias"] = 30.0 * p["moe"]["router_bias"]
+    return params
+
+
+BIASED = Seeded(ref, SIZES, KEY, change=with_a_large_selection_bias)
+# Where depth is not what a test asserts (Adam's wiring leaf by leaf, the
+# gradient exchange, the mean over ranks), one layer of each kind:
+# a step's compile time follows its layers.
+SHALLOW = dict(SIZES, hybrid_override_pattern="ME*", num_hidden_layers=3)
 
 
 # -------------------------------------------------- the chunked recurrence
@@ -90,8 +98,8 @@ def test_the_chunked_form_is_the_token_by_token_recurrence(t):
     chunk and one that forgets inside a chunk."""
     inputs = scan_inputs(t)
     with jax.default_matmul_precision("highest"):
-        want = token_by_token(*inputs)
-        got = mamba2.chunked_ssd(*inputs, chunk=32)
+        want = jax.jit(token_by_token)(*inputs)
+        got = jax.jit(lambda *a: mamba2.chunked_ssd(*a, chunk=32))(*inputs)
     assert got.shape == want.shape == (2, t, 4, 8)
     assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * float(
         jnp.max(jnp.abs(want)))
@@ -106,11 +114,12 @@ def test_the_chunked_forms_gradients_are_the_recurrences(t):
     inputs = scan_inputs(t, seed=1)
     weigh = jax.random.normal(jax.random.PRNGKey(9), (2, t, 4, 8))
     with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda *a: jnp.sum(token_by_token(*a) * weigh),
-                        argnums=range(5))(*inputs)
-        got = jax.grad(lambda *a: jnp.sum(
+        want = jax.jit(jax.grad(
+            lambda *a: jnp.sum(token_by_token(*a) * weigh),
+            argnums=range(5)))(*inputs)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
             mamba2.chunked_ssd(*a, chunk=32) * weigh),
-            argnums=range(5))(*inputs)
+            argnums=range(5)))(*inputs)
     assert worst_rel(got, want) <= 1e-4
 
 
@@ -135,10 +144,10 @@ def test_a_group_at_a_time_is_all_groups_at_once(token_heads, parts):
     grouped = mamba2.by_state_groups(mamba2.chunked_ssd, token_heads)
     loss = lambda scan: lambda *a: jnp.sum(jnp.sin(scan(*a, 32)))
     with jax.default_matmul_precision("highest"):
-        want, g_want = jax.value_and_grad(loss(mamba2.chunked_ssd),
-                                          argnums=range(5))(*inputs)
-        got, g_got = jax.value_and_grad(loss(grouped),
-                                        argnums=range(5))(*inputs)
+        want, g_want = jax.jit(jax.value_and_grad(
+            loss(mamba2.chunked_ssd), argnums=range(5)))(*inputs)
+        got, g_got = jax.jit(jax.value_and_grad(
+            loss(grouped), argnums=range(5)))(*inputs)
     text = jax.make_jaxpr(lambda *a: grouped(*a, 32))(*inputs).pretty_print()
     assert ("scan" in text.split("cumsum")[0]) == (parts > 1)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
@@ -184,14 +193,15 @@ def test_the_convolution_is_causal_depthwise_and_takes_a_bias():
 def test_the_mixer_is_the_references_layer():
     """``mamba2.mamba2`` against the reference's layer (groups of columns,
     the token-by-token recurrence) on the reference's own draw."""
-    params, _, _ = seeded()
+    params, _, _ = SEEDED
     p = params["layers"][0]["ssm"]
     u = jax.random.normal(KEY, (2, 100, 64))
     cfg = nemotron_h.tiny()
     q = ref.quantizer("float32")
     with jax.default_matmul_precision("highest"):
-        want = ref.mamba2(p, u, SIZES, ref._matmul(q), q)
-        got = mamba2.mamba2(u, p, cfg.ssm_dims())
+        want = jax.jit(lambda p, u: ref.mamba2(
+            p, u, SIZES, ref._matmul(q), q))(p, u)
+        got = jax.jit(lambda u, p: mamba2.mamba2(u, p, cfg.ssm_dims()))(u, p)
     assert float(jnp.max(jnp.abs(got - want))) <= 1e-4 * float(
         jnp.max(jnp.abs(want)))
 
@@ -398,21 +408,18 @@ def test_the_published_sizes_count_120b_parameters():
 
 @pytest.mark.parametrize("use_flash, token_heads", [
     (False, 1 << 17), (True, 1 << 17), (False, 2 * 100 * 4)])
-def test_logits_loss_and_gradients_are_the_references(monkeypatch, use_flash,
-                                                      token_heads):
+def test_logits_loss_and_gradients_are_the_references(use_flash, token_heads):
     """One period in float32 on seeded weights (the reference's own draw:
     norm weights away from one, the published decays, a selection bias),
     3.1 chunks a sequence; with the Pallas flash kernel interpreted at 4
     query heads on 2 key heads, and with the recurrence one group at a
     time."""
-    monkeypatch.setattr(nemotron_h, "SCAN_TOKEN_HEADS", token_heads)
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
+    want, (l1, g1) = SEEDED.logits, SEEDED.loss_and_grads
     cfg = nemotron_h.tiny(use_flash=use_flash)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, toks, SIZES))(params)
+    with planted(nemotron_h, "SCAN_TOKEN_HEADS", token_heads), \
+            jax.default_matmul_precision("highest"):
         got = jax.jit(lambda p: nemotron_h.forward(p, toks, cfg))(params)
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda p: nemotron_h.loss_fn(p, toks, tgts, cfg)))(params)
     assert got.shape == (2, 100, 256) and got.dtype == jnp.float32
@@ -448,21 +455,15 @@ def weights_with_the_bias(route):
     (mamba2, "mamba2", no_skip),
     (mamba2, "gated_group_norm", norm_over_all_channels),
     (moe, "dropless_route", weights_with_the_bias),
-    (nemotron_h, "local_flash_attention", None),
+    (blocks, "local_flash_attention", None),
 ])
-def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
-                                                     name, broken):
+def test_the_layers_wiring_is_what_the_reference_has(module, name, broken):
     """The skip ``D x`` left out, the gated norm over all channels instead
     of a group's, the router's weights taken from ``s + b``, a rotary
     applied: each moves the logits far beyond the tolerance that the sound
     model keeps."""
     from horovod_tpu.models import qwen3_next
-    jax.clear_caches()      # a region traced by an earlier test is kept
-    params, toks, _ = seeded()
-    # a selection bias large enough that weighing by it shows
-    for p in params["layers"]:
-        if "moe" in p:
-            p["moe"]["router_bias"] = 30.0 * p["moe"]["router_bias"]
+    params, toks, _ = BIASED
     if broken is None:
         attend = module.local_flash_attention
         turn = lambda y: qwen3_next._partial_rope(y, y.shape[-1], 1e4)
@@ -470,11 +471,10 @@ def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
             turn(q), turn(k), v, causal=causal))
     program = lambda: jax.jit(lambda p: nemotron_h.forward(
         p, toks, nemotron_h.tiny()))(params)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, toks, SIZES))(params)
-        sound = program()
-        monkeypatch.setattr(module, name, broken(getattr(module, name)))
-        jax.clear_caches()
+    want = BIASED.logits
+    sound = BIASED.kept("the sound program's logits", lambda *_: program())
+    with planted(module, name, broken(getattr(module, name))), \
+            jax.default_matmul_precision("highest"):
         got = program()
     scale = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(jnp.abs(sound - want))) <= LOGITS_TOL * scale
@@ -482,7 +482,7 @@ def test_the_layers_wiring_is_what_the_reference_has(monkeypatch, module,
 
 
 def test_the_counters_read_the_held_load_and_the_decays():
-    params, toks, _ = seeded()
+    params, toks, _ = SEEDED
     cfg = nemotron_h.tiny()
     load = jax.jit(lambda p: nemotron_h.expert_load(p, toks, cfg))(params)
     assert load.shape == (5, 4) and load.dtype == jnp.int32
@@ -513,14 +513,15 @@ def test_three_optimizer_steps_are_the_references():
     parameters' change, leaf by leaf (what ``compare.py`` is given)."""
     from benchmark import compare
     from benchmark.reference.common import leaf_norms
-    sizes = dict(SIZES, batch_per_chip=1)
+    sizes = dict(SHALLOW, batch_per_chip=1)
     reference = ref.follow(sizes, KEY, 1, 3)
     params = ref.init_weights(KEY, sizes)
     toks, tgts = ref.make_batch(KEY, sizes, 0)
     adam = ref.ADAM
     opt = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
                      eps=adam["eps"])
-    step = jax.jit(nemotron_h.make_train_step(nemotron_h.tiny(), opt))
+    step = jax.jit(nemotron_h.make_train_step(
+        nemotron_h.tiny(pattern="ME*"), opt))
     state, p, losses = opt.init(params), params, []
     with jax.default_matmul_precision("highest"):
         for i in range(3):
@@ -544,8 +545,8 @@ def test_the_train_step_under_shard_map_is_the_unsharded_step():
     whole batch."""
     hvd.init()
     mesh = hvd.mesh()
-    sizes = dict(SIZES, batch_per_chip=1, seq_len=64)
-    cfg = nemotron_h.tiny()
+    sizes = dict(SHALLOW, batch_per_chip=1, seq_len=64)
+    cfg = nemotron_h.tiny(pattern="ME*")
     params = ref.init_weights(KEY, sizes)
     toks, tgts = (jnp.concatenate(x) for x in zip(*(
         ref.make_batch(KEY, sizes, r) for r in range(mesh.size))))

@@ -6,6 +6,7 @@ wiring, the whole model's logits, loss and gradients against that
 reference, and the train step under ``shard_map`` with the in-graph
 ``DistributedOptimizer``."""
 
+import contextlib
 import os
 import sys
 
@@ -23,6 +24,7 @@ if ROOT not in sys.path:
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import olmo_hybrid as ref          # noqa: E402
 from benchmark.reference import qwen3_next as qwen_ref      # noqa: E402
+from family import Seeded, planted, worst_rel               # noqa: E402
 from horovod_tpu.compat import shard_map                    # noqa: E402
 from horovod_tpu.models import gated_delta, olmo_hybrid, qwen3_next  # noqa: E402
 
@@ -40,10 +42,7 @@ KEY = jax.random.PRNGKey(5)
 LOGITS_TOL, LOSS_TOL, GRAD_TOL = 2e-4, 1e-5, 5e-4
 
 
-def worst_rel(a, b):
-    return max(float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12))
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
+SEEDED = Seeded(ref, SIZES, KEY)
 
 
 # ------------------------------------------------------- the chunked rule
@@ -73,7 +72,8 @@ def test_chunked_rule_is_the_recurrence_with_beta_up_to_two(t, beta, dk, dv):
     args = rule_inputs(t, beta, dk=dk, dv=dv)
     with jax.default_matmul_precision("highest"):
         want = qwen_ref.recurrence(*args)
-        got = gated_delta.chunked_gated_delta_rule(*args, chunk=64)
+        got = jax.jit(lambda *a: gated_delta.chunked_gated_delta_rule(
+            *a, chunk=64))(*args)
     assert got.shape == want.shape == (2, t, 3, dv)
     assert float(jnp.max(jnp.abs(got - want))) <= 5e-5 * float(
         jnp.max(jnp.abs(want)))
@@ -84,11 +84,12 @@ def test_chunked_rule_has_the_recurrences_gradients_with_beta_up_to_two(beta):
     args = rule_inputs(150, beta)
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 3, 24))
     with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda *a: jnp.sum(qwen_ref.recurrence(*a) * weight),
-                        argnums=range(5))(*args)
-        got = jax.grad(lambda *a: jnp.sum(
+        want = jax.jit(jax.grad(
+            lambda *a: jnp.sum(qwen_ref.recurrence(*a) * weight),
+            argnums=range(5)))(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
             gated_delta.chunked_gated_delta_rule(*a, chunk=64) * weight),
-            argnums=range(5))(*args)
+            argnums=range(5)))(*args)
     assert worst_rel(got, want) <= 5e-4
 
 
@@ -123,23 +124,17 @@ def test_the_rule_by_head_groups_is_the_rule(token_heads, groups):
     grouped = gated_delta.by_head_groups(rule, token_heads)
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 100, 6, 24))
     with jax.default_matmul_precision("highest"):
-        want = jax.value_and_grad(lambda *a: jnp.sum(
+        want = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
             gated_delta.chunked_gated_delta_rule(*a, 64) * weight),
-            argnums=range(5))(*args)
-        got = jax.value_and_grad(lambda *a: jnp.sum(
-            grouped(*a, 64) * weight), argnums=range(5))(*args)
+            argnums=range(5)))(*args)
+        got = jax.jit(jax.value_and_grad(lambda *a: jnp.sum(
+            grouped(*a, 64) * weight), argnums=range(5)))(*args)
     assert set(calls) == {6 // groups}
     assert abs(float(got[0]) - float(want[0])) <= 1e-5 * abs(float(want[0]))
     assert worst_rel(got[1], want[1]) <= 1e-4
 
 
 # ------------------------------------------------------------- the model
-def seeded():
-    params = ref.init_weights(KEY, SIZES)
-    toks, tgts = ref.make_batch(KEY, SIZES, 0)
-    return params, toks, tgts
-
-
 def test_the_weights_have_the_programs_layout_and_pattern():
     cfg = olmo_hybrid.tiny(n_layers=8)
     assert cfg.layer_types == ("linear_attention",) * 3 + (
@@ -166,21 +161,18 @@ def test_the_published_sizes_count_7_4b_parameters():
 
 @pytest.mark.parametrize("use_flash, token_heads", [
     (False, 1 << 17), (True, 1 << 17), (False, 2 * 200 * 2)])
-def test_logits_loss_and_gradients_are_the_references(monkeypatch, use_flash,
-                                                      token_heads):
+def test_logits_loss_and_gradients_are_the_references(use_flash, token_heads):
     """One period in float32 on seeded weights (the reference's own draw:
     norm weights away from one, decays up to 0.999, half of the betas past
     1), 3.1 chunks a sequence; with the Pallas flash kernel interpreted,
     and with the delta rule two heads at a time (its result saved by
     name)."""
-    monkeypatch.setattr(olmo_hybrid, "RULE_TOKEN_HEADS", token_heads)
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
+    want, (l1, g1) = SEEDED.logits, SEEDED.loss_and_grads
     cfg = olmo_hybrid.tiny(use_flash=use_flash)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p: ref.forward(p, toks, SIZES))(params)
+    with planted(olmo_hybrid, "RULE_TOKEN_HEADS", token_heads), \
+            jax.default_matmul_precision("highest"):
         got = jax.jit(lambda p: olmo_hybrid.forward(p, toks, cfg))(params)
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda p: olmo_hybrid.loss_fn(p, toks, tgts, cfg)))(params)
     assert got.shape == (2, 200, 256) and got.dtype == jnp.float32
@@ -208,35 +200,35 @@ def with_rotary(attend):
 
 
 @pytest.mark.parametrize("fault", ["norm_first", "rotary", "beta_in_0_1"])
-def test_the_blocks_wiring_is_what_the_reference_has(monkeypatch, fault):
+def test_the_blocks_wiring_is_what_the_reference_has(fault):
     """A norm moved before the sublayer, a rotary applied, beta left in
     (0, 1): each moves the logits far beyond the tolerance that the sound
     model keeps."""
-    jax.clear_caches()      # a region traced by an earlier test is kept
-    params, toks, _ = seeded()
-    cfg = olmo_hybrid.tiny()
+    params, toks, _ = SEEDED
+    want = SEEDED.logits
+    cfg, broken = olmo_hybrid.tiny(), contextlib.nullcontext()
     if fault == "norm_first":
-        monkeypatch.setattr(olmo_hybrid, "_mixer_block", norm_first)
+        broken = planted(olmo_hybrid, "_mixer_block", norm_first)
     elif fault == "rotary":
-        monkeypatch.setattr(olmo_hybrid, "local_flash_attention", with_rotary(
+        broken = planted(olmo_hybrid, "local_flash_attention", with_rotary(
             olmo_hybrid.local_flash_attention))
-    else:
+    else:       # another config is another trace
         cfg = olmo_hybrid.tiny(allow_neg_eigval=False)
-    with jax.default_matmul_precision("highest"):
-        want = ref.forward(params, toks, SIZES)
-        got = olmo_hybrid.forward(params, toks, cfg)
+    with broken, jax.default_matmul_precision("highest"):
+        got = jax.jit(lambda p: olmo_hybrid.forward(p, toks, cfg))(params)
     assert float(jnp.max(jnp.abs(got - want))) > 100 * LOGITS_TOL * float(
         jnp.max(jnp.abs(want)))
 
 
 def test_beta_stats_reads_the_share_past_one_and_the_largest():
-    params, toks, _ = seeded()
-    share, largest = olmo_hybrid.beta_stats(params, toks, olmo_hybrid.tiny())
+    params, toks, _ = SEEDED
+    stats = lambda cfg: jax.jit(
+        lambda p: olmo_hybrid.beta_stats(p, toks, cfg))(params)
+    share, largest = stats(olmo_hybrid.tiny())
     assert share.shape == largest.shape == (3,)
     assert (np.asarray(share) > 0.35).all() and (np.asarray(share) < 0.65).all()
     assert (np.asarray(largest) > 1.8).all() and (np.asarray(largest) < 2).all()
-    share, largest = olmo_hybrid.beta_stats(
-        params, toks, olmo_hybrid.tiny(allow_neg_eigval=False))
+    share, largest = stats(olmo_hybrid.tiny(allow_neg_eigval=False))
     assert not np.asarray(share).any() and (np.asarray(largest) < 1).all()
 
 
@@ -247,8 +239,11 @@ def test_the_train_step_under_shard_map_is_the_unsharded_step():
     whole batch."""
     hvd.init()
     mesh = hvd.mesh()
-    sizes = dict(SIZES, batch_per_chip=1, seq_len=96)
-    cfg = olmo_hybrid.tiny()
+    # the gradient exchange is not a matter of depth: a Gated DeltaNet
+    # layer and an attention layer, and a step that compiles in half the time
+    sizes = dict(SIZES, num_hidden_layers=2, full_attention_interval=2,
+                 batch_per_chip=1, seq_len=96)
+    cfg = olmo_hybrid.tiny(n_layers=2, full_attention_interval=2)
     params = ref.init_weights(KEY, sizes)
     toks, tgts = (jnp.concatenate(x) for x in zip(*(
         ref.make_batch(KEY, sizes, r) for r in range(mesh.size))))

@@ -23,6 +23,7 @@ if ROOT not in sys.path:
 
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import ouro as ref                 # noqa: E402
+from family import Seeded, worst_rel                        # noqa: E402
 from horovod_tpu.compat import shard_map                    # noqa: E402
 from horovod_tpu.models import ouro                         # noqa: E402
 
@@ -37,16 +38,7 @@ KEY = jax.random.PRNGKey(5)
 LOSS_TOL, GRAD_TOL = 1e-5, 5e-4
 
 
-def worst_rel(a, b):
-    return max(float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12))
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
-
-
-def seeded(sizes=SIZES):
-    params = ref.init_weights(KEY, sizes)
-    toks, tgts = ref.make_batch(KEY, sizes, 0)
-    return params, toks, tgts
+SEEDED = Seeded(ref, SIZES, KEY)
 
 
 def config(**kw):
@@ -80,14 +72,14 @@ def test_loss_each_passes_loss_and_gradients_are_the_references(use_flash):
     """Four passes over three layers in float32 on seeded weights (the
     reference's own draw: norm weights away from one, the gates spread
     round a half), with the Pallas flash kernel interpreted too."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config(use_flash=use_flash)
+    nll, z = SEEDED.kept("exits", lambda w, toks, tgts: jax.jit(
+        lambda w: ref.exits(w, toks, tgts, SIZES))(w))
+    l1, g1 = SEEDED.loss_and_grads
     with jax.default_matmul_precision("highest"):
-        nll, z = jax.jit(lambda p: ref.exits(p, toks, tgts, SIZES))(params)
         logits, p = jax.jit(lambda w: ouro.forward(w, toks, cfg))(params)
         stats = jax.jit(lambda w: ouro.exit_stats(w, toks, tgts, cfg))(params)
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda w: ref.loss_fn(w, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda w: ouro.loss_fn(w, toks, tgts, cfg)))(params)
     assert logits.shape == (4, 2, 70, 256) and logits.dtype == jnp.float32
@@ -127,13 +119,13 @@ def test_one_pass_is_the_plain_stack():
     """With ``total_ut_steps`` 1 the only exit takes all of the
     probability: the loss is the plain stack's mean cross-entropy, and the
     gate gets no gradient."""
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config(total_ut_steps=1)
     with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(
-            lambda w: ouro.loss_fn(w, toks, tgts, cfg))(params)
-        want, plain = jax.value_and_grad(lambda w: jnp.mean(plain_exits(
-            [w["layers"]], w, toks, tgts, cfg)[0]))(params)
+        loss, grads = jax.jit(jax.value_and_grad(
+            lambda w: ouro.loss_fn(w, toks, tgts, cfg)))(params)
+        want, plain = jax.jit(jax.value_and_grad(lambda w: jnp.mean(
+            plain_exits([w["layers"]], w, toks, tgts, cfg)[0])))(params)
     assert abs(float(loss) - float(want)) <= LOSS_TOL * float(want)
     assert not np.asarray(grads["gate"]["w"]).any()
     assert worst_rel(grads["layers"], plain["layers"]) <= GRAD_TOL
@@ -141,7 +133,7 @@ def test_one_pass_is_the_plain_stack():
 
 
 def test_a_shared_weights_gradient_is_the_sum_over_four_unshared_copies():
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config()
     copies = [params["layers"]] * cfg.total_ut_steps
 
@@ -150,9 +142,9 @@ def test_a_shared_weights_gradient_is_the_sum_over_four_unshared_copies():
             *plain_exits(stacks, params, toks, tgts, cfg), cfg.entropy_beta)
 
     with jax.default_matmul_precision("highest"):
-        of_copy = jax.grad(unshared)(copies)
-        shared = jax.grad(lambda w: ouro.loss_fn(
-            dict(params, layers=w), toks, tgts, cfg))(params["layers"])
+        of_copy = jax.jit(jax.grad(unshared))(copies)
+        shared = jax.jit(jax.grad(lambda w: ouro.loss_fn(
+            dict(params, layers=w), toks, tgts, cfg)))(params["layers"])
     # every pass adds something of its own
     norms = [float(jnp.linalg.norm(g["w_up"])) for g in of_copy]
     assert min(norms) > 0 and len(set(np.round(norms, 6))) == 4
@@ -176,13 +168,14 @@ def test_the_exit_distribution_sums_to_one_and_the_last_takes_the_rest():
 
 
 def test_the_entropy_term_lowers_the_loss_by_beta_times_the_entropy():
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     cfg = config()
     with jax.default_matmul_precision("highest"):
-        stats = ouro.exit_stats(params, toks, tgts, cfg)
-        with_it = ouro.loss_fn(params, toks, tgts, cfg)
-        without = ouro.loss_fn(params, toks, tgts,
-                               dataclasses.replace(cfg, entropy_beta=0.0))
+        stats = jax.jit(lambda w: ouro.exit_stats(w, toks, tgts, cfg))(params)
+        loss = lambda cfg: jax.jit(
+            lambda w: ouro.loss_fn(w, toks, tgts, cfg))(params)
+        with_it = loss(cfg)
+        without = loss(dataclasses.replace(cfg, entropy_beta=0.0))
     entropy = float(stats["entropy_mean"])
     assert 0 < entropy <= np.log(4) + 1e-6
     assert abs(float(without) - float(with_it) - 0.05 * entropy) <= 1e-5
@@ -193,7 +186,7 @@ def test_the_entropy_term_lowers_the_loss_by_beta_times_the_entropy():
 
 @pytest.mark.parametrize("block", [16, 32, 70, 4096])
 def test_the_head_in_blocks_is_the_head(monkeypatch, block):
-    params, toks, tgts = seeded()
+    params, toks, tgts = SEEDED
     x = params["embed"][toks]
     monkeypatch.setattr(ouro, "HEAD_TOKENS", 4096)
     want, g1 = jax.value_and_grad(lambda w: jnp.sum(ouro._token_nll(
@@ -214,7 +207,7 @@ def test_the_train_step_under_shard_map_is_the_unsharded_step():
     mesh = hvd.mesh()
     sizes = dict(SIZES, batch_per_chip=1, seq_len=48)
     cfg = config()
-    params = ref.init_weights(KEY, sizes)
+    params, _, _ = SEEDED       # the draw does not read the batch's shape
     toks, tgts = (jnp.concatenate(x) for x in zip(*(
         ref.make_batch(KEY, sizes, r) for r in range(mesh.size))))
     inner = optax.sgd(0.1)

@@ -56,26 +56,24 @@ def test_ring_flash_matches_local(causal, monkeypatch):
         return ra.ring_attention(q, k, v, axis_name="sp", causal=causal,
                                  use_flash=True)
 
-    out = jax.jit(shard_map(
-        ring, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
-        out_specs=P(None, "sp"), check_vma=False))(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
     def loss_ring(q, k, v):
+        """``(loss, out)``: the values ride the gradient's program."""
         def f(q, k, v):
             o = ring(q, k, v)
-            return _lax.psum(jnp.sum(o.astype(jnp.float32) ** 2), "sp")
+            return _lax.psum(jnp.sum(o.astype(jnp.float32) ** 2), "sp"), o
         return jax.jit(shard_map(
-            f, mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(),
-            check_vma=False))(q, k, v)
+            f, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
+            out_specs=(P(), P(None, "sp")), check_vma=False))(q, k, v)
 
     def loss_ref(q, k, v):
         return jnp.sum(
             ra.local_flash_attention(q, k, v, causal=causal)
             .astype(jnp.float32) ** 2)
 
-    gf = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    (_, out), gf = jax.value_and_grad(loss_ring, argnums=(0, 1, 2),
+                                      has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -104,19 +102,14 @@ def test_ring_flash_gqa(causal):
         return ra.ring_attention(q, k, v, axis_name="sp", causal=causal,
                                  use_flash=True)
 
-    out = jax.jit(shard_map(
-        ring, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
-        out_specs=P(None, "sp"), check_vma=False))(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-5)
-
     def loss_ring(q, k, v):
+        """``(loss, out)``: the values ride the gradient's program."""
         def f(q, k, v):
-            return _lax.psum(
-                jnp.sum(ring(q, k, v).astype(jnp.float32) ** 2), "sp")
+            o = ring(q, k, v)
+            return _lax.psum(jnp.sum(o.astype(jnp.float32) ** 2), "sp"), o
         return jax.jit(shard_map(
-            f, mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(),
-            check_vma=False))(q, k, v)
+            f, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
+            out_specs=(P(), P(None, "sp")), check_vma=False))(q, k, v)
 
     def loss_ref(q, k, v):
         kr = jnp.repeat(k, H // K, axis=2)
@@ -124,7 +117,10 @@ def test_ring_flash_gqa(causal):
         return jnp.sum(ra.local_flash_attention(q, kr, vr, causal=causal)
                        .astype(jnp.float32) ** 2)
 
-    gf = jax.grad(loss_ring, argnums=(0, 1, 2))(q, k, v)
+    (_, out), gf = jax.value_and_grad(loss_ring, argnums=(0, 1, 2),
+                                      has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

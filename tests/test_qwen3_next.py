@@ -4,6 +4,7 @@ that is told its share against a plain loop over experts (the benchmark's
 float32 reference, which shares no code with the program), and the whole
 model's loss and gradients against that reference."""
 
+import functools
 import os
 import sys
 
@@ -17,6 +18,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.reference import qwen3_next as ref      # noqa: E402
+from family import Seeded, worst_rel                    # noqa: E402
 from horovod_tpu.models import moe, qwen3_next          # noqa: E402
 
 # one period, 16 experts of which 4 are held, top-2: the configuration
@@ -38,10 +40,7 @@ def mm(spec, a, b):
     return jnp.einsum(spec, a, b)
 
 
-def worst_rel(a, b):
-    return max(float(jnp.max(jnp.abs(x - y)) / (jnp.max(jnp.abs(y)) + 1e-12))
-               for x, y in zip(jax.tree_util.tree_leaves(a),
-                               jax.tree_util.tree_leaves(b)))
+SEEDED = Seeded(ref, SIZES, KEY)
 
 
 # ------------------------------------------------------- the chunked rule
@@ -66,7 +65,8 @@ def test_chunked_delta_rule_is_the_token_by_token_recurrence(t, decay):
     args = rule_inputs(t, decay)
     with jax.default_matmul_precision("highest"):
         want = ref.recurrence(*args)
-        got = qwen3_next.chunked_gated_delta_rule(*args, chunk=64)
+        got = jax.jit(lambda *a: qwen3_next.chunked_gated_delta_rule(
+            *a, chunk=64))(*args)
     assert got.shape == want.shape == (2, t, 3, 8)
     assert float(jnp.max(jnp.abs(got - want))) <= 2e-5 * float(
         jnp.max(jnp.abs(want)))
@@ -76,11 +76,12 @@ def test_chunked_delta_rule_has_the_recurrences_gradients():
     args = rule_inputs(150, 0.99)
     weight = jax.random.normal(jax.random.PRNGKey(9), (2, 150, 3, 8))
     with jax.default_matmul_precision("highest"):
-        want = jax.grad(lambda *a: jnp.sum(ref.recurrence(*a) * weight),
-                        argnums=range(5))(*args)
-        got = jax.grad(lambda *a: jnp.sum(
+        want = jax.jit(jax.grad(
+            lambda *a: jnp.sum(ref.recurrence(*a) * weight),
+            argnums=range(5)))(*args)
+        got = jax.jit(jax.grad(lambda *a: jnp.sum(
             qwen3_next.chunked_gated_delta_rule(*a, chunk=64) * weight),
-            argnums=range(5))(*args)
+            argnums=range(5)))(*args)
     assert worst_rel(got, want) <= 1e-4
 
 
@@ -88,21 +89,27 @@ def test_the_state_carried_between_chunks_matters():
     """With the state dropped at each chunk's edge the outputs past the
     first chunk are far off: the comparison is not vacuous."""
     q, k, v, g, beta = rule_inputs(128, 0.999)
-    whole = qwen3_next.chunked_gated_delta_rule(q, k, v, g, beta, chunk=64)
-    alone = qwen3_next.chunked_gated_delta_rule(
-        q[:, 64:], k[:, 64:], v[:, 64:], g[:, 64:], beta[:, 64:], chunk=64)
+    rule = jax.jit(lambda *a: qwen3_next.chunked_gated_delta_rule(
+        *a, chunk=64))
+    whole = rule(q, k, v, g, beta)
+    alone = rule(q[:, 64:], k[:, 64:], v[:, 64:], g[:, 64:], beta[:, 64:])
     assert float(jnp.max(jnp.abs(whole[:, 64:] - alone))) > 0.1 * float(
         jnp.max(jnp.abs(whole)))
 
 
 # ------------------------------------------------------- the expert layer
+@functools.lru_cache(maxsize=None)
+def all_experts(seed):
+    """An expert layer's weights for ALL 16 experts: drawn once a seed."""
+    return ref.init_weights(jax.random.PRNGKey(seed), dict(
+        SIZES, num_experts=16, num_hidden_layers=1,
+        full_attention_interval=1))["layers"][0]["moe"]
+
+
 def layer_params(held, first=0, seed=1):
     """An expert layer's weights for ALL 16 experts, and the slice a share
     holds."""
-    sizes = dict(SIZES, num_experts=16)
-    full = ref.init_weights(jax.random.PRNGKey(seed), dict(
-        sizes, num_hidden_layers=1, full_attention_interval=1))[
-            "layers"][0]["moe"]
+    full = dict(all_experts(seed))
     share = {k: (v[first:first + held] if k in ("w1", "w2", "w3") else v)
              for k, v in full.items()}
     return full, share
@@ -155,8 +162,9 @@ def test_a_share_is_the_references_share_with_its_gradients(first, held):
             p, x, SIZES, mm, first_expert=first, held=held))))
 
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(program, argnums=(0, 1))(share, x)
-        want = jax.value_and_grad(reference, argnums=(0, 1))(share, x)
+        got = jax.jit(jax.value_and_grad(program, argnums=(0, 1)))(share, x)
+        want = jax.jit(jax.value_and_grad(reference, argnums=(0, 1)))(
+            share, x)
     assert abs(float(got[0]) - float(want[0])) <= 1e-5 * abs(float(want[0]))
     assert worst_rel(got[1], want[1]) <= 1e-4
 
@@ -182,11 +190,11 @@ def test_no_assignment_is_lost_at_either_end(experts, here):
         y, counts = moe.dropless_moe_ffn(x, share, cfg)
         routed, shared = ref.expert_layer(share, x, SIZES, mm,
                                           first_expert=0, held=4)
-        got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
-            moe.dropless_moe_ffn(x, p, cfg)[0])), argnums=(0, 1))(share, x)
-        want = jax.grad(lambda p, x: jnp.sum(jnp.sin(sum(ref.expert_layer(
-            p, x, SIZES, mm, first_expert=0, held=4)))),
-            argnums=(0, 1))(share, x)
+        got = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(
+            moe.dropless_moe_ffn(x, p, cfg)[0])), argnums=(0, 1)))(share, x)
+        want = jax.jit(jax.grad(lambda p, x: jnp.sum(jnp.sin(sum(
+            ref.expert_layer(p, x, SIZES, mm, first_expert=0, held=4)))),
+            argnums=(0, 1)))(share, x)
     assert int(counts.sum()) == here
     assert sorted(np.asarray(counts))[-2:] == sorted(
         [96 if e < 4 else 0 for e in experts])
@@ -239,12 +247,10 @@ def test_loss_and_gradients_are_the_references(use_flash):
     """One period in float32 on seeded weights (the reference's own draw:
     norm weights away from zero, decays up to 0.999), 2.5 chunks a
     sequence; with the Pallas flash kernel interpreted as well."""
-    params = ref.init_weights(KEY, SIZES)
-    toks, tgts = ref.make_batch(KEY, SIZES, 0)
+    params, toks, tgts = SEEDED
+    l1, g1 = SEEDED.loss_and_grads
     cfg = qwen3_next.tiny(use_flash=use_flash)
     with jax.default_matmul_precision("highest"):
-        l1, g1 = jax.jit(jax.value_and_grad(
-            lambda p: ref.loss_fn(p, toks, tgts, SIZES)))(params)
         l2, g2 = jax.jit(jax.value_and_grad(
             lambda p: qwen3_next.loss_fn(p, toks, tgts, cfg)))(params)
     assert abs(float(l1) - float(l2)) <= 1e-5 * abs(float(l2))
@@ -252,10 +258,9 @@ def test_loss_and_gradients_are_the_references(use_flash):
 
 
 def test_expert_load_counts_what_lands_on_the_held_experts():
-    params = ref.init_weights(KEY, SIZES)
-    toks, _ = ref.make_batch(KEY, SIZES, 0)
-    counts = np.asarray(qwen3_next.expert_load(params, toks,
-                                               qwen3_next.tiny()))
+    params, toks, _ = SEEDED
+    counts = np.asarray(jax.jit(lambda p: qwen3_next.expert_load(
+        p, toks, qwen3_next.tiny()))(params))
     assert counts.shape == (4, 4) and counts.dtype == np.int32
     # 4 of 16 experts, top-2 of 320 tokens: about 160 a layer, never more
     # than every assignment
@@ -265,8 +270,7 @@ def test_expert_load_counts_what_lands_on_the_held_experts():
 def test_a_train_step_lowers_the_loss():
     import optax
     cfg = qwen3_next.tiny()
-    params = ref.init_weights(KEY, SIZES)
-    toks, tgts = ref.make_batch(KEY, SIZES, 0)
+    params, toks, tgts = SEEDED
     opt = optax.adam(1e-2)
     step = jax.jit(qwen3_next.make_train_step(cfg, opt))
     state, losses = opt.init(params), []

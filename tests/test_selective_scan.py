@@ -11,6 +11,8 @@ plain path's chunks and the kernels' blocks; the float32 sums of ``dA`` and
 the ``jamba`` family, never ``nemotron_h``'s Mamba-2.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -24,9 +26,11 @@ KEY = jax.random.PRNGKey(43)
 NAMES = ("x", "delta", "A", "B", "C", "D")
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def draw(b, T, d, n, dtype=jnp.float32, key=KEY):
     """Inputs as a Mamba layer makes them: steps of 0.001 to 1, decays
-    ``-1 .. -16``."""
+    ``-1 .. -16``.  (One program a shape, as ``value_and_grads`` is: op by
+    op the file compiles 760 programs.)"""
     ks = jax.random.split(key, 7)
     return (jax.random.normal(ks[0], (b, T, d)).astype(dtype),
             jax.nn.softplus(2.0 * jax.random.normal(ks[1], (b, T, d)) - 3.0),
@@ -70,9 +74,13 @@ def token_loop(x, delta, A, B, C, D, dy):
 
 
 def value_and_grads(fn, args):
-    *inputs, dy = args
-    y, back = jax.vjp(fn, *inputs)
-    return y, dict(zip(NAMES, back(dy.astype(y.dtype))))
+    @jax.jit
+    def both(*inputs):
+        *inputs, dy = inputs
+        y, back = jax.vjp(fn, *inputs)
+        return y, back(dy.astype(y.dtype))
+    y, grads = both(*args)
+    return y, dict(zip(NAMES, grads))
 
 
 def gap(got, want):
@@ -223,8 +231,8 @@ def test_the_kernels_state_crosses_a_blocks_edge():
     b, T, d, n = 1, 384, 128, 8
     x, delta, A, B, C, D, _ = draw(b, T, d, n)
     A, delta = jnp.full_like(A, -0.01), jnp.full_like(delta, 0.05)
-    at = lambda fn: jax.grad(
-        lambda x: fn(x, delta, A, B, C, D)[0, -1].sum())(x)[0, 0]
+    at = lambda fn: jax.jit(jax.grad(
+        lambda x: fn(x, delta, A, B, C, D)[0, -1].sum()))(x)[0, 0]
     got, want = at(kernels()), at(ss.plain_selective_scan)
     assert float(jnp.abs(want).max()) > 1e-4
     assert gap(got, want) <= EXACT
