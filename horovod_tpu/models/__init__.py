@@ -7,7 +7,7 @@ framework deps) eagerly.
 
 _FAMILIES = ("llama", "gpt2", "bert", "vit", "resnet", "moe", "dlrm",
              "mnist", "convert", "qwen3_next", "olmo_hybrid", "gated_delta",
-             "nemotron_h", "mamba2", "ouro", "jamba", "mamba")
+             "nemotron_h", "mamba2", "ouro", "jamba", "mamba", "laguna")
 
 __all__ = list(_FAMILIES)
 

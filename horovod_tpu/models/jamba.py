@@ -54,7 +54,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from . import blocks as _blocks
 from . import mamba as _ssm
@@ -213,24 +212,9 @@ def loss_fn(params, tokens, targets, cfg: JambaConfig):
     """Mean next-token cross-entropy over this rank's tokens, the head
     :data:`HEAD_TOKENS` tokens at a time, each block recomputed in the
     backward pass."""
-    x = hidden(params, tokens, cfg)
-    B, T, _ = x.shape
-    block = min(HEAD_TOKENS, T)
-    pad = (-T) % block
-
-    def of_block(args):
-        xb, tb = args
-        logits = _logits(params, xb, cfg)
-        return jax.scipy.special.logsumexp(logits, axis=-1) - (
-            jnp.take_along_axis(logits, tb[..., None], axis=-1)[..., 0])
-
-    def blocks(y):                      # [B, T, ...] -> [T / block, B, ...]
-        y = jnp.pad(y, ((0, 0), (0, pad)) + ((0, 0),) * (y.ndim - 2))
-        return jnp.moveaxis(y.reshape((B, -1, block) + y.shape[2:]), 1, 0)
-
-    with jax.named_scope("head"):
-        nll = lax.map(jax.checkpoint(of_block), (blocks(x), blocks(targets)))
-        return jnp.mean(jnp.moveaxis(nll, 0, 1).reshape(B, -1)[:, :T])
+    return _blocks.next_token_loss_in_blocks(
+        hidden(params, tokens, cfg), targets,
+        lambda x: _logits(params, x, cfg), HEAD_TOKENS)
 
 
 def decay_stats(params, tokens, cfg: JambaConfig):

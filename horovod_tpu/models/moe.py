@@ -426,7 +426,8 @@ def dropless_blocks(rows, cfg: DroplessMoEConfig) -> int:
     """In how many equal blocks the ``rows`` sorted assignments are taken:
     as many as leave a block ``BLOCK_OVER_EXPECTED`` times the rows that
     even routing sends to the held experts, among the divisors of ``rows``.
-    One block down to a share of an eighth; four at a thirty-second."""
+    One block down to a share of an eighth; two at a sixteenth; four at a
+    thirty-second."""
     most = max(1, cfg.n_experts // (BLOCK_OVER_EXPECTED * cfg.held))
     return max(n for n in range(1, most + 1) if rows % n == 0)
 

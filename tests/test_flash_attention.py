@@ -104,6 +104,35 @@ def test_flash_twenty_query_heads_on_one_key_head(T, blocks):
                                    atol=2e-4, rtol=2e-4)
 
 
+@pytest.mark.parametrize("H, window", [(9, 12), (6, 12), (6, None)],
+                         ids=["9-to-1-window", "6-to-1-window", "6-to-1"])
+def test_flash_nine_and_six_query_heads_a_key_head_under_a_window(H, window):
+    """9 and 6 query heads a key-value head (``laguna``'s sliding layers
+    at 72 on 8 and its full layers at 48 on 8: the first odd ratio a cell
+    runs) with a window well under T, where blocks below the band are no
+    steps: values and all three gradients against the plain path;
+    ``flash_bwd_dkv`` sums ``dk`` and ``dv`` over the 9 (or 6) heads of a
+    group, each over its band's blocks alone."""
+    B, T, K, D = 1, 70, 2, 128
+    rng = np.random.RandomState(47 + H)
+    q = jnp.asarray(rng.randn(B, T, H * K, D), jnp.float32)
+    k = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
+    v = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
+    w = jnp.asarray(rng.randn(B, T, H * K, D), jnp.float32)
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, block_q=16, block_k=16)
+    plain = lambda q, k, v: local_flash_attention(
+        q, k, v, causal=True, window=window)
+    (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v, w)
+                            for f in (flash, plain))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=3e-5, rtol=3e-5)
+    assert gf[1].shape == (B, T, K, D)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
 def test_flash_cross_attention_shapes():
     """Tq != Tk (cross attention / KV cache shapes)."""
     rng = np.random.RandomState(3)
@@ -234,7 +263,9 @@ def test_flash_forward_output_and_logsumexp(D, rep, causal, window, Tq, Tk,
     assert np.all(np.asarray(o)[:, empty] == 0.0)
 
 
-# (Tq, Tk, tiles, causal, window, rep): the six cells' attention layers, then
+# (Tq, Tk, tiles, causal, window, rep): the seven cells' attention layers
+# (``laguna-s-2_1-5l`` has two kinds: a band of 512 at 9 a key head, and
+# full layers at 6), then
 # what the small shapes reach — a padded tail, a window's edge inside a
 # block and across blocks, odd windows (the band's last column the first of
 # a block), more queries than keys under a window (q rows with no live
@@ -249,6 +280,10 @@ SCHEDULES = [
                  id="nemotron3-super-11l"),
     pytest.param(8192, 8192, (512, 512), True, 0, 8, id="qwen3next-4l"),
     pytest.param(8192, 8192, (512, 512), True, 0, 20, id="jamba2-3b-14l"),
+    pytest.param(16384, 16384, (512, 512), True, 512, 9,
+                 id="laguna-s-2_1-5l-window512"),
+    pytest.param(16384, 16384, (512, 512), True, 0, 6,
+                 id="laguna-s-2_1-5l-full"),
     pytest.param(4096, 4096, (512, 512), True, 1024, 4, id="window1024"),
     pytest.param(3000, 3000, (512, 512), True, 0, 20, id="t3000-padded"),
     pytest.param(70, 70, (32, 32), True, 0, 4, id="t70-padded"),
@@ -284,6 +319,24 @@ def _block_masks(Tq, Tk, bq, bk, causal, window):
         if window:
             mask = mask & (rows - cols < window)
     return mask.reshape(n_q, bq, n_k, bk).transpose(0, 2, 1, 3)
+
+
+@pytest.mark.parametrize("window, rep, by_q, by_k", [
+    (512, 9, 63, 567), (0, 6, 528, 3168), (4096, 4, 252, 1008),
+], ids=["laguna-window512", "laguna-full", "a-band-of-8-blocks"])
+def test_the_live_blocks_of_a_band_and_of_a_triangle(window, rep, by_q, by_k):
+    """16384 positions in 32 x 32 blocks of 512.  A band of 512 keeps the
+    diagonal block and the one before it: 1 + 31 x 2 = 63 of 1024, every one
+    with an edge through it; the causal triangle 32 x 33 / 2 = 528; a band
+    of 4096 (eight blocks, and the ninth that a block's first query still
+    reaches) 8 x 9 / 2 + 24 x 9 = 252.  ``flash_bwd_dkv`` walks each once a
+    query head of its group."""
+    from horovod_tpu.ops import flash_attention as fa
+    live = lambda *a, **kw: int(np.sum(
+        fa.block_schedule(16384, 16384, 512, 512, True, window, *a,
+                          **kw)[2] & fa.LIVE != 0))
+    assert live() == by_q
+    assert live(rep, by_k=True) == by_k
 
 
 @pytest.mark.parametrize("by_k", [False, True], ids=["by_q", "by_k"])
@@ -446,8 +499,8 @@ def _flash_sweep():
     return module
 
 
-# A llama layer and the six decoder cells' attention layers as a step sees
-# them, from the table tools/flash_sweep.py times on the chip; then 20 query
+# A llama layer and the seven decoder cells' attention layers (``laguna``'s
+# two kinds) as a step sees them, from the table tools/flash_sweep.py times on the chip; then 20 query
 # heads on one key head with a padded tail, and a cross-attention shape.
 CELL_GEOMETRIES = [pytest.param(1, 1024, 1024, 8, 4, 128, True, None,
                                 id="llama-d128-h8k4-t1024")] + [

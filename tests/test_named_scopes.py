@@ -61,42 +61,39 @@ def llama_step():
         P(("dp", "ep", "pp"), "sp")).lower(*args)
 
 
-def ouro_step():
-    from horovod_tpu.compat import shard_map
-    from horovod_tpu.models import ouro
-    cfg = ouro.tiny()
-    mesh = make_mesh({"hvd": 8})
-    params = jax.eval_shape(lambda k: ouro.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    opt = optax.adam(1e-3)
-    tokens = jnp.zeros((8, 16), jnp.int32)
-    args = (params, jax.eval_shape(opt.init, params), tokens, tokens)
-    return lambda: jax.jit(shard_map(
-        ouro.make_train_step(cfg, opt), mesh=mesh,
-        in_specs=(P(), P(), P("hvd"), P("hvd")), out_specs=(P(), P(), P()),
-        check_vma=False)).lower(*args)
+def family_step(name):
+    """A decoder family's ``tiny()`` train step under ``shard_map`` over 8
+    ranks, lowered from shapes."""
+    def step():
+        import importlib
 
-
-def jamba_step():
-    from horovod_tpu.compat import shard_map
-    from horovod_tpu.models import jamba
-    cfg = jamba.tiny()
-    mesh = make_mesh({"hvd": 8})
-    params = jax.eval_shape(lambda k: jamba.init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    opt = optax.adam(1e-3)
-    tokens = jnp.zeros((8, 16), jnp.int32)
-    args = (params, jax.eval_shape(opt.init, params), tokens, tokens)
-    return lambda: jax.jit(shard_map(
-        jamba.make_train_step(cfg, opt), mesh=mesh,
-        in_specs=(P(), P(), P("hvd"), P("hvd")), out_specs=(P(), P(), P()),
-        check_vma=False)).lower(*args)
+        from horovod_tpu.compat import shard_map
+        family = importlib.import_module(f"horovod_tpu.models.{name}")
+        cfg = family.tiny()
+        mesh = make_mesh({"hvd": 8})
+        params = jax.eval_shape(lambda k: family.init_params(cfg, k),
+                                jax.random.PRNGKey(0))
+        opt = optax.adam(1e-3)
+        tokens = jnp.zeros((8, 16), jnp.int32)
+        args = (params, jax.eval_shape(opt.init, params), tokens, tokens)
+        return lambda: jax.jit(shard_map(
+            family.make_train_step(cfg, opt), mesh=mesh,
+            in_specs=(P(), P(), P("hvd"), P("hvd")),
+            out_specs=(P(), P(), P()), check_vma=False)).lower(*args)
+    return step
 
 
 # the Mamba-1 hybrid's own: the mixer's four parts, the attention layer, a
 # layer's MLP and the tied head (``benchmark/families/jamba.py`` ``SCOPES``)
 JAMBA_SCOPES = ("ssm/proj", "ssm/conv", "ssm/scan", "ssm/out", "attn/full",
                 "mlp", "head")
+
+# the window/full expert decoder's own: a layer kind a scope, layer 0's
+# SwiGLU, the expert layer's parts and the head
+# (``benchmark/families/laguna.py`` ``SCOPES``)
+LAGUNA_SCOPES = ("attn/full", "attn/window", "mlp", "moe/route",
+                 "moe/dispatch", "moe/experts", "moe/shared", "moe/combine",
+                 "head")
 
 # the looped step's own: a layer's two halves, and what ends a pass
 # (``benchmark/families/ouro.py`` ``SCOPES``)
@@ -105,9 +102,12 @@ OURO_SCOPES = ("attn/full", "mlp", "head", "loop/exit", "loop/carry")
 
 @pytest.mark.parametrize("step, scopes", [
     (resnet_step, SCOPES), (llama_step, SCOPES),
-    (ouro_step, ("forward", "backward", "optimizer") + OURO_SCOPES),
-    (jamba_step, ("forward", "backward", "optimizer") + JAMBA_SCOPES)],
-    ids=["resnet", "llama", "ouro", "jamba"])
+    (family_step("ouro"), ("forward", "backward", "optimizer") + OURO_SCOPES),
+    (family_step("jamba"),
+     ("forward", "backward", "optimizer") + JAMBA_SCOPES),
+    (family_step("laguna"),
+     ("forward", "backward", "optimizer") + LAGUNA_SCOPES)],
+    ids=["resnet", "llama", "ouro", "jamba", "laguna"])
 def test_named_scopes_change_metadata_only(step, scopes, monkeypatch):
     lower = step()
     jax.clear_caches()      # a checkpointed region traced before is kept
@@ -124,21 +124,15 @@ def test_named_scopes_change_metadata_only(step, scopes, monkeypatch):
     assert scoped.as_text() == bare.as_text()
 
 
-def test_the_looped_steps_scopes_are_the_ones_the_benchmark_reads():
+@pytest.mark.parametrize("name, scopes", [
+    ("ouro", OURO_SCOPES), ("jamba", JAMBA_SCOPES),
+    ("laguna", LAGUNA_SCOPES)])
+def test_a_familys_scopes_are_the_ones_the_benchmark_reads(name, scopes):
+    import importlib
     import os
     import sys
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if root not in sys.path:
         sys.path.insert(0, root)
-    from benchmark.families import ouro as family
-    assert family.SCOPES == OURO_SCOPES
-
-
-def test_the_jamba_steps_scopes_are_the_ones_the_benchmark_reads():
-    import os
-    import sys
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmark.families import jamba as family
-    assert family.SCOPES == JAMBA_SCOPES
+    family = importlib.import_module(f"benchmark.families.{name}")
+    assert family.SCOPES == scopes

@@ -310,11 +310,15 @@ def test_all_shares_parts_add_up_to_the_whole_layer(held):
 
 def test_blocks_are_taken_where_the_share_is_small():
     """One block down to a share of an eighth (``qwen3next-80b-a3b-4l``:
-    64 of 512), four at a thirty-second (16 of 512), among the divisors of
-    the rows."""
+    64 of 512), two at a sixteenth (``laguna-s-2_1-5l``: 16 of 256, the
+    sorted assignments of a run of 4096 tokens), four at a thirty-second
+    (16 of 512), among the divisors of the rows."""
     of = lambda held, rows: moe.dropless_blocks(rows, moe.DroplessMoEConfig(
         n_experts=512, top_k=2, experts_held=held))
     assert of(64, 16384 * 10) == 1 and of(512, 16384 * 10) == 1
+    assert of(32, 16384 * 10) == 2
+    assert moe.dropless_blocks(4096 * 10, moe.DroplessMoEConfig(
+        n_experts=256, top_k=10, experts_held=16)) == 2
     assert of(16, 8192 * 22) == 4 and of(8, 8192 * 22) == 8
     assert of(16, 3 * 7 * 11) == 3        # the largest divisor up to 4
 

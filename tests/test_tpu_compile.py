@@ -71,7 +71,8 @@ def _kernels(hlo_text):
 # 2 key-value heads), GQA but for olmo_hybrid's 30 heads with keys of their
 # own, up to nemotron_h's 16 query heads a key head and ouro's 16 with keys
 # of their own, and jamba's 20 query heads on one key head; causal, one
-# windowed, one non-causal.
+# windowed, one non-causal.  (``laguna``'s 9 a key head under a window of 512
+# and 6 a key head compile at the cell's own size, further down.)
 FLASH_CASES = [
     pytest.param(64, 8, 4, True, None, id="d64-h8k4-causal"),
     pytest.param(128, 32, 8, True, None, id="d128-h32k8-causal"),
@@ -645,6 +646,111 @@ def test_jamba2_3b_step_compiles_and_fits_as_recorded(one_chip, monkeypatch):
     recorded = cell.config["memory_analysis"]
     assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
         params)) == 1_598_556_096
+    # weights and two moments, 6 bytes a parameter, all donated
+    assert abs(memory.argument_size_in_bytes
+               - recorded["argument_bytes"]) < 1e6
+    assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
+    assert abs(memory.temp_size_in_bytes
+               - recorded["sandbox_temp_bytes"]) < 0.02 * recorded[
+                   "sandbox_temp_bytes"]
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 16.9e9
+
+
+def _laguna_cell(monkeypatch):
+    """``(sizes, config, shapes of the seeded weights)`` of
+    ``laguna_s2_1-5l-spmd-1c`` with the flash kernels compiled, not
+    interpreted (the default backend here is the CPU's)."""
+    from benchmark import cell as cells
+    from benchmark.families import laguna as family
+    from benchmark.reference import laguna as data
+    from horovod_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret_default", lambda: False)
+    sizes = dict(cells.load_cell("laguna_s2_1-5l-spmd-1c").sizes,
+                 use_flash=True)
+    return sizes, family.config_of(sizes), jax.eval_shape(
+        lambda k: data.init_weights(k, sizes), jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("layer, kind, rep, live", [
+    pytest.param(0, "full", 6, 528, id="full-48-on-8-yarn-on-half"),
+    pytest.param(1, "window", 9, 63, id="window512-72-on-8-plain-rotary"),
+])
+def test_laguna_attention_block_compiles_at_the_cells_size(
+        one_chip, monkeypatch, layer, kind, rep, live):
+    """One attention block of each layer kind of ``laguna-s-2_1-5l`` at the
+    cell's own sizes (16384 tokens of 3072, heads of 128 on 8 key-value
+    heads), forward and backward: the norm, the projections, the gate a
+    head, the layer kind's rotary and the three flash kernels compile for
+    the described v5e, and a sliding layer's kernels walk the band's 63
+    blocks a head of the grid's 1024 where a full layer's walk the
+    triangle's 528 (``flash_bwd_dkv`` once a query head of its group)."""
+    from horovod_tpu import trace
+    from horovod_tpu.models import laguna
+
+    sizes, cfg, params = _laguna_cell(monkeypatch)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    p = {k: params["layers"][layer][k] for k in ("attn_norm", "attn")}
+    assert p["attn"]["wq"].shape == (3072, 128 * 8 * rep)
+    x = jax.ShapeDtypeStruct((1, sizes["seq_len"], 3072), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        return laguna._attention_block(p, x, cfg, layer).astype(
+            jnp.float32).sum()
+
+    before = dict(trace.flash_blocks), dict(trace.attention)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        at(p), x).compile()
+    assert _kernels(compiled.as_text()) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    grid, steps = (trace.flash_blocks[k] - before[0][k]
+                   for k in ("grid", "steps"))
+    # forward and dq a head, dkv a key head's group of ``rep``
+    assert grid * (2 + rep) * live == steps * (2 + rep) * 1024
+    assert trace.attention[f"{kind}_flash"] > before[1][f"{kind}_flash"]
+    assert trace.attention[f"{kind}_plain"] == before[1][f"{kind}_plain"]
+
+
+def test_laguna_s2_1_step_compiles_and_fits_as_recorded(one_chip,
+                                                        monkeypatch):
+    """The training step of ``laguna_s2_1-5l-spmd-1c`` at the cell's sizes
+    (five layers at the published widths, 16 of 256 experts, 12544 rows,
+    16384 tokens; ``optax.adam`` in the distributed optimizer's place): it
+    compiles for the described v5e with the flash kernels of both layer
+    kinds, and its arguments and temporaries are what the configuration
+    file records, inside the 16.9 GB the runtime allows."""
+    import optax
+
+    from benchmark import cell as cells
+    from benchmark.reference import laguna as data
+    from horovod_tpu.models import laguna
+
+    sizes, cfg, params = _laguna_cell(monkeypatch)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    adam = data.ADAM
+    optimizer = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
+                           eps=adam["eps"])
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32,
+        sharding=one_chip)
+    compiled = jax.jit(
+        laguna.make_train_step(cfg, optimizer), donate_argnums=(0, 1)).lower(
+            at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+            tokens).compile()
+    assert _kernels(compiled.as_text()) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    memory = compiled.memory_analysis()
+    recorded = cells.load_cell("laguna_s2_1-5l-spmd-1c").config[
+        "memory_analysis"]
+    # 1,113,007,104 and the four selection biases of 256 zeros
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        params)) == 1_113_008_128
     # weights and two moments, 6 bytes a parameter, all donated
     assert abs(memory.argument_size_in_bytes
                - recorded["argument_bytes"]) < 1e6

@@ -1,5 +1,7 @@
-"""On-chip sweep of the flash attention kernels at the benchmark's six
-attention geometries: each kernel alone, forward + backward, the tiles.
+"""On-chip sweep of the flash attention kernels at the benchmark's eight
+attention geometries (``laguna_s2_1-5l-spmd-1c`` has two: a band of 512 at
+72 heads and full layers at 48): each kernel alone, forward + backward, the
+tiles.
 
 Per geometry (a decoder cell's heads, head width, sequence and window, one
 step's batch, bf16) it times, from one device trace, the forward kernel
@@ -55,6 +57,10 @@ GEOMETRIES = {
                                        window=0),
     "qwen3next-4l-spmd-1c": dict(B=2, T=8192, H=16, K=2, D=256, window=0),
     "jamba2_3b-14l-spmd-1c": dict(B=1, T=8192, H=20, K=1, D=128, window=0),
+    "laguna_s2_1-5l-spmd-1c.window": dict(B=1, T=16384, H=72, K=8, D=128,
+                                          window=512),
+    "laguna_s2_1-5l-spmd-1c.full": dict(B=1, T=16384, H=48, K=8, D=128,
+                                        window=0),
 }
 KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 PRODUCTS = {"flash_fwd": 2, "flash_bwd_dq": 3, "flash_bwd_dkv": 4}
