@@ -9,9 +9,9 @@ and L2-normalised a head, q times ``k_dim ** -0.5``; ``beta = beta_scale *
 sigmoid(b)``, ``g = -exp(A_log) * softplus(a + dt_bias)``; per head ``S <-
 exp(g_t) S + k_t (beta_t (v_t - S^T k_t))^T``, ``o_t = S^T q_t`` with ``S``
 ``k_dim x v_dim``, computed in the **chunked** form
-(:func:`chunked_gated_delta_rule`, or on a TPU where the widths fit the
-kernel pair of ``ops/delta_rule.py``); ``o <- RMSNorm(o; w_out[v_dim]) *
-SiLU(z)``; ``W_o``.
+(:func:`chunked_gated_delta_rule`, or on a TPU the kernel pair of
+``ops/delta_rule.py``, at widths rounded up to whole lanes); ``o <-
+RMSNorm(o; w_out[v_dim]) * SiLU(z)``; ``W_o``.
 
 ``beta_scale`` 1 keeps beta in (0, 1): ``I - beta k k^T`` then only shrinks
 a state component along k.  ``beta_scale`` 2 (the published
@@ -111,9 +111,9 @@ def chunked_gated_delta_rule(q, k, v, g, beta, chunk=64):
     This is the **plain branch** of the rule: :func:`gated_delta_net` takes
     it wherever the kernel pair of ``ops/delta_rule.py`` does not engage —
     any backend but a TPU (the CPU tests and rehearsals), and on a TPU a
-    key or value width that is no multiple of the 128 lanes (96 and 192,
-    say) or a chunk its tiles do not take — and it is the yardstick the
-    kernels' tests hold them to.  Every float32
+    key or value width under 32 (96 and 192, say, run in the kernels at
+    128 and 256 on zero-padded heads) or a chunk its tiles do not take —
+    and it is the yardstick the kernels' tests hold them to.  Every float32
     intermediate here is an array of all chunks at once that goes to HBM
     and comes back; the kernels keep a chunk's in VMEM."""
     B, T, H, dk = q.shape
@@ -183,8 +183,9 @@ def by_head_groups(rule, token_heads):
 
     Part of the **plain branch**: the split exists because
     :func:`chunked_gated_delta_rule`'s working set is all chunks' at once.
-    Where :func:`gated_delta_net` takes the kernel pair the working set is
-    a chunk's, and the ``rule`` a caller built with this is not called."""
+    Where :func:`gated_delta_net` takes the kernel pair — on a TPU, the
+    shape this was written for included — the working set is a chunk's,
+    and the ``rule`` a caller built with this is not called."""
     def grouped(q, k, v, g, beta, chunk):
         B, T, H = q.shape[:3]
         fit = max(1, token_heads // (B * T))
@@ -247,12 +248,14 @@ def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
     """The mixer: x ``[B, T, d_model]`` -> ``[B, T, d_model]``.  The delta
     rule is one algorithm with two implementations, chosen here at trace
     time from what can be observed: on a TPU, where the shape fits
-    (``ops/delta_rule.py`` ``tiles``: key and value widths in 128s), the
-    Pallas kernel pair, which holds a chunk's algebra in VMEM and needs no
-    split by heads; everywhere else ``rule``, the plain branch
-    (:func:`chunked_gated_delta_rule` by default; a caller whose shape
-    wants it hands in :func:`by_head_groups` of it).  ``trace.delta_rule``
-    counts the call sites of each."""
+    (``ops/delta_rule.py`` ``tiles``: key and value widths of 32 or more,
+    run at ``widths``' whole lanes on zero-padded heads — 96 x 192 at 128
+    x 256), the Pallas kernel pair, which holds a chunk's algebra in VMEM
+    and needs no split by heads; everywhere else ``rule``, the plain
+    branch (:func:`chunked_gated_delta_rule` by default; a caller whose
+    shape wants it hands in :func:`by_head_groups` of it).
+    ``trace.delta_rule`` counts the call sites of each, and of the
+    kernel's those that run at padded widths."""
     rule = rule or chunked_gated_delta_rule
     B, T, _ = x.shape
     hk, hv, dk, dv = dims.k_heads, dims.v_heads, dims.k_dim, dims.v_dim
@@ -273,6 +276,8 @@ def gated_delta_net(x, p, dims: GatedDeltaDims, rule=None):
         kernel = delta_rule.kernel_enabled() and delta_rule.tiles(
             (B, T, hk, dk), (B, T, hv, dv), dims.chunk, x.dtype)
         trace.delta_rule["kernel" if kernel else "plain"] += 1   # a trace
+        if kernel and delta_rule.widths(dk, dv) != (dk, dv):
+            trace.delta_rule["padded"] += 1
 
         def heads(y, n, dim, repeat=1):
             y = y.reshape(B, T, n, dim).astype(f32)

@@ -47,7 +47,11 @@ here (``benchmark/configs/olmo-hybrid-7b-4l.json`` lists the same under
    ``[b|a]`` over all heads, which matters only to a checkpoint converter.
 
 Each mixer and each MLP is recomputed in the backward pass as its own
-region, and the delta rule runs a group of heads at a time
+region.  On a TPU the delta rule is the kernel pair of ``ops/delta_rule.py``
+(``gated_delta_net`` chooses at trace time): the 96 x 192 heads run at 128
+x 256, padded with zeros, a chunk's float32 algebra stays in VMEM and all
+30 heads go at once.  Everywhere else — the CPU tests and rehearsals — it
+is the plain rule this module hands in, a group of heads at a time
 (``gated_delta.by_head_groups``, ``RULE_TOKEN_HEADS`` (token, head) pairs
 together, each group recomputed too), so that a 16 k-token step never holds
 every head's float32 chunk algebra at once.
@@ -71,8 +75,9 @@ from . import gated_delta as _gdn
 from ..parallel.ring_attention import local_flash_attention
 
 # (token, head) pairs of a sequence whose chunked delta rule is computed
-# together (gated_delta.by_head_groups): 6 of 30 heads at 16384 tokens, and
-# every head at once up to 4369 tokens
+# together where the plain rule runs (gated_delta.by_head_groups; not on a
+# TPU, where the kernel pair takes the shape): 6 of 30 heads at 16384
+# tokens, and every head at once up to 4369 tokens
 RULE_TOKEN_HEADS = 1 << 17
 
 
@@ -209,7 +214,9 @@ def forward(params, tokens, cfg: OlmoHybridConfig):
     # an MLP's intermediates together (qwen3_next._forward's reason).  A
     # grouped delta rule's result is saved (189 MB a layer at 16 k tokens):
     # its groups recompute themselves, so the mixer's recomputation need
-    # not run the rule forward a third time.
+    # not run the rule forward a third time.  (The kernel pair's result
+    # carries no such name: the recomputation runs its forward kernel, which
+    # saves what its backward kernel starts from.)
     mixer = jax.checkpoint(
         _mixer_block, static_argnums=(2,),
         policy=jax.checkpoint_policies.save_only_these_names(

@@ -44,6 +44,14 @@ their operands in the inputs' type and accumulate in float32.  ``T`` need
 not be a multiple of the chunk or of the block: padding tokens have ``k =
 0``, ``beta = 0``, ``g = 0``, which write nothing and decay nothing.
 
+A head is a column block of ``[B, T, H * d]``, so the kernels' widths are
+whole lanes.  A key or value width that is not (96 x 192, say) runs at the
+widths rounded up (:func:`widths`: 128 x 256) on ``q``, ``k``, ``v`` padded
+with zeros, and the result is cut back to the value width: a key padded
+with zeros has the same length and the same products with every other key
+and query, the padded rows of the state stay zero, and the padded columns
+of ``v`` give zero columns of ``U``, of the state and of ``o``.
+
 On non-TPU backends the kernels run in Pallas interpret mode (tests);
 ``gated_delta_net`` in ``models/gated_delta.py`` routes here on a TPU where
 :func:`tiles` finds the shape a fit and keeps the plain formulation
@@ -83,21 +91,36 @@ def kernel_enabled() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def widths(dk: int, dv: int) -> Optional[tuple]:
+    """The key and value widths the kernels run at for heads of ``dk x
+    dv``: each rounded up to whole lanes (96 x 192 -> 128 x 256;
+    :func:`gated_delta_rule` pads with zeros and cuts the result back).
+    ``None`` where a width is under a quarter of the lanes: the kernels'
+    cost is the padded problem's whatever the width and the plain
+    formulation's falls with it.  A reading on each side of the line, 30
+    heads at 16 k tokens: at 32 x 32 the kernels are ahead (14.2 ms forward
+    and 30.3 with the backward against 15.6 and 41.8), at 16 x 16 behind
+    (14.1 and 29.9 against 11.8 and 28.8): PERF.md section 6, PR 48."""
+    if min(dk, dv) < LANES // 4:
+        return None
+    return tuple(-(-d // LANES) * LANES for d in (dk, dv))
+
+
 def tiles(q_shape, v_shape, chunk: int, dtype) -> Optional[int]:
     """The chunks a grid step takes for q (and k) of ``q_shape [B, T, Hk,
     dk]`` and v of ``v_shape [B, T, Hv, dv]``: :data:`BLOCK`, or all of
     them where the sequence has no more than twice that (the gates' block
     is then the whole array: a sequence is padded to whole blocks, and a
     short one would be mostly padding).  ``None`` where the kernels do
-    not take the shape: a key or a value width that is no multiple of the
-    128 lanes (a head is then no column block of ``[B, T, H * d]``), a
+    not take the shape: a key or a value width that :func:`widths` does
+    not take (under 32: whole lanes would be mostly padding), a
     chunk that is no multiple of 16 rows (a sublane tile of a 16-bit
     type) or wider than the lanes, value heads that are no multiple of
     the key heads, or a type other than bfloat16 and float32."""
     if len(q_shape) != 4 or len(v_shape) != 4:
         return None
     (_, T, hk, dk), (hv, dv) = q_shape, v_shape[2:]
-    if (dk % LANES or dv % LANES or chunk % 16 or not 16 <= chunk <= LANES
+    if (widths(dk, dv) is None or chunk % 16 or not 16 <= chunk <= LANES
             or hk < 1 or hv % hk
             or jnp.dtype(dtype) not in (jnp.dtype(jnp.bfloat16),
                                         jnp.dtype(jnp.float32))):
@@ -554,16 +577,19 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     dv]`` in v's type.  ``block`` (the chunks a grid step) comes from
     :func:`tiles` where it is not given (tests give small ones); a shape
     that the kernels do not take is an error here — the caller asks
-    :func:`tiles` first.  The sequence is padded to whole blocks."""
+    :func:`tiles` first.  The sequence is padded to whole blocks and the
+    heads to :func:`widths`, with zeros, outside the ``custom_vjp``: the
+    cotangents' slices and pads are autodiff's."""
     B, T, hk, dk = q.shape
     hv, dv = v.shape[2:]
     fit = tiles(q.shape, v.shape, chunk, v.dtype)
     if not fit or k.shape != q.shape or q.dtype != v.dtype:
         raise ValueError(
             f"no tiles for q {q.shape}, v {v.shape} in {v.dtype} at a chunk "
-            f"of {chunk}: widths must be multiples of {LANES}, the chunk a "
+            f"of {chunk}: widths must be at least {LANES // 4}, the chunk a "
             f"multiple of 16 up to {LANES}, the value heads a multiple of "
             "the key heads")
+    dk_run, dv_run = widths(dk, dv)
     block = block or fit
     group = group or next(n for n in range(min(GROUP, block), 0, -1)
                           if block % n == 0)
@@ -573,13 +599,17 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64,
     pad = (-T) % (chunk * block)
     N = (T + pad) // chunk
 
-    def rows(x):            # [B, T, H, d] -> [B, T + pad, H * d]
+    def rows(x, width):     # [B, T, H, d] -> [B, T + pad, H * width]
+        if width != x.shape[3]:
+            x = jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[3]),))
         return jnp.pad(x.reshape(B, T, -1), ((0, 0), (0, pad), (0, 0)))
 
     def gates(x):           # [B, T, H] -> [B, H, N, C] float32
         x = jnp.pad(x.astype(F32), ((0, 0), (0, pad), (0, 0)))
         return jnp.moveaxis(x, 1, 2).reshape(B, hv, N, chunk)
 
-    o = _rule_core(rows(q), rows(k), rows(v), gates(g), gates(beta),
-                   (chunk, block, group, dk, dv, interpret))
-    return o[:, :T].reshape(B, T, hv, dv)
+    o = _rule_core(rows(q, dk_run), rows(k, dk_run), rows(v, dv_run),
+                   gates(g), gates(beta),
+                   (chunk, block, group, dk_run, dv_run, interpret))
+    o = o[:, :T].reshape(B, T, hv, dv_run)
+    return o if dv_run == dv else o[..., :dv]

@@ -305,9 +305,11 @@ selective_scan = {"kernel": 0, "plain": 0}
 # The same pair for the Gated DeltaNet mixers' chunked delta rule
 # (``models/gated_delta.py`` ``gated_delta_net``): ``kernel`` call sites
 # took the Pallas kernel pair (``ops/delta_rule.py``: a TPU, key and value
-# widths in 128s), ``plain`` XLA's code for all chunks at once.  ``plain``
+# widths of 32 or more), ``padded`` those of them whose widths are no
+# multiple of 128 and run rounded up on zero-padded heads (a subset of
+# ``kernel``), ``plain`` XLA's code for all chunks at once.  ``plain``
 # rising on a TPU is a shape the kernels do not take.
-delta_rule = {"kernel": 0, "plain": 0}
+delta_rule = {"kernel": 0, "padded": 0, "plain": 0}
 
 # The flash attention kernels' block schedules (``ops/flash_attention.py``
 # ``_schedule``), added up in Python once a traced kernel call, a head:
@@ -363,6 +365,8 @@ _register_counts("hvd_selective_scan", selective_scan, {
     "plain": "selective scan call sites traced as XLA's own code"})
 _register_counts("hvd_delta_rule", delta_rule, {
     "kernel": "chunked delta rule call sites traced as the Pallas kernels",
+    "padded": "chunked delta rule call sites traced as the Pallas kernels "
+              "at widths rounded up to whole lanes",
     "plain": "chunked delta rule call sites traced as XLA's own code"})
 _register_counts("hvd_attention", attention, {
     "full_flash": "full-attention call sites traced as the flash kernels",

@@ -5,11 +5,13 @@ the plain formulation (``models/gated_delta.py``
 (``benchmark/reference/qwen3_next.py`` ``recurrence``) — lengths that are
 and are not multiples of the chunk and of the kernel's block, decay 0.999
 and 0.9, beta over (0, 2) and every beta at 1.999, ``dk != dv``, keys
-shared by two value heads, bfloat16 and float32; sequences and heads that
-see nothing of each other; the gradient through ``jax.checkpoint``; the
-in-kernel inverse against numpy's; which shapes take which branch of
-``gated_delta_net`` and the counts that say so (``trace.delta_rule``, the
-two ``/metrics`` series).
+shared by two value heads, bfloat16 and float32; widths that are no
+multiple of 128 (96 x 192, 96 x 128: run at 128 x 256 and 128 x 128 on
+zero-padded heads, and nothing of the padding reaches a result);
+sequences and heads that see nothing of each other; the gradient through
+``jax.checkpoint``; the in-kernel inverse against numpy's; which shapes
+take which branch of ``gated_delta_net`` and the counts that say so
+(``trace.delta_rule``, the three ``/metrics`` series).
 """
 
 import functools
@@ -107,25 +109,77 @@ GEOMETRIES = [
     pytest.param((1, 256, 1, 1, 128, 256), (2, 2), id="dv256-two-blocks"),
     pytest.param((1, 136, 2, 2, 128, 128), (2, 2), id="t136-two-heads"),
 ]
+# widths that are no multiple of the lanes, run rounded up on zero-padded
+# heads: ``olmo_hybrid``'s 96 x 192 (at 128 x 256, a head's two chunks
+# stacked), and a key alone padded under two value heads of 128
+PADDED = [
+    pytest.param((1, 136, 2, 2, 96, 192), (2, 2), id="t136-96x192"),
+    pytest.param((2, 100, 1, 2, 96, 128), (2, 1), id="t100-96x128-shared"),
+]
 # float32: the sums' order; bfloat16: the products round their operands
 # where the plain formulation does, one more rounding of ``k beta`` there
 TOLERANCE = {jnp.float32: 2e-5, jnp.bfloat16: 3e-2}
 
 
-@pytest.mark.parametrize("shape, tile", GEOMETRIES)
+@pytest.mark.parametrize("shape, tile", GEOMETRIES + PADDED)
 def test_values_and_gradients_are_the_plain_branchs(shape, tile,
-                                                    dtype=jnp.float32):
-    args, w = draw(*shape, dtype)
+                                                    dtype=jnp.float32,
+                                                    beta=None):
+    args, w = draw(*shape, dtype, beta=beta)
     got = with_gradients(kernel_rule(*tile), args, w)
     want = with_gradients(PLAIN, args, w)
-    assert got[0].shape == want[0].shape
+    # the primals' own shapes: a padded width is cut off again
+    assert [x.shape for x in got] == [x.shape for x in want]
     assert max(gaps(got, want)) <= TOLERANCE[dtype], gaps(got, want)
 
 
-@pytest.mark.parametrize("shape, tile", GEOMETRIES[1:3])
+@pytest.mark.parametrize("shape, tile", GEOMETRIES[1:3] + PADDED)
 def test_values_and_gradients_in_bfloat16(shape, tile):
     test_values_and_gradients_are_the_plain_branchs(shape, tile,
                                                     jnp.bfloat16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape, tile", PADDED)
+def test_padded_widths_with_every_beta_at_1_999(shape, tile, dtype):
+    """``olmo_hybrid`` draws beta over (0, 2): the range where the
+    in-kernel inverse has most to lose, at the widths it pads."""
+    test_values_and_gradients_are_the_plain_branchs(shape, tile, dtype,
+                                                    beta=1.999)
+
+
+def test_nothing_of_the_padding_reaches_a_result(monkeypatch):
+    """The kernels get zeros in the padded columns of q, k and v; and
+    were v's (and q's) padded columns anything else, ``o`` would be the
+    same, bit for bit: they meet zero rows of the state and zero columns
+    of k, and give columns of ``o`` that are cut off."""
+    args, _ = draw(1, 136, 1, 2, 96, 192)
+    rule = lambda *a: delta_rule.gated_delta_rule(*a, 64, block=2, group=2,
+                                                  interpret=True)
+    want = rule(*args)
+    assert want.shape == (1, 136, 2, 192)
+    core, seen = delta_rule._rule_core, []
+
+    def garbage(x, width, run):
+        return jnp.where(jnp.arange(x.shape[-1]) % run >= width, 7.0, x)
+
+    def double(q, k, v, g, beta, static):
+        dk, dv = static[3:5]
+        assert (dk, dv) == (128, 256)
+        assert q.shape[-1] == k.shape[-1] == dk and v.shape[-1] == 2 * dv
+        seen.append(all(
+            not np.asarray(x).reshape(x.shape[:2] + (-1, run))[..., width:]
+            .any() for x, width, run in ((q, 96, dk), (k, 96, dk),
+                                         (v, 192, dv))))
+        o = core(garbage(q, 96, dk), k, garbage(v, 192, dv), g, beta, static)
+        assert o.shape == v.shape
+        return o
+
+    monkeypatch.setattr(delta_rule, "_rule_core", double)
+    got = rule(*args)
+    assert seen == [True]
+    assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("beta", [None, 1.999], ids=["beta-0-2", "beta-1.999"])
@@ -229,23 +283,33 @@ def test_the_gradient_through_a_checkpoint():
     ((1, 64, 1, 128), (1, 64, 1, 128), 64, jnp.bfloat16, 1),
     ((1, 1000, 1, 128), (1, 1000, 1, 128), 64, jnp.bfloat16, 16),
     ((1, 1100, 1, 128), (1, 1100, 1, 128), 64, jnp.bfloat16, 8),
-    ((1, 16384, 30, 96), (1, 16384, 30, 192), 64, jnp.bfloat16, None),
+    ((1, 16384, 30, 96), (1, 16384, 30, 192), 64, jnp.bfloat16, 8),
+    ((1, 16384, 30, 32), (1, 16384, 30, 32), 64, jnp.bfloat16, 8),
     ((2, 128, 3, 16), (2, 128, 3, 8), 64, jnp.float32, None),
+    ((2, 128, 1, 16), (2, 128, 1, 128), 64, jnp.bfloat16, None),
     ((2, 128, 2, 128), (2, 128, 3, 128), 64, jnp.bfloat16, None),
     ((2, 128, 2, 128), (2, 128, 2, 128), 40, jnp.bfloat16, None),
     ((2, 128, 2, 128), (2, 128, 2, 128), 256, jnp.bfloat16, None),
     ((2, 128, 2, 128), (2, 128, 2, 128), 64, jnp.float16, None),
     ((2, 128, 256), (2, 128, 256), 64, jnp.bfloat16, None),
 ], ids=["qwen3next", "float32", "t200-dv256", "one-chunk", "t1000-one-block",
-        "t1100-padded", "olmo-hybrid",
-        "narrow", "heads-3-over-2", "chunk-40", "chunk-256", "float16",
+        "t1100-padded", "olmo-hybrid", "a-quarter-of-the-lanes",
+        "narrow", "narrow-key", "heads-3-over-2", "chunk-40", "chunk-256", "float16",
         "three-axes"])
 def test_tiles_by_shape_and_type(q_shape, v_shape, chunk, dtype, want):
     assert delta_rule.tiles(q_shape, v_shape, chunk, dtype) == want
 
 
+@pytest.mark.parametrize("dk, dv, want", [
+    (128, 128, (128, 128)), (128, 256, (128, 256)), (96, 192, (128, 256)),
+    (96, 128, (128, 128)), (130, 128, (256, 128)), (64, 64, (128, 128)),
+    (32, 32, (128, 128)), (31, 128, None), (128, 16, None), (16, 8, None)])
+def test_widths_round_up_from_a_quarter_of_the_lanes(dk, dv, want):
+    assert delta_rule.widths(dk, dv) == want
+
+
 def test_a_shape_without_tiles_is_an_error():
-    (q, k, v, g, beta), _ = draw(1, 64, 1, 1, 96, 128)
+    (q, k, v, g, beta), _ = draw(1, 64, 1, 1, 16, 128)
     with pytest.raises(ValueError, match="no tiles"):
         delta_rule.gated_delta_rule(q, k, v, g, beta, 64, interpret=True)
     (q, k, v, g, beta), _ = draw(1, 128, 1, 1, 128, 128)
@@ -268,20 +332,22 @@ def mixer(k_dim, v_dim, chunk=64, k_heads=1, v_heads=2, d_model=32, T=100,
 @pytest.mark.parametrize("k_dim, v_dim, chunk, branch", [
     pytest.param(128, 128, 64, "kernel", id="fits"),
     pytest.param(128, 256, 32, "kernel", id="fits-dv256-chunk32"),
-    pytest.param(96, 192, 64, "plain", id="widths-96-192"),
+    pytest.param(96, 192, 64, "padded", id="widths-96-192"),
+    pytest.param(16, 128, 64, "plain", id="narrow-key-16"),
     pytest.param(128, 128, 40, "plain", id="chunk-40"),
 ])
 def test_the_branch_follows_backend_and_shape(monkeypatch, k_dim, v_dim,
                                               chunk, branch):
     """With the backend said to be a TPU the shape decides (the kernel is
-    interpreted here); on the CPU every shape is plain.  A caller's own
-    ``rule`` is the plain branch's and never the kernel's."""
+    interpreted here; ``padded``: the kernel at widths rounded up); on
+    the CPU every shape is plain.  A caller's own ``rule`` is the plain
+    branch's and never the kernel's."""
     x, p, dims = mixer(k_dim, v_dim, chunk)
     net = lambda **kw: jax.jit(lambda x, p: gated_delta.gated_delta_net(
         x, p, dims, **kw))(x, p)       # traced once: the counts hold
     before = counts()
     want = net()
-    assert moved(before) == {"kernel": 0, "plain": 1}
+    assert moved(before) == {"kernel": 0, "padded": 0, "plain": 1}
     monkeypatch.setattr(delta_rule, "kernel_enabled", lambda: True)
     called = []
 
@@ -291,17 +357,22 @@ def test_the_branch_follows_backend_and_shape(monkeypatch, k_dim, v_dim,
 
     before = counts()
     got = net(rule=rule)
-    assert moved(before) == {"kernel": int(branch == "kernel"),
+    assert moved(before) == {"kernel": int(branch != "plain"),
+                             "padded": int(branch == "padded"),
                              "plain": int(branch == "plain")}
     assert len(called) == int(branch == "plain")
+    assert got.shape == want.shape
     assert max(gaps([np.asarray(got)], [np.asarray(want)])) <= 2e-5
     jaxpr = str(jax.make_jaxpr(
         lambda x, p: gated_delta.gated_delta_net(x, p, dims))(x, p))
-    assert ("delta_rule_fwd" in jaxpr) == (branch == "kernel")
+    assert ("delta_rule_fwd" in jaxpr) == (branch != "plain")
 
 
-def test_the_mixers_gradient_is_the_same_on_both_branches(monkeypatch):
-    x, p, dims = mixer(128, 128)
+@pytest.mark.parametrize("k_dim, v_dim", [(128, 128), (96, 192)],
+                         ids=["128x128", "96x192-padded"])
+def test_the_mixers_gradient_is_the_same_on_both_branches(monkeypatch,
+                                                          k_dim, v_dim):
+    x, p, dims = mixer(k_dim, v_dim)
     loss = lambda x, p: jnp.sum(jnp.square(
         gated_delta.gated_delta_net(x, p, dims)))
     want = jax.jit(jax.grad(loss, argnums=(0, 1)))(x, p)
@@ -318,11 +389,15 @@ def test_one_trace_a_signature(monkeypatch):
     fn = jax.jit(lambda x, p: gated_delta.gated_delta_net(x, p, dims))
     before = counts()
     fn(x, p), fn(x + 1, p), fn(x, p)
-    assert moved(before) == {"kernel": 1, "plain": 0}
-    x2, p2, dims2 = mixer(96, 128, T=64)
+    assert moved(before) == {"kernel": 1, "padded": 0, "plain": 0}
+    x2, p2, dims2 = mixer(16, 128, T=64)
     fn2 = jax.jit(lambda x, p: gated_delta.gated_delta_net(x, p, dims2))
     fn2(x2, p2), fn2(x2, p2)
-    assert moved(before) == {"kernel": 1, "plain": 1}
+    assert moved(before) == {"kernel": 1, "padded": 0, "plain": 1}
+    x3, p3, dims3 = mixer(96, 128, T=64)
+    fn3 = jax.jit(lambda x, p: gated_delta.gated_delta_net(x, p, dims3))
+    fn3(x3, p3), fn3(x3, p3)
+    assert moved(before) == {"kernel": 2, "padded": 1, "plain": 1}
 
 
 def test_monitor_agent_exports_the_two_counts(monkeypatch):
@@ -332,13 +407,14 @@ def test_monitor_agent_exports_the_two_counts(monkeypatch):
         monitor = None
 
     x, p, dims = mixer(128, 128, T=64)
+    x2, p2, dims2 = mixer(96, 192, T=64)
     agent = MonitorAgent(engine=Engine())
     try:
         first = agent.registry.snapshot()
         gated_delta.gated_delta_net(x, p, dims)
         monkeypatch.setattr(delta_rule, "kernel_enabled", lambda: True)
         gated_delta.gated_delta_net(x, p, dims)
-        gated_delta.gated_delta_net(x, p, dims)
+        gated_delta.gated_delta_net(x2, p2, dims2)
         second = agent.registry.snapshot()
         text = agent.registry.to_prometheus('rank="0"')
     finally:
@@ -350,9 +426,12 @@ def test_monitor_agent_exports_the_two_counts(monkeypatch):
 
     assert value(second, "hvd_delta_rule_kernel_total") \
         - value(first, "hvd_delta_rule_kernel_total") == 2
+    assert value(second, "hvd_delta_rule_padded_total") \
+        - value(first, "hvd_delta_rule_padded_total") == 1
     assert value(second, "hvd_delta_rule_plain_total") \
         - value(first, "hvd_delta_rule_plain_total") == 1
     assert "hvd_delta_rule_kernel_total" in text
+    assert "hvd_delta_rule_padded_total" in text
     assert "hvd_delta_rule_plain_total" in text
 
 
@@ -381,7 +460,7 @@ def lowered_step(family):
 def test_a_step_without_the_rule_never_counts(family):
     before = counts()
     lowered_step(family)
-    assert moved(before) == {"kernel": 0, "plain": 0}
+    assert moved(before) == {"kernel": 0, "padded": 0, "plain": 0}
 
 
 @pytest.mark.parametrize("family", ["qwen3_next", "olmo_hybrid"])

@@ -352,15 +352,19 @@ DELTA_KERNELS = (r"%(?:jvp_)?(delta_rule_(?:fwd|bwd))[\w.]* = "
     pytest.param((2, 8192, 16, 32, 128, 128), 8, id="qwen3next-b2-t8192"),
     pytest.param((1, 1100, 2, 2, 128, 256), 8, id="t1100-dv256-padded"),
     pytest.param((1, 256, 1, 1, 128, 128), 4, id="one-block-of-four"),
+    pytest.param((1, 16384, 30, 30, 96, 192), 8, id="olmohybrid-t16384"),
 ])
 def test_delta_rule_kernels_compile_for_v5e(one_chip, shape, block):
     """The delta rule's kernel pair (``ops/delta_rule.py``) at
     ``qwen3next-80b-a3b-4l``'s geometry (16 key heads shared by 32 value
-    heads of 128 x 128, two sequences of 8192) and at two that pad and
-    stack differently: forward under ``jax.checkpoint`` and its VJP.  Two
+    heads of 128 x 128, two sequences of 8192), at two that pad and
+    stack differently, and at ``olmo-hybrid-7b-4l``'s (30 heads of 96 x
+    192 on one sequence of 16384, run at 128 x 256 on zero-padded heads):
+    forward under ``jax.checkpoint`` and its VJP.  Two
     kernels, no loop of XLA's, and no temporary but the state every group
     of chunks starts from, the gates a chunk a row and the padding (XLA's
-    code for the plain formulation keeps 2.9 GB at the first shape)."""
+    code for the plain formulation keeps 2.9 GB at the first shape),
+    reckoned at the widths the kernels run at."""
     from horovod_tpu.ops import delta_rule
 
     def spec(s, dtype=jnp.bfloat16):
@@ -369,6 +373,7 @@ def test_delta_rule_kernels_compile_for_v5e(one_chip, shape, block):
     B, T, hk, hv, dk, dv = shape
     q, v = (B, T, hk, dk), (B, T, hv, dv)
     assert delta_rule.tiles(q, v, 64, jnp.bfloat16) == block
+    dk, dv = delta_rule.widths(dk, dv)
 
     def both(q, k, v, g, beta, do):
         o, back = jax.vjp(jax.checkpoint(
@@ -424,6 +429,45 @@ def test_qwen3next_step_names_the_delta_rules_kernels(one_chip, monkeypatch):
     calls = re.findall(DELTA_KERNELS.replace("%(?:jvp_)?", ""), text)
     assert sorted(calls) == ["delta_rule_bwd"] * 3 + ["delta_rule_fwd"] * 6
     assert "causal_conv_fwd" in text
+    assert "InvertDiagBlocks" not in text       # the plain path's solve
+
+
+def test_olmohybrid_step_names_the_delta_rules_kernels(one_chip, monkeypatch):
+    """The twin for ``olmo_hybrid``: a training step with the published
+    head geometry (as many key heads as value heads, 96 x 192; the rest
+    at test size) takes the kernel pair at its three Gated DeltaNet
+    layers at widths rounded up to 128 x 256 — ``padded`` counts every
+    kernel site — and the grouped plain rule the model hands in is not
+    called.  (The cell's own step: nine such calls and 4.79 GB of
+    temporaries where the plain path's has 5.65, PERF.md section 6, PR 48;
+    not compiled here.)"""
+    import optax
+
+    from horovod_tpu import trace
+    from horovod_tpu.models import olmo_hybrid
+    from horovod_tpu.ops import causal_conv, delta_rule
+
+    for module in (causal_conv, delta_rule):
+        monkeypatch.setattr(module, "_interpret_default", lambda: False)
+        monkeypatch.setattr(module, "kernel_enabled", lambda: True)
+    cfg = olmo_hybrid.tiny(lin_k_dim=96, lin_v_dim=192, dtype=jnp.bfloat16)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(lambda k: olmo_hybrid.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    optimizer = optax.adam(1e-3)
+    tokens = jax.ShapeDtypeStruct((2, 256), jnp.int32, sharding=one_chip)
+    jax.clear_caches()      # a region traced before would not count again
+    before = dict(trace.delta_rule)
+    compiled = jax.jit(olmo_hybrid.make_train_step(cfg, optimizer)).lower(
+        at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+        tokens).compile()
+    moved = {k: v - before[k] for k, v in trace.delta_rule.items()}
+    assert moved["plain"] == 0 and 1 <= moved["kernel"] == moved["padded"]
+    text = compiled.as_text()
+    calls = re.findall(DELTA_KERNELS.replace("%(?:jvp_)?", ""), text)
+    assert sorted(calls) == ["delta_rule_bwd"] * 3 + ["delta_rule_fwd"] * 6
     assert "InvertDiagBlocks" not in text       # the plain path's solve
 
 

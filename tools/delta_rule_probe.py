@@ -4,7 +4,17 @@ against the plain formulation.  ``chiprun -- python tools/delta_rule_probe.py
 forward with the backward (host clock round ``block_until_ready``, the
 median of five), and the largest difference from the plain path's values
 and gradients over their largest value.  A time here is a chip's or
-nothing: on the CPU the kernels are interpreted."""
+nothing: on the CPU the kernels are interpreted.
+
+``--shape B,T,Hk,Hv,dk,dv``: ``qwen3next-80b-a3b-4l``'s by default;
+``olmo-hybrid-7b-4l``'s is ``--shape 1,16384,30,30,96,192 --token-heads
+131072`` (the plain path six heads at a time, as its step runs it on a
+CPU).  Widths that are no multiple of 128 run at ``delta_rule.widths``'
+(``ran_at`` says which), and ``kernel_*`` then holds XLA's pads, slices
+and changes of layout round the kernels: ``alone_*`` is the pair on heads
+that come as wide as they run.  A width that ``widths`` refuses (under 32)
+is padded here by hand, what the wrapper would do if it took it
+(``forced``): the reading that says where the rule's line belongs."""
 import argparse
 import json
 import statistics
@@ -16,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from horovod_tpu.models.gated_delta import chunked_gated_delta_rule
+from horovod_tpu.models.gated_delta import (by_head_groups,
+                                            chunked_gated_delta_rule)
 from horovod_tpu.ops import delta_rule
 
 
@@ -63,6 +74,9 @@ def main():
     ap.add_argument("--shape", default="2,8192,16,32,128,128")
     ap.add_argument("--chunk", type=int, default=64)
     ap.add_argument("--plain", type=int, default=1)
+    ap.add_argument("--token-heads", type=int, default=0,
+                    help="the plain path a group of heads at a time: "
+                         "gated_delta.by_head_groups' (token, head) pairs")
     ap.add_argument("--groups", default="",
                     help="other lengths of the kernels' straight-line "
                          "stretch to time, as 1,4")
@@ -77,11 +91,29 @@ def main():
                  "--shape)")
     B, T, hk, hv, dk, dv = (int(x) for x in a.shape.split(","))
     rep = hv // hk
-    kernel = lambda q, k, v, g, beta: delta_rule.gated_delta_rule(
-        q, k, v, g, beta, a.chunk)
-    plain = lambda q, k, v, g, beta: chunked_gated_delta_rule(
+    ran_at = delta_rule.widths(dk, dv)
+    forced = ran_at is None
+    if forced:
+        ran_at = tuple(-(-d // delta_rule.LANES) * delta_rule.LANES
+                       for d in (dk, dv))
+
+    def wider(x, width):
+        return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[3]),))
+
+    def kernel(q, k, v, g, beta, **kw):
+        if forced:
+            q, k, v = wider(q, ran_at[0]), wider(k, ran_at[0]), wider(
+                v, ran_at[1])
+        return delta_rule.gated_delta_rule(q, k, v, g, beta, a.chunk,
+                                           **kw)[..., :dv]
+
+    rule = chunked_gated_delta_rule
+    if a.token_heads:
+        rule = by_head_groups(rule, a.token_heads)
+    plain = lambda q, k, v, g, beta: rule(
         jnp.repeat(q, rep, 2), jnp.repeat(k, rep, 2), v, g, beta, a.chunk)
     out = {"device": jax.devices()[0].device_kind, "shape": a.shape,
+           "ran_at": list(ran_at), "forced": forced,
            "rehearsal": a.rehearse}
     # the compiled kernels against the plain path, a sequence of 1024
     small = draw(1, 1024, hk, hv, dk, dv, jnp.bfloat16, key=1)
@@ -92,9 +124,17 @@ def main():
     fwd, both = pair(kernel)
     out["kernel_fwd_ms"] = ms(fwd, *args[:5])
     out["kernel_both_ms"] = ms(both, *args)
+    if ran_at != (dk, dv):
+        # the pair alone: the same heads, already as wide as they run
+        q, k, v, g, beta, do = args
+        alone = (wider(q, ran_at[0]), wider(k, ran_at[0]),
+                 wider(v, ran_at[1]), g, beta, wider(do, ran_at[1]))
+        fwd, both = pair(lambda *x: delta_rule.gated_delta_rule(*x, a.chunk))
+        out["alone_fwd_ms"] = ms(fwd, *alone[:5])
+        out["alone_both_ms"] = ms(both, *alone)
     for group in (int(x) for x in a.groups.split(",") if x):
-        fwd, both = pair(lambda q, k, v, g, beta: delta_rule.gated_delta_rule(
-            q, k, v, g, beta, a.chunk, group=group))
+        fwd, both = pair(lambda q, k, v, g, beta: kernel(
+            q, k, v, g, beta, group=group))
         out[f"group{group}_fwd_ms"] = ms(fwd, *args[:5])
         out[f"group{group}_both_ms"] = ms(both, *args)
     if a.plain:
