@@ -47,8 +47,8 @@ loss; the rotated half of a full layer's head is its first 64 numbers in
 the half-split pairing; YaRN's ramp as the published YaRN code computes it.
 
 Each attention block and each dense MLP is recomputed in the backward pass
-as its own region, an expert layer :data:`MOE_TOKENS` tokens at a time and
-the head :data:`HEAD_TOKENS` tokens at a time, recomputed too.
+as its own region, an expert layer too, and the head :data:`HEAD_TOKENS`
+tokens at a time, recomputed too.
 ``trace.attention`` counts, once a traced call site, which path a layer
 kind took: ``full_flash``, ``full_plain``, ``window_flash``,
 ``window_plain``.
@@ -68,7 +68,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from . import blocks as _blocks
 from . import moe as _moe
@@ -81,14 +80,6 @@ PUBLISHED_LAYERS = 48
 # tokens of a sequence whose logits over the vocabulary's rows are held
 # together, in the forward pass and again in the backward pass
 HEAD_TOKENS = 2048
-# tokens whose expert layer is computed together, each such run recomputed
-# in the backward pass: the layer gathers a row for every assignment made
-# anywhere (``moe.dropless_moe_ffn``: tokens x top_k rows, whatever share is
-# held), and at 16384 tokens of 3072 that buffer, the experts' output, and
-# both cotangents are 1 GB each; routing is a token's own, so a run of
-# tokens at a time is the same layer
-MOE_TOKENS = 4096
-
 ROPE_FULL = _blocks.Rotary(
     width=64, theta=500000.0, kind="yarn", factor=128.0, original_max=8192,
     beta_fast=32.0, beta_slow=1.0, attention_factor=1.4852030263919618)
@@ -269,40 +260,34 @@ def _mlp_block(p, x, cfg: LagunaConfig):
 
 def _expert_block(p, x, cfg: LagunaConfig):
     """``(x, held_counts [experts_held])``: a sparse layer's expert layer
-    behind the second norm, :data:`MOE_TOKENS` tokens at a time (as many
-    equal runs as leave a run that long at most), each run recomputed in
-    the backward pass."""
+    behind the second norm, over all of the tokens at once (the layer
+    holds rows for a block of the held assignments, not for every
+    assignment made anywhere)."""
     B, T, D = x.shape
-    S = B * T
-    runs = next(n for n in range(-(-S // MOE_TOKENS), S + 1) if S % n == 0)
-
-    def of_run(xr):                     # [S / runs, D]
-        y, counts = _moe.dropless_moe_ffn(
-            _rmsnorm(xr, p["mlp_norm"], cfg.norm_eps), p["moe"],
-            cfg.moe_cfg())
-        return xr + y, counts
-
-    y, counts = lax.map(jax.checkpoint(of_run), x.reshape(runs, S // runs, D))
-    return y.reshape(B, T, D), jnp.sum(counts, axis=0)
+    y, counts = _moe.dropless_moe_ffn(
+        _rmsnorm(x, p["mlp_norm"], cfg.norm_eps).reshape(B * T, D), p["moe"],
+        cfg.moe_cfg())
+    return x + y.reshape(B, T, D), counts
 
 
 def _hidden(params, tokens, cfg: LagunaConfig):
     """``(the last layer's output [B, T, d_model], held_counts [expert
     layers, experts_held])``, before the final norm."""
     x = params["embed"][tokens]
-    # Each attention block, each dense MLP and each run of an expert
-    # layer's tokens is recomputed in the backward pass, as regions of
+    # Each attention block, each dense MLP and each expert layer is
+    # recomputed in the backward pass, as regions of
     # their own, so that the backward pass never holds an attention
     # block's and an MLP's intermediates together (at 16 k tokens a sliding
     # layer's q and o are 302 MB each, the dense MLP's three products 403
     # MB each; a block's input is 101 MB).
     attention = jax.checkpoint(_attention_block, static_argnums=(2, 3))
     mlp = jax.checkpoint(_mlp_block, static_argnums=(2,))
+    experts = jax.checkpoint(_expert_block, static_argnums=(2,))
     counts = []
     for i, p in enumerate(params["layers"]):
         x = attention(p, x, cfg, i)
         if "moe" in p:
-            x, c = _expert_block(p, x, cfg)
+            x, c = experts(p, x, cfg)
             counts.append(c)
         else:
             x = mlp(p, x, cfg)

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
+from .. import trace
 from ..compat import axis_size as compat_axis_size
 
 
@@ -261,7 +262,11 @@ class DroplessMoEConfig:
     computes alike — the shared expert, and with a latent its projections
     — counted once) the parts are the whole layer.  No exchange is made
     here — one share on one chip runs as it stands; the all-to-all that
-    brings every rank's tokens to a share is ROADMAP queue 2 A's.
+    brings every rank's tokens to a share is ROADMAP queue 2 A's.  What a
+    share costs follows the rows it holds, not the assignments made
+    anywhere: :func:`dropless_moe_ffn` walks the sorted assignments in
+    blocks sized from ``n_experts`` and ``experts_held`` and stops after
+    the last held row.
 
     Fields of the model, not options of the system:
 
@@ -357,45 +362,6 @@ def dropless_init_params(cfg: DroplessMoEConfig, key) -> Dict:
     return p
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _gather_rows(x, order, inverse, top_k):
-    """Rows of ``x [S, D]`` in the order of the sorted assignments,
-    ``[S * top_k, D]``: row ``i`` is token ``order[i] // top_k``.  Linear;
-    its transpose is :func:`_sum_rows`, and each is the other's backward
-    pass, so that both directions are gathers (the transpose JAX derives
-    for a gather with repeated rows is a scatter-add)."""
-    return x[order // top_k]
-
-
-def _gather_rows_fwd(x, order, inverse, top_k):
-    return _gather_rows(x, order, inverse, top_k), (order, inverse)
-
-
-def _gather_rows_bwd(top_k, res, g):
-    order, inverse = res
-    return _sum_rows(g, order, inverse, top_k), None, None
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _sum_rows(y, order, inverse, top_k):
-    """``[S * top_k, D]`` in sorted order back to ``[S, D]``: each token
-    the sum of its ``top_k`` rows (:func:`_gather_rows`' transpose)."""
-    return y[inverse].reshape(-1, top_k, y.shape[-1]).sum(axis=1)
-
-
-def _sum_rows_fwd(y, order, inverse, top_k):
-    return _sum_rows(y, order, inverse, top_k), (order, inverse)
-
-
-def _sum_rows_bwd(top_k, res, g):
-    order, inverse = res
-    return _gather_rows(g, order, inverse, top_k), None, None
-
-
-_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
-_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
-
-
 def dropless_route(x, router_w, cfg: DroplessMoEConfig, bias=None):
     """``(ids [S, top_k], weights [S, top_k] float32)`` over ALL
     ``n_experts``, in float32 (the product at ``HIGHEST`` precision: which
@@ -419,17 +385,25 @@ def dropless_route(x, router_w, cfg: DroplessMoEConfig, bias=None):
 
 # a block of the sorted assignments is this many times the rows that even
 # routing sends to the held experts (``dropless_blocks``)
-BLOCK_OVER_EXPECTED = 8
+BLOCK_OVER_EXPECTED = 1.5
 
 
 def dropless_blocks(rows, cfg: DroplessMoEConfig) -> int:
     """In how many equal blocks the ``rows`` sorted assignments are taken:
     as many as leave a block ``BLOCK_OVER_EXPECTED`` times the rows that
     even routing sends to the held experts, among the divisors of ``rows``.
-    One block down to a share of an eighth; two at a sixteenth; four at a
-    thirty-second."""
-    most = max(1, cfg.n_experts // (BLOCK_OVER_EXPECTED * cfg.held))
+    One block where every expert is held; five at an eighth of 163840 rows,
+    sixteen at a thirty-second of 180224."""
+    most = max(1, int(cfg.n_experts / (BLOCK_OVER_EXPECTED * cfg.held)))
     return max(n for n in range(1, most + 1) if rows % n == 0)
+
+
+def live_blocks(held_counts, rows, cfg: DroplessMoEConfig):
+    """How many of :func:`dropless_blocks`' blocks a call computes, from
+    the counts it returns (``[..., experts_held]``, a call a row): those
+    that begin before the last held row."""
+    block = rows // dropless_blocks(rows, cfg)
+    return (held_counts.sum(axis=-1) + block - 1) // block
 
 
 def _expert_products(rows, gate, here, counts, params, cfg):
@@ -458,78 +432,100 @@ def _expert_products(rows, gate, here, counts, params, cfg):
     return grouped(hidden, params["w2"])
 
 
-def _in_blocks(blocks, *buffers):
-    """``buffers`` (sorted assignments lead) cut into ``blocks`` equal runs,
-    and where each run begins."""
-    R = buffers[0].shape[0] // blocks
-    return tuple(b.reshape((blocks, R) + b.shape[1:]) for b in buffers) + (
-        jnp.arange(blocks, dtype=jnp.int32) * R,)
-
-
-def _block_groups(lo, rows, held_counts):
-    """``(counts, here, any)`` for the block of ``rows`` sorted assignments
-    that begins at ``lo``: the part of each held expert's group that lies
-    in it, which of its rows are held at all, and whether any is."""
+def _block(b, rows, top_k, order, gate, held_counts):
+    """``(lo, tokens, gate, counts, here)`` of block ``b`` of ``rows``
+    sorted assignments: where it begins, each row's token, its router
+    weight, the part of each held expert's group that lies in the block,
+    and which of its rows are held at all."""
+    lo = b * rows
     ends = jnp.cumsum(held_counts)
     starts = ends - held_counts
     counts = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo),
                       0)
-    return counts, (lo + jnp.arange(rows) < ends[-1])[:, None], lo < ends[-1]
+    return (lo, lax.dynamic_slice(order, (lo,), (rows,)) // top_k,
+            lax.dynamic_slice(gate, (lo,), (rows,)), counts,
+            (lo + jnp.arange(rows) < ends[-1])[:, None])
+
+
+def _take_rows(x, tokens):
+    """``x [S, D]`` at ``tokens [R]``: a block's rows.  Its transpose is
+    :func:`_add_rows`; the block loop of :func:`_blocked_experts` writes
+    both passes itself, so that each is the other's backward pass."""
+    return x.at[tokens].get(mode="promise_in_bounds")
+
+
+def _add_rows(acc, rows, tokens):
+    """``rows [R, D]`` added into the float32 ``acc [S, D]`` at ``tokens
+    [R]`` (:func:`_take_rows`' transpose)."""
+    return acc.at[tokens].add(rows.astype(acc.dtype),
+                              mode="promise_in_bounds")
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _blocked_products(cfg, blocks, rows, gate, held_counts, w):
-    """:func:`_expert_products` over the sorted assignments in ``blocks``
-    equal blocks, one after the other; a block that begins past the last
-    held row is skipped in both passes (``lax.cond``), and its part of the
-    result is zero.  The backward pass recomputes a block's products inside
-    the branch that differentiates them, so that nothing an expert's width
-    wide crosses a branch's boundary, and carries the matrices' gradients
-    through the blocks: a skipped block neither fills nor adds one."""
-    def block(of_block):
-        rows_b, gate_b, lo = of_block
-        counts, here, held = _block_groups(lo, rows_b.shape[0], held_counts)
-        return lax.cond(
-            held,
-            lambda: _expert_products(rows_b, gate_b, here, counts, w, cfg),
-            lambda: jnp.zeros_like(rows_b))
+def _blocked_experts(cfg, blocks, z, order, gate, held_counts, w):
+    """The held experts' part ``[S, d_expert_io]`` for tokens ``z``: the
+    sorted assignments in ``blocks`` equal blocks, one after the other,
+    as many as :func:`live_blocks` says (a loop of that many trips: a block
+    that begins past the last held row costs nothing in either pass).  A
+    block gathers its own rows (``moe/dispatch``), runs
+    :func:`_expert_products` on them (``moe/experts``) and adds its result
+    at its rows' tokens into a float32 sum (``moe/combine``) that is
+    rounded once.  The backward pass walks the same blocks: it gathers a
+    block's rows and its cotangent's, recomputes and differentiates the
+    block's products, adds the rows' gradient at their tokens and carries
+    the matrices' gradients through the live blocks."""
+    S, K, R = z.shape[0], cfg.top_k, order.shape[0] // blocks
 
-    return lax.map(block, _in_blocks(blocks, rows, gate)).reshape(rows.shape)
+    def block(b, acc):
+        with jax.named_scope("moe/dispatch"):
+            _, tokens, gate_b, counts, here = _block(b, R, K, order, gate,
+                                                     held_counts)
+            rows = _take_rows(z, tokens)
+        with jax.named_scope("moe/experts"):
+            out = _expert_products(rows, gate_b, here, counts, w, cfg)
+        with jax.named_scope("moe/combine"):
+            return _add_rows(acc, out, tokens)
+
+    return lax.fori_loop(
+        0, live_blocks(held_counts, order.shape[0], cfg), block,
+        jnp.zeros((S, z.shape[1]), jnp.float32)).astype(z.dtype)
 
 
-def _blocked_products_fwd(cfg, blocks, rows, gate, held_counts, w):
-    return (_blocked_products(cfg, blocks, rows, gate, held_counts, w),
-            (rows, gate, held_counts, w))
+def _blocked_experts_fwd(cfg, blocks, z, order, gate, held_counts, w):
+    return (_blocked_experts(cfg, blocks, z, order, gate, held_counts, w),
+            (z, order, gate, held_counts, w))
 
 
-def _blocked_products_bwd(cfg, blocks, res, ct):
-    rows, gate, held_counts, w = res
+def _blocked_experts_bwd(cfg, blocks, res, ct):
+    z, order, gate, held_counts, w = res
+    K, R = cfg.top_k, order.shape[0] // blocks
 
-    def block(d_w, of_block):
-        rows_b, gate_b, ct_b, lo = of_block
-        counts, here, held = _block_groups(lo, rows_b.shape[0], held_counts)
-
-        def run(d_w):
+    def block(b, carry):
+        d_z, d_gate, d_w = carry
+        with jax.named_scope("moe/dispatch"):
+            lo, tokens, gate_b, counts, here = _block(b, R, K, order, gate,
+                                                      held_counts)
+            rows = _take_rows(z, tokens)
+        with jax.named_scope("moe/combine"):
+            ct_b = _take_rows(ct, tokens)
+        with jax.named_scope("moe/experts"):
             _, back = jax.vjp(
                 lambda r, g, w_: _expert_products(r, g, here, counts, w_,
-                                                  cfg), rows_b, gate_b, w)
-            d_rows, d_gate, d_w_b = back(ct_b)
-            return jax.tree_util.tree_map(jnp.add, d_w, d_w_b), (d_rows,
-                                                                 d_gate)
+                                                  cfg), rows, gate_b, w)
+            d_rows, d_gate_b, d_w_b = back(ct_b)
+            d_w = jax.tree_util.tree_map(jnp.add, d_w, d_w_b)
+        with jax.named_scope("moe/dispatch"):
+            d_z = _add_rows(d_z, d_rows, tokens)
+        return d_z, lax.dynamic_update_slice(d_gate, d_gate_b, (lo,)), d_w
 
-        return lax.cond(
-            held, run,
-            lambda d_w: (d_w, (jnp.zeros_like(rows_b),
-                               jnp.zeros_like(gate_b))), d_w)
-
-    d_w, (d_rows, d_gate) = lax.scan(
-        block, jax.tree_util.tree_map(jnp.zeros_like, w),
-        _in_blocks(blocks, rows, gate, ct))
-    return (d_rows.reshape(rows.shape), d_gate.reshape(gate.shape), None,
-            d_w)
+    d_z, d_gate, d_w = lax.fori_loop(
+        0, live_blocks(held_counts, order.shape[0], cfg), block,
+        (jnp.zeros(z.shape, jnp.float32), jnp.zeros_like(gate),
+         jax.tree_util.tree_map(jnp.zeros_like, w)))
+    return d_z.astype(z.dtype), None, d_gate, None, d_w
 
 
-_blocked_products.defvjp(_blocked_products_fwd, _blocked_products_bwd)
+_blocked_experts.defvjp(_blocked_experts_fwd, _blocked_experts_bwd)
 
 
 def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
@@ -538,53 +534,44 @@ def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
     Returns ``(y [S, D], held_counts [experts_held] int32)``: ``y`` is the
     routed part of the experts held here plus the shared expert (where
     ``d_shared``), ``held_counts`` the assignments that landed on each held
-    expert.  Nothing is dropped for any routing: the assignments are
-    sorted by expert, the held ones first, into a buffer of all ``S *
-    top_k`` rows (the most that can land here), and the grouped products
-    (``lax.ragged_dot``) compute the rows the held experts' groups cover.
-    Static shapes; between no assignment here and all of them the result
-    is exact.
-
-    Where the share is small the sorted rows are taken in equal blocks
-    (:func:`dropless_blocks`, :func:`_blocked_products`), each recomputed
-    in the backward pass, and a block that begins past the last held row
-    is skipped (``lax.cond``): an expert's hidden width is then held for a
-    block, not for every assignment made anywhere, and with even routing
-    the first block is the only one computed.  With a latent (``d_latent``) the rows are the
-    latent's, under the scope ``moe/latent`` with the projection back.
+    expert.  Nothing is dropped for any routing: the ``S * top_k``
+    assignments are sorted by expert, the held ones first, and taken in
+    equal blocks (:func:`dropless_blocks`: a block a little more than even
+    routing sends here).  A block gathers its own rows, computes the part
+    of the held experts' groups that lies in it (``lax.ragged_dot``) and
+    adds its result at its rows' tokens (:func:`_blocked_experts`); the
+    blocks past the last held row are not walked, in either pass
+    (:func:`live_blocks`).  So the layer costs what the held rows cost:
+    one block with the usual load, every block with every assignment here,
+    none with none — static shapes, and the result is exact between them.
+    A token's sum is float32, rounded once.  With a latent (``d_latent``)
+    the rows are the latent's, under the scope ``moe/latent`` with the
+    projection back.
     """
-    K, H = cfg.top_k, cfg.held
+    H = cfg.held
     with jax.named_scope("moe/route"):
         ids, weights = dropless_route(x, params["router"], cfg,
                                       params.get("router_bias"))
         local = ids.reshape(-1) - cfg.first_expert          # [S * K]
-        here = (local >= 0) & (local < H)
         # held assignments first, by expert; the others after every group
-        keys = jnp.where(here, local, H)
+        keys = jnp.where((local >= 0) & (local < H), local, H)
         order = jnp.argsort(keys, stable=True)
-        inverse = jnp.argsort(order)
         held_counts = jnp.sum(
             keys[:, None] == jnp.arange(H, dtype=keys.dtype)[None, :],
             axis=0, dtype=jnp.int32)
-        gate, here = weights.reshape(-1)[order], here[order][:, None]
+        gate = weights.reshape(-1)[order]
 
     z = x
     if cfg.d_latent:
         with jax.named_scope("moe/latent"):
             z = x @ params["w_down"]
-    with jax.named_scope("moe/dispatch"):
-        rows = _gather_rows(z, order, inverse, K)           # [S * K, L]
-    with jax.named_scope("moe/experts"):
-        blocks = dropless_blocks(rows.shape[0], cfg)
-        if blocks == 1:
-            out = _expert_products(rows, gate, here, held_counts, params,
-                                   cfg)
-        else:
-            out = _blocked_products(
-                cfg, blocks, rows, gate, held_counts,
-                {k: params[k] for k in ("w1", "w2", "w3") if k in params})
-    with jax.named_scope("moe/combine"):
-        y = _sum_rows(out, order, inverse, K)
+    blocks = dropless_blocks(order.shape[0], cfg)
+    trace.expert_blocks["sites"] += 1
+    trace.expert_blocks["blocks"] += blocks
+    trace.expert_blocks["block_rows"] += order.shape[0] // blocks
+    y = _blocked_experts(
+        cfg, blocks, z, order, gate, held_counts,
+        {k: params[k] for k in ("w1", "w2", "w3") if k in params})
     if cfg.d_latent:
         with jax.named_scope("moe/latent"):
             y = y @ params["w_up"]

@@ -25,7 +25,7 @@ from . import core
 from .core import (DIGEST_MAX_CYCLES, DIGEST_MAX_OPEN, OFF, PHASE_BUCKETS_US,
                    PHASES, REDUCE_LEGS, CycleRecord, ProgramSpan, TensorSpan,
                    TraceRecorder, attention, causal_conv, delta_rule,
-                   flash_blocks, inner_update, installed, selective_scan,
+                   expert_blocks, flash_blocks, inner_update, installed, selective_scan,
                    span, stage_group, startup, startup_span, write_startup)
 from .writer import TraceWriter
 
@@ -34,8 +34,8 @@ __all__ = [
     "DIGEST_MAX_OPEN", "CycleRecord", "TensorSpan", "TraceRecorder",
     "TraceWriter", "maybe_install", "span", "installed", "OFF",
     "ProgramSpan", "inner_update", "stage_group", "causal_conv",
-    "selective_scan", "delta_rule", "flash_blocks", "attention", "startup",
-    "startup_span", "write_startup",
+    "selective_scan", "delta_rule", "expert_blocks", "flash_blocks",
+    "attention", "startup", "startup_span", "write_startup",
 ]
 
 

@@ -311,6 +311,14 @@ selective_scan = {"kernel": 0, "plain": 0}
 # rising on a TPU is a shape the kernels do not take.
 delta_rule = {"kernel": 0, "padded": 0, "plain": 0}
 
+# The dropless expert layer's blocks (``models/moe.py``
+# ``dropless_moe_ffn``), added up in Python once a traced call site:
+# ``sites`` the call sites, ``blocks`` the equal blocks their sorted
+# assignments are cut into, ``block_rows`` the rows of a block.  How many of
+# a site's blocks a call computes hangs on the routing:
+# ``moe.live_blocks`` gives it from the counts the layer returns.
+expert_blocks = {"sites": 0, "blocks": 0, "block_rows": 0}
+
 # The flash attention kernels' block schedules (``ops/flash_attention.py``
 # ``_schedule``), added up in Python once a traced kernel call, a head:
 # ``grid`` the blocks of the dense grid, ``steps`` the steps the schedule
@@ -368,6 +376,11 @@ _register_counts("hvd_delta_rule", delta_rule, {
     "padded": "chunked delta rule call sites traced as the Pallas kernels "
               "at widths rounded up to whole lanes",
     "plain": "chunked delta rule call sites traced as XLA's own code"})
+_register_counts("hvd_expert_blocks", expert_blocks, {
+    "sites": "dropless expert layer call sites traced",
+    "blocks": "blocks the traced expert layers cut their sorted "
+              "assignments into",
+    "block_rows": "rows of a block, added over the traced expert layers"})
 _register_counts("hvd_attention", attention, {
     "full_flash": "full-attention call sites traced as the flash kernels",
     "full_plain": "full-attention call sites traced as XLA's own code",

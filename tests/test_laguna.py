@@ -25,7 +25,8 @@ if ROOT not in sys.path:
 
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import laguna as ref               # noqa: E402
-from family import Seeded, planted, worst_rel               # noqa: E402
+from family import (EXPERT_ROUTINGS, Seeded,                 # noqa: E402
+                    expert_blocks_case, planted, worst_rel)
 from horovod_tpu import trace                               # noqa: E402
 from horovod_tpu.compat import shard_map                    # noqa: E402
 from horovod_tpu.models import blocks, laguna, moe          # noqa: E402
@@ -116,8 +117,10 @@ def test_the_cells_share_counts_what_the_configuration_file_says():
     assert cfg.rope_full == laguna.ROPE_FULL
     assert cfg.rope_sliding == laguna.ROPE_SLIDING
     assert (cfg.first_expert, cfg.experts_held, cfg.n_experts) == (0, 16, 256)
-    # a sixteenth of the experts: two blocks of a run's sorted assignments
-    assert moe.dropless_blocks(laguna.MOE_TOKENS * 10, cfg.moe_cfg()) == 2
+    # a sixteenth of the experts: the sequence's sorted assignments in ten
+    # blocks of 16384
+    assert moe.dropless_blocks(cell.sizes["seq_len"] * 10,
+                               cfg.moe_cfg()) == 10
 
 
 @pytest.mark.parametrize("kw, match", [
@@ -330,6 +333,18 @@ def test_all_shares_parts_add_up_to_the_whole_layer(held):
         jnp.max(jnp.abs(routed)))
 
 
+@pytest.mark.parametrize("routing", list(EXPERT_ROUTINGS))
+def test_the_blocks_are_a_plain_loop_over_the_held_experts(routing):
+    """This family's form of the layer (sigmoid scoring, SwiGLU experts, an
+    ungated shared expert, no latent, the weights scaled) block by block
+    is a plain loop over the held experts, values and gradients, under
+    the four routings of ``family.expert_blocks_case``."""
+    expert_blocks_case(moe.DroplessMoEConfig(
+        d_model=32, d_ff=24, n_experts=64, top_k=4, first_expert=8,
+        experts_held=4, d_shared=16, scoring="sigmoid", routed_scale=2.5,
+        shared_gate=False), routing)
+
+
 def test_the_chosen_weigh_by_their_score_over_its_sum_times_the_scale():
     params, _, _ = SEEDED
     x = jax.random.normal(KEY, (24, 64))
@@ -398,25 +413,6 @@ def test_the_head_in_blocks_is_the_head(monkeypatch, block):
         monkeypatch.setattr(laguna, "HEAD_TOKENS", block)
         got = laguna.loss_fn(params, toks, tgts, cfg)
     assert abs(float(got) - float(want)) <= 1e-6 * float(want)
-
-
-@pytest.mark.parametrize("run", [32, 100])
-def test_the_expert_layer_in_runs_of_tokens_is_the_layer(monkeypatch, run):
-    """128 tokens in runs of 32 and of 64 (the equal runs of at most 100)
-    against the whole: the same loss, gradients and counts."""
-    params, toks, tgts = SEEDED
-    cfg = config()
-    loss = lambda p: laguna.loss_fn(p, toks, tgts, cfg)
-    with jax.default_matmul_precision("highest"):
-        monkeypatch.setattr(laguna, "MOE_TOKENS", 10 ** 6)
-        want = jax.value_and_grad(loss)(params)
-        counts = laguna.expert_load(params, toks, cfg)
-        monkeypatch.setattr(laguna, "MOE_TOKENS", run)
-        got = jax.value_and_grad(loss)(params)
-        assert (np.asarray(laguna.expert_load(params, toks, cfg))
-                == np.asarray(counts)).all()
-    assert abs(float(got[0]) - float(want[0])) <= 1e-6 * float(want[0])
-    assert worst_rel(got[1], want[1]) <= 1e-5
 
 
 # ----------------------------------------------------------- planted faults

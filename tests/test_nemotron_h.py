@@ -24,7 +24,8 @@ if ROOT not in sys.path:
 
 import horovod_tpu as hvd                                   # noqa: E402
 from benchmark.reference import nemotron_h as ref           # noqa: E402
-from family import Seeded, planted, worst_rel               # noqa: E402
+from family import (EXPERT_ROUTINGS, Seeded,                 # noqa: E402
+                    expert_blocks_case, planted, worst_rel)
 from horovod_tpu.compat import shard_map                    # noqa: E402
 from horovod_tpu.models import (blocks, gated_delta, mamba2, moe,  # noqa: E402
                                 nemotron_h)
@@ -309,36 +310,40 @@ def test_all_shares_parts_add_up_to_the_whole_layer(held):
 
 
 def test_blocks_are_taken_where_the_share_is_small():
-    """One block down to a share of an eighth (``qwen3next-80b-a3b-4l``:
-    64 of 512), two at a sixteenth (``laguna-s-2_1-5l``: 16 of 256, the
-    sorted assignments of a run of 4096 tokens), four at a thirty-second
-    (16 of 512), among the divisors of the rows."""
+    """A block is one and a half times what even routing sends here, among
+    the divisors of the rows: one block where every expert is held, five
+    at an eighth (``qwen3next-80b-a3b-4l``: 64 of 512, blocks of 32768),
+    ten at a sixteenth (``laguna-s-2_1-5l``: 16 of 256), sixteen at a
+    thirty-second (16 of 512 over 8192 x 22 rows: blocks of 11264)."""
     of = lambda held, rows: moe.dropless_blocks(rows, moe.DroplessMoEConfig(
         n_experts=512, top_k=2, experts_held=held))
-    assert of(64, 16384 * 10) == 1 and of(512, 16384 * 10) == 1
-    assert of(32, 16384 * 10) == 2
-    assert moe.dropless_blocks(4096 * 10, moe.DroplessMoEConfig(
-        n_experts=256, top_k=10, experts_held=16)) == 2
-    assert of(16, 8192 * 22) == 4 and of(8, 8192 * 22) == 8
-    assert of(16, 3 * 7 * 11) == 3        # the largest divisor up to 4
+    assert of(64, 16384 * 10) == 5 and of(512, 16384 * 10) == 1
+    assert of(32, 16384 * 10) == 10
+    sixteenth = moe.DroplessMoEConfig(n_experts=256, top_k=10,
+                                      experts_held=16)
+    assert moe.dropless_blocks(4096 * 10, sixteenth) == 10
+    assert moe.dropless_blocks(16384 * 10, sixteenth) == 10
+    assert of(16, 8192 * 22) == 16 and of(8, 8192 * 22) == 32
+    assert of(16, 5 * 7 * 11) == 11       # the largest divisor up to 21
 
 
 @pytest.mark.parametrize("bias_on_held, held_rows", [
-    (0.7, (15, 10)),    # a share's usual load: the first block alone
-    (-50.0, (0, 0)),    # nothing routed here: every block skipped
-    (1.0, (65, 50)),    # the second expert's group lies in two blocks
-    (50.0, (96, 96)),   # every token chooses both: a block each
+    (0.7, (15, 10)),    # a row over one block: two live, the second all but
+                        # empty
+    (-50.0, (0, 0)),    # nothing routed here: no block walked
+    (1.0, (65, 50)),    # five blocks live; both groups cross blocks' edges
+    (50.0, (96, 96)),   # every token chooses both: eight of the sixteen
 ])
 def test_blocks_give_what_one_block_gives(monkeypatch, bias_on_held,
                                           held_rows):
     """Values and gradients of the layer with the sorted assignments in
-    four blocks of 96 rows are those of one block, whatever the routing; a
-    block past the last held row is skipped in both passes."""
+    sixteen blocks of 24 rows are those of one block, whatever the routing;
+    a block past the last held row is not walked, in either pass."""
     cfg = dataclasses.replace(LATENT, n_experts=64, top_k=4, first_expert=8,
                               experts_held=2)
     params, x = latent_layer(cfg, tokens=96)
     params["router_bias"] = params["router_bias"].at[8:10].add(bias_on_held)
-    assert moe.dropless_blocks(96 * 4, cfg) == 4
+    assert moe.dropless_blocks(96 * 4, cfg) == 16
 
     def loss(p, x):
         y, counts = moe.dropless_moe_ffn(x, p, cfg)
@@ -356,6 +361,17 @@ def test_blocks_give_what_one_block_gives(monkeypatch, bias_on_held,
     assert worst_rel(g_got, g_want) <= 1e-5
     if sum(held_rows):  # the held experts' matrices take a gradient
         assert float(jnp.max(jnp.abs(g_got[0]["w1"]))) > 0
+
+
+@pytest.mark.parametrize("routing", list(EXPERT_ROUTINGS))
+def test_the_blocks_are_a_plain_loop_over_the_held_experts(routing):
+    """This family's form of the layer (sigmoid scoring with a selection
+    bias, ``relu^2`` experts in a latent, an ungated shared expert) block
+    by block is a plain loop over the held experts, values and gradients,
+    under the four routings of ``family.expert_blocks_case``."""
+    expert_blocks_case(dataclasses.replace(
+        LATENT, n_experts=64, top_k=4, first_expert=8, experts_held=4),
+        routing)
 
 
 def test_the_gated_softmax_layer_in_blocks_is_one_blocks(monkeypatch):

@@ -18,7 +18,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark.reference import qwen3_next as ref      # noqa: E402
-from family import Seeded, worst_rel                    # noqa: E402
+from family import (EXPERT_ROUTINGS, Seeded,            # noqa: E402
+                    expert_blocks_case, worst_rel)
+from horovod_tpu import trace                           # noqa: E402
 from horovod_tpu.models import moe, qwen3_next          # noqa: E402
 
 # one period, 16 experts of which 4 are held, top-2: the configuration
@@ -203,6 +205,85 @@ def test_no_assignment_is_lost_at_either_end(experts, here):
     if here == 0:
         assert float(jnp.max(jnp.abs(routed))) == 0.0
     assert worst_rel(got, want) <= 1e-4
+
+
+@pytest.mark.parametrize("routing", list(EXPERT_ROUTINGS))
+def test_the_blocks_are_a_plain_loop_over_the_held_experts(routing):
+    """This family's form of the layer (softmax scoring, SwiGLU experts, a
+    gated shared expert, no latent) block by block — the usual load in one
+    block, every assignment here in all eight, none in none, three with
+    the last part full — is a plain loop over the held experts, values and
+    gradients, and ``live_blocks`` says how many blocks ran."""
+    expert_blocks_case(moe.DroplessMoEConfig(
+        d_model=32, d_ff=24, n_experts=64, top_k=4, first_expert=8,
+        experts_held=4, d_shared=16), routing)
+
+
+def test_the_counter_adds_up_a_sites_blocks():
+    """``trace.expert_blocks``: a traced call site adds itself, the blocks
+    its sorted assignments are cut into and a block's rows; a second call
+    of the compiled function adds nothing."""
+    cfg = moe.DroplessMoEConfig(d_model=32, d_ff=24, n_experts=64, top_k=4,
+                                first_expert=8, experts_held=4)
+    params = moe.dropless_init_params(cfg, KEY)
+    x = jax.random.normal(KEY, (96, 32))
+    before = dict(trace.expert_blocks)
+    layer = jax.jit(lambda p, x: moe.dropless_moe_ffn(x, p, cfg))
+    layer(params, x), layer(params, x)
+    blocks = moe.dropless_blocks(96 * 4, cfg)
+    assert {k: trace.expert_blocks[k] - n for k, n in before.items()} == {
+        "sites": 1, "blocks": blocks, "block_rows": 96 * 4 // blocks}
+    whole = moe.DroplessMoEConfig(d_model=32, d_ff=24, n_experts=64, top_k=4)
+    moe.dropless_moe_ffn(x, moe.dropless_init_params(whole, KEY), whole)
+    assert {k: trace.expert_blocks[k] - n for k, n in before.items()} == {
+        "sites": 2, "blocks": blocks + 1,
+        "block_rows": 96 * 4 // blocks + 96 * 4}
+
+
+def test_monitor_agent_exports_the_three_counts():
+    """The counts are registered series: ``/metrics`` serves them."""
+    from horovod_tpu.monitor.agent import MonitorAgent
+
+    class Engine:
+        monitor = None
+
+    cfg = moe.DroplessMoEConfig(d_model=32, d_ff=24, n_experts=64, top_k=4,
+                                first_expert=8, experts_held=4)
+    params = moe.dropless_init_params(cfg, KEY)
+    blocks = moe.dropless_blocks(96 * 4, cfg)
+    agent = MonitorAgent(engine=Engine())
+    try:
+        first = agent.registry.snapshot()
+        moe.dropless_moe_ffn(jnp.ones((96, 32)), params, cfg)
+        second = agent.registry.snapshot()
+        text = agent.registry.to_prometheus('rank="0"')
+    finally:
+        agent.close()
+
+    def value(snap, name):
+        return snap[name]["value"] if isinstance(snap[name], dict) \
+            else snap[name]
+
+    for key, moved in (("sites", 1), ("blocks", blocks),
+                       ("block_rows", 96 * 4 // blocks)):
+        name = f"hvd_expert_blocks_{key}_total"
+        assert value(second, name) - value(first, name) == moved
+        assert name in text and trace.core.SERIES[name][0] == "counter"
+
+
+def test_live_blocks_reads_a_call_a_row():
+    """``live_blocks`` over ``expert_load``'s ``[layers, experts_held]``:
+    a layer a call, a NumPy array in and out."""
+    cfg = moe.DroplessMoEConfig(n_experts=512, top_k=10, experts_held=64)
+    rows = 16384 * 10
+    block = rows // moe.dropless_blocks(rows, cfg)
+    counts = np.zeros((3, 64), np.int64)
+    counts[1, :] = block // 64            # a block to the row
+    counts[2, 0] = rows                   # every assignment on one expert
+    assert moe.live_blocks(counts, rows, cfg).tolist() == [
+        0, 1, moe.dropless_blocks(rows, cfg)]
+    counts[1, 5] += 1
+    assert moe.live_blocks(counts, rows, cfg).tolist()[1] == 2
 
 
 @pytest.mark.parametrize("kw", [dict(first_expert=14, experts_held=4),

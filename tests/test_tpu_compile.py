@@ -283,17 +283,52 @@ def test_ring_attention_partitions_over_sp4(mesh4, causal):
     assert "collective-permute" in text
 
 
-def test_dropless_expert_layer_compiles_for_v5e(one_chip):
-    """The share-aware expert layer at the published widths (64 of 512
-    experts held, top-10, 4096 tokens), forward and backward: the grouped
-    products have to stay the TPU's own grouped-matmul kernels
-    (``ragged-dot-...`` custom calls), which skip the rows past the held
-    groups; a dense fallback would cost 64 times the work."""
+def _expert_layers():
+    """The dropless expert layer as the three cells that run it have it:
+    ``(config, tokens a call, blocks, grouped products at least)``."""
+    from horovod_tpu.models import moe
+    return {
+        # 64 of 512 experts held, top-10 softmax, SwiGLU, a gated shared
+        # expert: w1, w3, w2 again and six transposes in the backward loop
+        "qwen3next": (moe.DroplessMoEConfig(
+            d_model=2048, d_ff=512, n_experts=512, top_k=10, first_expert=0,
+            experts_held=64, d_shared=512, dtype=jnp.bfloat16),
+            16384, 5, 9),
+        # 16 of 512, top-22 sigmoid, relu^2 experts of 2688 in a latent of
+        # 1024: w1, w2 forward; again and four transposes backward
+        "nemotron3super": (moe.DroplessMoEConfig(
+            d_model=4096, d_ff=2688, n_experts=512, top_k=22, first_expert=0,
+            experts_held=16, d_shared=5376, dtype=jnp.bfloat16,
+            scoring="sigmoid", routed_scale=5.0, expert_form="relu2",
+            d_latent=1024, shared_gate=False), 8192, 16, 8),
+        # 16 of 256, top-10 sigmoid, SwiGLU, an ungated shared expert
+        "laguna_s2_1": (moe.DroplessMoEConfig(
+            d_model=3072, d_ff=1024, n_experts=256, top_k=10, first_expert=0,
+            experts_held=16, d_shared=1024, dtype=jnp.bfloat16,
+            scoring="sigmoid", routed_scale=2.5, shared_gate=False),
+            16384, 10, 9),
+    }
+
+
+@pytest.mark.parametrize("cell", ["qwen3next", "nemotron3super",
+                                  "laguna_s2_1"])
+def test_dropless_expert_layer_compiles_for_v5e(one_chip, cell):
+    """The share-aware expert layer at a cell's widths, share and tokens,
+    forward and backward: the sorted assignments in blocks a little over
+    what even routing sends here, walked by a loop of as many trips as
+    blocks are live (no conditional); the grouped products stay the TPU's
+    own grouped-matmul kernels (``ragged-dot-...`` custom calls: a dense
+    fallback would cost every expert's work); nothing an expert's width
+    wide, and no row of the model's, exists for every assignment made
+    anywhere; and the loop's body operations carry the scopes the
+    benchmark's readers sum (``moe/dispatch``, ``moe/experts``,
+    ``moe/combine``) while the ``while`` itself carries none of them, so
+    that no reader counts the loop whole under one name."""
     from horovod_tpu.models import moe
 
-    cfg = moe.DroplessMoEConfig(d_model=2048, d_ff=512, n_experts=512,
-                                top_k=10, first_expert=0, experts_held=64,
-                                d_shared=512, dtype=jnp.bfloat16)
+    cfg, tokens, blocks, products = _expert_layers()[cell]
+    rows = tokens * cfg.top_k
+    assert moe.dropless_blocks(rows, cfg) == blocks
     params = jax.eval_shape(lambda k: moe.dropless_init_params(cfg, k),
                             jax.random.PRNGKey(0))
     at = lambda t: jax.tree_util.tree_map(
@@ -301,14 +336,25 @@ def test_dropless_expert_layer_compiles_for_v5e(one_chip):
         t)
 
     def loss(p, x):
-        return moe.dropless_moe_ffn(x, p, cfg)[0].astype(jnp.float32).sum()
+        y = moe.dropless_moe_ffn(x, p, cfg)[0].astype(jnp.float32)
+        return jnp.sum(y * y)
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        at(params), jax.ShapeDtypeStruct((4096, 2048), jnp.bfloat16,
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        at(params), jax.ShapeDtypeStruct((tokens, cfg.d_model), jnp.bfloat16,
                                          sharding=one_chip)).compile()
     text = compiled.as_text()
-    # w1, w3, w2 forward; six transposes backward
-    assert text.count("%ragged-dot-none") >= 9
+    assert text.count("%ragged-dot-none") >= products
+    assert " conditional(" not in text
+    for width in (cfg.d_ff, cfg.d_expert_io):
+        assert f"[{rows},{width}]" not in text
+    assert f"[{rows // blocks},{cfg.d_ff}]" in text
+    names = dict(re.findall(r'%([\w.\-]+) = [^\n]*?op_name="([^"]*)"', text))
+    loops = [op for name, op in names.items() if name.startswith("while")
+             and op.endswith("/while")]
+    assert len(loops) >= 2 and not any("moe/" in op for op in loops)
+    for scope in ("moe/dispatch", "moe/experts", "moe/combine"):
+        assert any(re.search(rf"/while/body/(.*/)?{scope}(/|$)", op)
+                   for op in names.values()), scope
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
@@ -496,49 +542,15 @@ def test_chunked_ssd_compiles_for_v5e(one_chip, at_once):
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9
 
 
-def test_latent_expert_layer_compiles_for_v5e(one_chip):
-    """The share-aware expert layer as ``nemotron_h`` has it (16 of 512
-    experts held, top-22 sigmoid, ``relu^2`` experts of 2688 in a latent
-    of 1024, 8192 tokens), forward and backward: the sorted assignments in
-    four blocks, each skipped or computed by a conditional, the grouped
-    products still the TPU's own kernels, and no buffer an expert's width
-    wide over all 180,224 assignments."""
-    from horovod_tpu.models import moe
-
-    cfg = moe.DroplessMoEConfig(
-        d_model=4096, d_ff=2688, n_experts=512, top_k=22, first_expert=0,
-        experts_held=16, d_shared=5376, dtype=jnp.bfloat16,
-        scoring="sigmoid", routed_scale=5.0, expert_form="relu2",
-        d_latent=1024, shared_gate=False)
-    assert moe.dropless_blocks(8192 * 22, cfg) == 4
-    params = jax.eval_shape(lambda k: moe.dropless_init_params(cfg, k),
-                            jax.random.PRNGKey(0))
-    at = lambda t: jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        t)
-
-    def loss(p, x):
-        return moe.dropless_moe_ffn(x, p, cfg)[0].astype(jnp.float32).sum()
-
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        at(params), jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16,
-                                         sharding=one_chip)).compile()
-    text = compiled.as_text()
-    # w1, w2 forward; again and four transposes in the backward branch
-    assert text.count("%ragged-dot-none") >= 8
-    assert " conditional(" in text
-    assert "[180224,2688]" not in text and "[45056,2688]" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
-
-
 def test_nemotron3super_step_compiles_and_fits_as_recorded(one_chip,
                                                            monkeypatch):
     """The training step of ``nemotron3super-11l-spmd-1c`` at the cell's
     sizes (11 layers at the published widths, 16 of 512 experts held, 8192
     tokens; ``optax.adam`` in the distributed optimizer's place): it
     compiles for the described v5e with the flash kernels at 16 query
-    heads a key head, and its arguments and temporaries are what the
-    configuration file records, inside the 16.9 GB the runtime allows."""
+    heads a key head, its arguments are what the configuration file
+    records and its temporaries 5.55 GB, under the file's 5.78, inside the
+    16.9 GB the runtime allows."""
     import optax
 
     from benchmark import cell as cells
@@ -579,9 +591,11 @@ def test_nemotron3super_step_compiles_and_fits_as_recorded(one_chip,
     assert abs(memory.argument_size_in_bytes
                - recorded["argument_bytes"]) < 1e6
     assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
-    assert abs(memory.temp_size_in_bytes
-               - recorded["sandbox_temp_bytes"]) < 0.02 * recorded[
-                   "sandbox_temp_bytes"]
+    # The file's record dates from the PR that brought the cell (it is the
+    # benchmark's to bring up to date): since PR 49 the expert layer holds
+    # no buffer of every assignment made anywhere, and the step reads less.
+    assert memory.temp_size_in_bytes < recorded["sandbox_temp_bytes"]
+    assert abs(memory.temp_size_in_bytes - 5.552e9) < 0.02 * 5.552e9
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16.9e9
 
@@ -765,8 +779,9 @@ def test_laguna_s2_1_step_compiles_and_fits_as_recorded(one_chip,
     (five layers at the published widths, 16 of 256 experts, 12544 rows,
     16384 tokens; ``optax.adam`` in the distributed optimizer's place): it
     compiles for the described v5e with the flash kernels of both layer
-    kinds, and its arguments and temporaries are what the configuration
-    file records, inside the 16.9 GB the runtime allows."""
+    kinds, its arguments are what the configuration file records and its
+    temporaries 5.82 GB, under the file's 6.74, inside the 16.9 GB the
+    runtime allows."""
     import optax
 
     from benchmark import cell as cells
@@ -799,8 +814,10 @@ def test_laguna_s2_1_step_compiles_and_fits_as_recorded(one_chip,
     assert abs(memory.argument_size_in_bytes
                - recorded["argument_bytes"]) < 1e6
     assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
-    assert abs(memory.temp_size_in_bytes
-               - recorded["sandbox_temp_bytes"]) < 0.02 * recorded[
-                   "sandbox_temp_bytes"]
+    # The file's record dates from the PR that brought the cell (it is the
+    # benchmark's to bring up to date): since PR 49 the expert layer holds
+    # no buffer of every assignment made anywhere, and the step reads less.
+    assert memory.temp_size_in_bytes < recorded["sandbox_temp_bytes"]
+    assert abs(memory.temp_size_in_bytes - 5.820e9) < 0.02 * 5.820e9
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16.9e9
