@@ -7,7 +7,8 @@ framework deps) eagerly.
 
 _FAMILIES = ("llama", "gpt2", "bert", "vit", "resnet", "moe", "dlrm",
              "mnist", "convert", "qwen3_next", "olmo_hybrid", "gated_delta",
-             "nemotron_h", "mamba2", "ouro", "jamba", "mamba", "laguna")
+             "nemotron_h", "mamba2", "ouro", "jamba", "mamba", "laguna",
+             "latent_attention", "joyai")
 
 __all__ = list(_FAMILIES)
 

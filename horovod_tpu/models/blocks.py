@@ -5,8 +5,8 @@ a rotary told its kind (plain or YaRN) and the width it turns, and the
 training step.
 
 A family (``qwen3_next``, ``olmo_hybrid``, ``nemotron_h``, ``ouro``,
-``jamba``, ``laguna``) owns its config, its parameters, its mixers, its
-layer pattern and its ``loss_fn``; what it would otherwise copy from the
+``jamba``, ``laguna``, ``joyai``) owns its config, its parameters, its
+mixers, its layer pattern and its ``loss_fn``; what it would otherwise copy from the
 family before it is here.  Nothing here knows a family: each function is
 told what it needs.
 """
@@ -130,11 +130,13 @@ def next_token_loss(logits, targets):
                                              axis=-1))
 
 
-def next_token_loss_in_blocks(x, targets, logits_of, block):
+def next_token_loss_in_blocks(x, targets, logits_of, block, mask=None):
     """Mean next-token cross-entropy of the last layer's output ``x [B, T,
     d]``, the head ``block`` tokens at a time (``logits_of(x_block)`` gives
     float32 logits), each block recomputed in the backward pass: logits
-    over a vocabulary are hundreds of MB a thousand tokens."""
+    over a vocabulary are hundreds of MB a thousand tokens.  With a
+    ``mask [B, T]`` the mean is over the positions it keeps (a prediction
+    module's last positions have no target)."""
     B, T, _ = x.shape
     block = min(block, T)
     pad = (-T) % block
@@ -151,25 +153,34 @@ def next_token_loss_in_blocks(x, targets, logits_of, block):
 
     with jax.named_scope("head"):
         nll = lax.map(jax.checkpoint(of_block), (blocks(x), blocks(targets)))
-        return jnp.mean(jnp.moveaxis(nll, 0, 1).reshape(B, -1)[:, :T])
+        nll = jnp.moveaxis(nll, 0, 1).reshape(B, -1)[:, :T]
+        if mask is None:
+            return jnp.mean(nll)
+        return jnp.sum(jnp.where(mask, nll, 0.0)) / jnp.sum(mask)
 
 
-def train_step(loss, optimizer):
+def train_step(loss, optimizer, after_update=None):
     """``step(params, opt_state, tokens, targets) -> (params, opt_state,
     loss)`` of ``loss(params, tokens, targets)``, for use inside
     ``shard_map``; ``optimizer`` is an in-graph ``hvd.DistributedOptimizer``
-    (or plain optax), which exchanges the gradients."""
+    (or plain optax), which exchanges the gradients.  A state that changes
+    by another rule than the gradient's (a router's selection bias, moved by
+    the experts' load) has ``after_update``: ``loss`` then returns ``(value,
+    aux)`` and the step ends with ``params = after_update(params, aux)``."""
     import optax
 
     def step(params, opt_state, tokens, targets):
         with jax.named_scope("forward"):
-            value, backward = jax.vjp(lambda p: loss(p, tokens, targets),
-                                      params)
+            value, backward, *aux = jax.vjp(
+                lambda p: loss(p, tokens, targets), params,
+                has_aux=after_update is not None)
         with jax.named_scope("backward"):
             grads, = backward(jnp.ones_like(value))
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            if after_update is not None:
+                params = after_update(params, *aux)
         return params, opt_state, value
 
     return step

@@ -528,8 +528,11 @@ def _blocked_experts_bwd(cfg, blocks, res, ct):
 _blocked_experts.defvjp(_blocked_experts_fwd, _blocked_experts_bwd)
 
 
-def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
-    """The share's part of the layer for tokens ``x [S, D]``.
+def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig, routed=None):
+    """The share's part of the layer for tokens ``x [S, D]``.  ``routed``
+    is :func:`dropless_route`'s result where the caller has it already (a
+    family that moves the selection bias by the load of ALL experts counts
+    the ids itself); otherwise the layer routes.
 
     Returns ``(y [S, D], held_counts [experts_held] int32)``: ``y`` is the
     routed part of the experts held here plus the shared expert (where
@@ -550,8 +553,8 @@ def dropless_moe_ffn(x, params, cfg: DroplessMoEConfig):
     """
     H = cfg.held
     with jax.named_scope("moe/route"):
-        ids, weights = dropless_route(x, params["router"], cfg,
-                                      params.get("router_bias"))
+        ids, weights = routed or dropless_route(
+            x, params["router"], cfg, params.get("router_bias"))
         local = ids.reshape(-1) - cfg.first_expert          # [S * K]
         # held assignments first, by expert; the others after every group
         keys = jnp.where((local >= 0) & (local < H), local, H)

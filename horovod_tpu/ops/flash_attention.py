@@ -16,6 +16,12 @@ keeps an element, so a block that computes nothing costs no grid step.
 Layout: ``[B, T, H, D]`` (the llama layout).  GQA is native: pass kv with
 ``K = H / rep`` heads and each q-head group reads its shared kv head
 through the kernels' block index maps — the repeat never touches HBM.
+Queries and keys have one width and values another (latent attention scores
+over 192 numbers and sums values of 128): ``q``, ``k``, ``dq`` and ``dk``
+blocks are ``Dqk`` wide, ``v``, ``o``, ``do`` and ``dv`` blocks and the
+output's accumulator ``Dv``; a width off the 128 lanes' grid is the array's
+own last dimension, which a block may have, and is padded in VMEM, never in
+HBM.
 
 On non-TPU backends the kernels run in Pallas interpret mode (tests), so
 the same code path is exercised everywhere; ``models/llama`` routes to
@@ -442,11 +448,12 @@ def _pad_t(x, block):
 
 def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
               window=0):
-    """q: [BH, T, D]; k, v: [BH // rep, T, D] (GQA: ``rep`` consecutive
-    q-heads share one kv head — remapped in the BlockSpec index, no
-    materialized repeat) -> (o [BH, Tq, D], lse [BH, Tq])."""
+    """q: [BH, T, Dqk]; k: [BH // rep, T, Dqk]; v: [BH // rep, T, Dv] (GQA:
+    ``rep`` consecutive q-heads share one kv head — remapped in the
+    BlockSpec index, no materialized repeat) -> (o [BH, Tq, Dv], lse [BH,
+    Tq])."""
     BH, Tq, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[2]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     qp, kp, vp = _pad_t(q, bq), _pad_t(k, bk), _pad_t(v, bk)
     Tqp = qp.shape[1]
@@ -464,22 +471,22 @@ def _fwd_impl(q, k, v, scale, causal, block_q, block_k, interpret, rep=1,
             in_specs=[
                 pl.BlockSpec((1, bq, D), by_q),
                 pl.BlockSpec((1, bk, D), kv_of_q),
-                pl.BlockSpec((1, bk, D), kv_of_q),
+                pl.BlockSpec((1, bk, Dv), kv_of_q),
             ],
             out_specs=[
-                pl.BlockSpec((1, bq, D), by_q),
+                pl.BlockSpec((1, bq, Dv), by_q),
                 # 3D (1, bq, 1): TPU block rules need the trailing dims
                 # divisible by (8, 128) or equal to the array's — a [BH, T]
                 # row vector can't satisfy that, [BH, T, 1] can.
                 pl.BlockSpec((1, bq, 1), by_q),
             ],
             scratch_shapes=[
-                pltpu.VMEM((bq, D), jnp.float32),
+                pltpu.VMEM((bq, Dv), jnp.float32),
                 pltpu.VMEM((bq, LANES), jnp.float32),
                 pltpu.VMEM((bq, LANES), jnp.float32),
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tqp, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tqp, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Tqp, 1), jnp.float32),
         ],
         compiler_params=walk.semantics,
@@ -522,16 +529,21 @@ def flash_attention(q, k, v, causal: bool = False,
                     window: Optional[int] = None):
     """Memory-efficient exact attention.
 
-    q: ``[B, T, H, D]``; k, v: ``[B, T, K, D]`` with ``H % K == 0`` — GQA
-    is native (each group of ``H // K`` consecutive q-heads reads its kv
-    head through the kernel's block index map; the kv tensors are never
-    repeated in HBM).  Differentiable via flash backward kernels; matches
-    ``parallel.ring_attention.local_flash_attention`` numerically.
+    q: ``[B, T, H, Dqk]``; k: ``[B, T, K, Dqk]``; v: ``[B, T, K, Dv]`` with
+    ``H % K == 0``; returns ``[B, T, H, Dv]``.  GQA is native (each group
+    of ``H // K`` consecutive q-heads reads its kv head through the
+    kernel's block index map; the kv tensors are never repeated in HBM).
+    ``Dv`` may differ from ``Dqk`` (see the module's note); the default
+    ``scale`` is ``Dqk ** -0.5``.  Differentiable via flash backward
+    kernels; matches ``parallel.ring_attention.local_flash_attention``
+    numerically.
     """
     B, Tq, H, D = q.shape
     K = k.shape[2]
     if v.shape[2] != K:
         raise ValueError(f"k has {K} heads but v has {v.shape[2]}")
+    if k.shape[3] != D:
+        raise ValueError(f"q heads are {D} wide but k heads {k.shape[3]}")
     if H % K:
         raise ValueError(f"q heads ({H}) must be a multiple of kv heads "
                          f"({K}) for GQA")
@@ -552,11 +564,11 @@ def flash_attention(q, k, v, causal: bool = False,
             raise ValueError(f"window must be >= 1, got {window}")
 
     def to_bh(x):
-        h = x.shape[2]
-        return x.transpose(0, 2, 1, 3).reshape(B * h, x.shape[1], D)
+        _, t, h, d = x.shape
+        return x.transpose(0, 2, 1, 3).reshape(B * h, t, d)
 
     def from_bh(x, t):
-        return x.reshape(B, H, t, D).transpose(0, 2, 1, 3)
+        return x.reshape(B, H, t, x.shape[-1]).transpose(0, 2, 1, 3)
 
     o = _flash_core(to_bh(q), to_bh(k), to_bh(v), scale, causal,
                     block_q, block_k, interpret, rep, window or 0)
@@ -593,13 +605,14 @@ def _flash_bwd(scale, causal, block_q, block_k, interpret, rep, window,
 
 def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
               interpret, rep=1, window=0):
-    """Flash backward over one (q-shard, kv-shard) pair: q/do [BH, Tq, D],
-    k/v [BK, Tk, D], lse/delta [BH, Tq] (lse may be the GLOBAL logsumexp —
-    that is exactly what makes this reusable as one ring-attention backward
-    step) -> (dq, dk, dv) in the input dtypes."""
+    """Flash backward over one (q-shard, kv-shard) pair: q [BH, Tq, Dqk],
+    do [BH, Tq, Dv], k [BK, Tk, Dqk], v [BK, Tk, Dv], lse/delta [BH, Tq]
+    (lse may be the GLOBAL logsumexp — that is exactly what makes this
+    reusable as one ring-attention backward step) -> (dq, dk, dv) in the
+    input dtypes."""
     BH, Tq, D = q.shape
     BK = k.shape[0]
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[2]
     bq, bk = min(block_q, Tq), min(block_k, Tk)
 
     qp, dop = _pad_t(q, bq), _pad_t(do, bq)
@@ -626,8 +639,8 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
             in_specs=[
                 pl.BlockSpec((1, bq, D), by_q),
                 pl.BlockSpec((1, bk, D), kv_of_q),
-                pl.BlockSpec((1, bk, D), kv_of_q),
-                pl.BlockSpec((1, bq, D), by_q),
+                pl.BlockSpec((1, bk, Dv), kv_of_q),
+                pl.BlockSpec((1, bq, Dv), by_q),
                 pl.BlockSpec((1, bq, 1), by_q),
                 pl.BlockSpec((1, bq, 1), by_q),
             ],
@@ -660,20 +673,20 @@ def _bwd_impl(q, k, v, do, lse, delta, *, scale, causal, block_q, block_k,
             in_specs=[
                 pl.BlockSpec((1, bq, D), q_of_k),
                 pl.BlockSpec((1, bk, D), by_k),
-                pl.BlockSpec((1, bk, D), by_k),
-                pl.BlockSpec((1, bq, D), q_of_k),
+                pl.BlockSpec((1, bk, Dv), by_k),
+                pl.BlockSpec((1, bq, Dv), q_of_k),
                 pl.BlockSpec((1, bq, 1), q_of_k),
                 pl.BlockSpec((1, bq, 1), q_of_k),
             ],
             out_specs=[
                 pl.BlockSpec((1, bk, D), by_k),
-                pl.BlockSpec((1, bk, D), by_k),
+                pl.BlockSpec((1, bk, Dv), by_k),
             ],
             scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                            pltpu.VMEM((bk, D), jnp.float32)]),
+                            pltpu.VMEM((bk, Dv), jnp.float32)]),
         out_shape=[
             jax.ShapeDtypeStruct((BK, Tkp, D), k.dtype),
-            jax.ShapeDtypeStruct((BK, Tkp, D), v.dtype),
+            jax.ShapeDtypeStruct((BK, Tkp, Dv), v.dtype),
         ],
         compiler_params=walk.semantics,
         interpret=interpret,

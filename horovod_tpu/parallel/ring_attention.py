@@ -316,7 +316,9 @@ def local_flash_attention(q, k, v, causal: bool = False,
     """Single-device reference attention (same math, no ring) for tests and
     for the sp=1 fast path.  GQA is native: kv may have ``K = H / rep``
     heads — a grouped einsum, no HBM repeat.  ``window`` = sliding-window
-    (Mistral-style) causal attention over the last ``window`` positions."""
+    (Mistral-style) causal attention over the last ``window`` positions.
+    ``v``'s heads may be another width than ``q``'s and ``k``'s: the
+    output has theirs."""
     B, Tq, H, D = q.shape
     K = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -334,7 +336,7 @@ def local_flash_attention(q, k, v, causal: bool = False,
         p = jax.nn.softmax(s, axis=-1)
         out = jnp.einsum("bkrqs,bskd->bqkrd", p.astype(v.dtype), v,
                          preferred_element_type=jnp.float32)
-        return out.reshape(B, Tq, H, D).astype(q.dtype)
+        return out.reshape(B, Tq, H, v.shape[-1]).astype(q.dtype)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:

@@ -326,14 +326,15 @@ expert_blocks = {"sites": 0, "blocks": 0, "block_rows": 0}
 flash_blocks = {"grid": 0, "steps": 0}
 
 # Call sites of a family whose attention differs by layer kind
-# (``models/laguna.py`` ``_attention_block``), counted in Python at trace
-# time by the layer's kind and the path it took: ``*_flash`` the Pallas
-# kernels (``ops/flash_attention.py``; a window layer's walk only its
-# band's blocks), ``*_plain`` XLA's masked softmax.  ``*_plain`` rising on
-# a TPU is a sequence under the kernels' crossover or a config that turned
-# them off.
+# (``models/laguna.py`` ``_attention_block``; ``models/joyai.py``'s latent
+# attention, keys of one width and values of another), counted in Python
+# at trace time by the layer's kind and the path it took: ``*_flash`` the
+# Pallas kernels (``ops/flash_attention.py``; a window layer's walk only
+# its band's blocks), ``*_plain`` XLA's masked softmax.  ``*_plain`` rising
+# on a TPU is a sequence under the kernels' crossover or a config that
+# turned them off.
 attention = {"full_flash": 0, "full_plain": 0, "window_flash": 0,
-             "window_plain": 0}
+             "window_plain": 0, "latent_flash": 0, "latent_plain": 0}
 
 # The one table of the process-wide series: name -> (kind, help, read,
 # label).  ``read()`` gives a number, or with a ``label`` a dict from the
@@ -386,7 +387,10 @@ _register_counts("hvd_attention", attention, {
     "full_plain": "full-attention call sites traced as XLA's own code",
     "window_flash": "window-attention call sites traced as the flash "
                     "kernels",
-    "window_plain": "window-attention call sites traced as XLA's own code"})
+    "window_plain": "window-attention call sites traced as XLA's own code",
+    "latent_flash": "latent-attention call sites traced as the flash "
+                    "kernels",
+    "latent_plain": "latent-attention call sites traced as XLA's own code"})
 _register_counts("hvd_flash_blocks", flash_blocks, {
     "grid": "blocks a head of the dense grids of traced flash kernel calls",
     "steps": "steps a head the flash kernels' schedules keep of those grids"})

@@ -30,20 +30,28 @@ def _value_and_grads(attend, q, k, v, w=None):
     ((2, 70, 3, 16), (32, 32)),   # padded: 70 % 32 != 0
     ((1, 64, 2, 32), (32, 32)),   # exact multiple
     ((2, 33, 1, 8), (16, 16)),    # tiny + padding
+    # keys and values of two widths (latent attention's 192 and 128, and a
+    # small odd pair), T no multiple of the block
+    ((1, 40, 2, 192, 128), (16, 16)),
+    ((2, 33, 3, 24, 10), (16, 16)),
+    ((1, 70, 2, 16, 40), (32, 16)),   # values wider than keys
 ])
 def test_flash_matches_reference(shape, blocks, causal):
-    B, T, H, D = shape
+    B, T, H, D, *rest = shape
+    Dv = rest[0] if rest else D
     bq, bk = blocks
     rng = np.random.RandomState(hash((shape, causal)) % (2**31))
     q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
+    v = jnp.asarray(rng.randn(B, T, H, Dv), jnp.float32)
 
     flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
                                             block_q=bq, block_k=bk)
     plain = lambda q, k, v: local_flash_attention(q, k, v, causal=causal)
     (out, gf), (ref, gr) = (_value_and_grads(f, q, k, v)
                             for f in (flash, plain))
+    assert out.shape == (B, T, H, Dv)
+    assert [g.shape[-1] for g in gf] == [D, D, Dv]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=3e-5, rtol=3e-5)
     for a, b in zip(gf, gr):
@@ -51,17 +59,19 @@ def test_flash_matches_reference(shape, blocks, causal):
                                    atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("D, Dv", [(16, 16), (24, 16)],
+                         ids=["one-width", "keys24-values16"])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_gqa_matches_repeated(causal):
+def test_flash_gqa_matches_repeated(causal, D, Dv):
     """Native GQA (kv heads shared via block index maps) == materialized
     jnp.repeat, for values and all three gradients (dk/dv accumulate over
-    the q-head group)."""
-    B, T, H, K, D = 2, 40, 4, 2, 16
+    the q-head group), with keys and values of one width and of two."""
+    B, T, H, K = 2, 40, 4, 2
     rep = H // K
     rng = np.random.RandomState(7)
     q = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
     k = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
-    v = jnp.asarray(rng.randn(B, T, K, D), jnp.float32)
+    v = jnp.asarray(rng.randn(B, T, K, Dv), jnp.float32)
 
     flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
                                             block_q=16, block_k=16)
@@ -512,7 +522,10 @@ CELL_GEOMETRIES = [pytest.param(1, 1024, 1024, 8, 4, 128, True, None,
     pytest.param(2, 1024, 4096, 8, 2, 128, False, None,
                  id="cross-tq1024-tk4096"),
     pytest.param(1, 700, 1500, 12, 12, 64, False, None,
-                 id="cross-d64-tq700-tk1500-padded")]
+                 id="cross-d64-tq700-tk1500-padded"),
+    # joyai_flash-5l-spmd-1c: 32 heads, keys of 192 beside values of 128
+    pytest.param(1, 16384, 16384, 32, 32, (192, 128), True, None,
+                 id="joyai_flash-5l-spmd-1c-keys192-values128")]
 
 
 @pytest.mark.parametrize("B,Tq,Tk,H,K,D,causal,window", CELL_GEOMETRIES)
@@ -528,10 +541,12 @@ def test_flash_tpu_lowering(B, Tq, Tk, H, K, D, causal, window):
             interpret=False).astype(jnp.float32)),
             argnums=(0, 1, 2))(q, k, v)
 
+    D, Dv = D if isinstance(D, tuple) else (D, D)
     spec_q = jax.ShapeDtypeStruct((B, Tq, H, D), jnp.bfloat16)
-    spec_kv = jax.ShapeDtypeStruct((B, Tk, K, D), jnp.bfloat16)  # GQA
+    spec_k = jax.ShapeDtypeStruct((B, Tk, K, D), jnp.bfloat16)  # GQA
+    spec_v = jax.ShapeDtypeStruct((B, Tk, K, Dv), jnp.bfloat16)
     exp = jax.export.export(jax.jit(f), platforms=["tpu"])(
-        spec_q, spec_kv, spec_kv)
+        spec_q, spec_k, spec_v)
     assert exp.mlir_module().count("tpu_custom_call") == 3
 
 

@@ -95,6 +95,14 @@ LAGUNA_SCOPES = ("attn/full", "attn/window", "mlp", "moe/route",
                  "moe/dispatch", "moe/experts", "moe/shared", "moe/combine",
                  "head")
 
+# the latent-attention expert decoder's own: the prediction module first
+# (an instruction goes to the first scope its op_name holds), the low-rank
+# paths beneath the attention's scope (``benchmark/families/joyai.py``
+# ``SCOPES``)
+JOYAI_SCOPES = ("mtp", "attn/latent/proj", "attn/latent", "mlp", "moe/route",
+                "moe/dispatch", "moe/experts", "moe/shared", "moe/combine",
+                "head")
+
 # the looped step's own: a layer's two halves, and what ends a pass
 # (``benchmark/families/ouro.py`` ``SCOPES``)
 OURO_SCOPES = ("attn/full", "mlp", "head", "loop/exit", "loop/carry")
@@ -106,8 +114,10 @@ OURO_SCOPES = ("attn/full", "mlp", "head", "loop/exit", "loop/carry")
     (family_step("jamba"),
      ("forward", "backward", "optimizer") + JAMBA_SCOPES),
     (family_step("laguna"),
-     ("forward", "backward", "optimizer") + LAGUNA_SCOPES)],
-    ids=["resnet", "llama", "ouro", "jamba", "laguna"])
+     ("forward", "backward", "optimizer") + LAGUNA_SCOPES),
+    (family_step("joyai"),
+     ("forward", "backward", "optimizer") + JOYAI_SCOPES)],
+    ids=["resnet", "llama", "ouro", "jamba", "laguna", "joyai"])
 def test_named_scopes_change_metadata_only(step, scopes, monkeypatch):
     lower = step()
     jax.clear_caches()      # a checkpointed region traced before is kept
@@ -126,7 +136,7 @@ def test_named_scopes_change_metadata_only(step, scopes, monkeypatch):
 
 @pytest.mark.parametrize("name, scopes", [
     ("ouro", OURO_SCOPES), ("jamba", JAMBA_SCOPES),
-    ("laguna", LAGUNA_SCOPES)])
+    ("laguna", LAGUNA_SCOPES), ("joyai", JOYAI_SCOPES)])
 def test_a_familys_scopes_are_the_ones_the_benchmark_reads(name, scopes):
     import importlib
     import os
@@ -136,3 +146,30 @@ def test_a_familys_scopes_are_the_ones_the_benchmark_reads(name, scopes):
         sys.path.insert(0, root)
     family = importlib.import_module(f"benchmark.families.{name}")
     assert family.SCOPES == scopes
+
+
+def test_the_prediction_modules_parts_are_mtps_in_the_scopes_table():
+    """``benchmark/trace_scopes.within`` on the ``joyai`` step's compiled
+    text: every scope of the family's table is some instruction's, and an
+    instruction of the module's attention, expert layer or head pass
+    (``mtp/.../attn/latent``, ``mtp/.../moe/experts``, ``mtp/.../head``)
+    goes to ``mtp``, the low-rank paths of a main layer to
+    ``attn/latent/proj`` and not to ``attn/latent``."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import trace_scopes
+    jax.clear_caches()
+    text = family_step("joyai")()().compile().as_text()
+    table = trace_scopes.within(JOYAI_SCOPES, text)
+    assert set(table.values()) == set(JOYAI_SCOPES)
+    names = dict(re.findall(r'%([\w.\-]+) = [^\n]*?op_name="([^"]*)"', text))
+    for inner in ("attn/latent", "moe/experts", "head"):
+        both = [n for n, op in names.items()
+                if re.search(r"\bmtp\b", op) and inner in op]
+        assert both and all(table[n] == "mtp" for n in both), inner
+    proj = [n for n, op in names.items() if "attn/latent/proj" in op
+            and not re.search(r"\bmtp\b", op)]
+    assert proj and all(table[n] == "attn/latent/proj" for n in proj)

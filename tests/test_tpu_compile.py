@@ -821,3 +821,109 @@ def test_laguna_s2_1_step_compiles_and_fits_as_recorded(one_chip,
     assert abs(memory.temp_size_in_bytes - 5.820e9) < 0.02 * 5.820e9
     assert (memory.argument_size_in_bytes
             + memory.temp_size_in_bytes) < 16.9e9
+
+
+def _joyai_cell(monkeypatch):
+    """``(sizes, config, shapes of the seeded weights in the program's
+    column order)`` of ``joyai_flash-5l-spmd-1c`` with the flash kernels
+    compiled, not interpreted (the default backend here is the CPU's)."""
+    from benchmark import cell as cells
+    from benchmark.families import joyai as family
+    from benchmark.reference import joyai as data
+    from horovod_tpu.models import joyai
+    from horovod_tpu.ops import flash_attention
+
+    monkeypatch.setattr(flash_attention, "_interpret_default", lambda: False)
+    sizes = dict(cells.load_cell("joyai_flash-5l-spmd-1c").sizes,
+                 use_flash=True)
+    cfg = family.config_of(sizes)
+    return sizes, cfg, jax.eval_shape(
+        lambda k: joyai.from_published(data.init_weights(k, sizes), cfg),
+        jax.random.PRNGKey(0))
+
+
+def test_joyai_latent_attention_block_compiles_at_the_cells_size(
+        one_chip, monkeypatch):
+    """One latent attention block of ``joyai-llm-flash-5l`` at the cell's
+    own sizes (16384 tokens of 2048, 32 heads, ranks 1536 and 512, keys of
+    192 beside values of 128), forward and backward: the norms, the two
+    low-rank paths, the rotary and the three flash kernels compile for the
+    described v5e — a block whose last dimension is 192, the array's own
+    and no multiple of the 128 lanes, is one Mosaic takes — over the
+    triangle's 528 blocks a head of the grid's 1024, and ``v`` reaches the
+    kernels 128 wide."""
+    from horovod_tpu import trace
+    from horovod_tpu.models import joyai
+
+    sizes, cfg, params = _joyai_cell(monkeypatch)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    p = {k: params["layers"][1][k] for k in ("attn_norm", "attn")}
+    assert p["attn"]["wq_b"].shape == (1536, 32 * 192)
+    assert p["attn"]["wkv_b"].shape == (512, 32 * (128 + 128))
+    x = jax.ShapeDtypeStruct((1, sizes["seq_len"], 2048), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(p, x):
+        return joyai._attention(p, x, cfg).astype(jnp.float32).sum()
+
+    before = dict(trace.flash_blocks), dict(trace.attention)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        at(p), x).compile().as_text()
+    assert _kernels(text) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    grid, steps = (trace.flash_blocks[k] - before[0][k]
+                   for k in ("grid", "steps"))
+    assert grid * 528 == steps * 1024
+    assert trace.attention["latent_flash"] == before[1]["latent_flash"] + 1
+    assert trace.attention["latent_plain"] == before[1]["latent_plain"]
+    # the kernels' operands: q, k of 192 and v of 128, none padded to 256
+    assert "bf16[32,16384,192]" in text and "bf16[32,16384,128]" in text
+    assert "bf16[32,16384,256]" not in text
+
+
+def test_joyai_flash_step_compiles_and_fits_as_recorded(one_chip,
+                                                        monkeypatch):
+    """The training step of ``joyai_flash-5l-spmd-1c`` at the cell's sizes
+    (five layers and the prediction module at the published widths, 32 of
+    256 experts, 16256 rows, 16384 tokens; ``optax.adam`` in the distributed
+    optimizer's place): it compiles for the described v5e with the flash
+    kernels, its arguments and temporaries are what the configuration file
+    records, and together they stay under 15.75 GiB."""
+    import optax
+
+    from benchmark import cell as cells
+    from benchmark.reference import joyai as data
+    from horovod_tpu.models import joyai
+
+    sizes, cfg, params = _joyai_cell(monkeypatch)
+    at = lambda t: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    adam = data.ADAM
+    optimizer = optax.adam(adam["lr"], b1=adam["b1"], b2=adam["b2"],
+                           eps=adam["eps"])
+    tokens = jax.ShapeDtypeStruct(
+        (sizes["batch_per_chip"], sizes["seq_len"]), jnp.int32,
+        sharding=one_chip)
+    compiled = jax.jit(
+        joyai.make_train_step(cfg, optimizer), donate_argnums=(0, 1)).lower(
+            at(params), at(jax.eval_shape(optimizer.init, params)), tokens,
+            tokens).compile()
+    assert _kernels(compiled.as_text()) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    memory = compiled.memory_analysis()
+    recorded = cells.load_cell("joyai_flash-5l-spmd-1c").config[
+        "memory_analysis"]
+    # 1,058,320,384 parameters and five selection biases of 256
+    assert sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
+        params)) == 1_058_321_664
+    # weights and two moments, 6 bytes a parameter (12 a bias), all donated
+    assert abs(memory.argument_size_in_bytes
+               - recorded["argument_bytes"]) < 1e6
+    assert memory.alias_size_in_bytes > 0.999 * recorded["argument_bytes"]
+    assert abs(memory.temp_size_in_bytes
+               - recorded["sandbox_temp_bytes"]) < 0.02 * recorded[
+                   "sandbox_temp_bytes"]
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) < 15.75 * 2 ** 30
